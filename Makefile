@@ -1,6 +1,6 @@
 # Test/bench entry points (CI runs these; see .github/workflows/ci.yml)
 
-.PHONY: test test-fast test-resilience test-cluster test-serving test-decode test-quant-serving test-spec-decode test-fleet test-fleet-chaos test-obs test-slo test-data test-ingest test-bundle test-kernels test-collectives test-layout test-recsys bench bench-dispatch bench-watch bench-gradcomm bench-layout bench-decode bench-decode-quant bench-spec bench-fleet bench-fleet-chaos bench-slo bench-recsys dryrun examples bench-scaling bench-loader watch
+.PHONY: test test-fast test-resilience test-cluster test-serving test-decode test-quant-serving test-spec-decode test-fleet test-fleet-chaos test-obs test-slo test-data test-ingest test-bundle test-kernels test-collectives test-layout test-recsys bench bench-dispatch bench-watch bench-gradcomm bench-layout bench-decode bench-decode-quant bench-spec bench-fleet bench-fleet-chaos bench-slo bench-recsys dryrun examples bench-scaling bench-loader
 
 # full suite, parallelized over cores (pytest-xdist): each worker is its
 # own process with its own 8-virtual-device CPU mesh, so distribution
@@ -14,20 +14,17 @@ test-serial:
 
 # the quick pre-commit loop: skips tests marked slow (multi-process
 # integration + minutes-scale compile-shape checks); CI's `make test`
-# still runs everything.  (The persistent compile cache is OFF by
-# default — BIGDL_TPU_TEST_CACHE=1 to opt in; see tests/conftest.py for
-# the segfault caveat on this image's jax build.)
+# still runs everything.
 test-fast:
 	python -m pytest tests/ -q -x -m "not slow" -n auto
 
-# the contributor/judge loop (VERDICT r4 item 9): the ~10-file core path,
-# serial, budgeted <= 5 min warm on 1 core — covers tensor ops, layers,
-# optim, the sharded train step, records, serving, storage, and the
-# watcher invariant without the long tail of integration files.
+# the contributor loop: the core path, serial, budgeted <= 5 min warm on
+# 1 core — covers tensor ops, layers, optim, the sharded train step,
+# records, serving and storage without the long tail of integration files.
 CORE_TESTS = tests/test_tensor.py tests/test_nn_layers.py \
   tests/test_optim.py tests/test_distri_optimizer.py \
   tests/test_parallel.py tests/test_records.py tests/test_serving.py \
-  tests/test_storage_remote.py tests/test_watcher_single.py
+  tests/test_storage_remote.py
 test-core:
 	python -m pytest $(CORE_TESTS) -q
 
@@ -135,8 +132,8 @@ test-kernels:
 	python -m pytest tests/test_ops_pallas.py -q
 
 # read-only perf-regression sentinel over the committed bench trajectory
-# (docs/performance.md §Regression sentinel).  NOT a watcher: it never
-# writes artifacts — chipup.py stays the single evidence writer.
+# (docs/performance.md §Regression sentinel).  Read-only: it never
+# writes artifacts.
 # `make bench-watch` proves the gate on synthetic rows (the CI step);
 # `python -m bigdl_tpu.obs.sentinel fresh.json` checks a real capture.
 bench-watch:
@@ -275,11 +272,6 @@ bench-fleet-chaos:
 # RECSYS_r*.json artifact source
 bench-recsys:
 	python bench_recsys.py
-
-# session-long TPU evidence orchestrator (single instance via flock;
-# BENCH_attempts.jsonl evidence trail)
-watch:
-	nohup python chipup.py >> chipup.log 2>&1 &
 
 # every example end-to-end at tiny sizes (the reference's nightly example
 # runs, SURVEY.md §5, scaled for CI); fails on the first broken example

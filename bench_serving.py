@@ -1216,7 +1216,7 @@ class _FleetServer:
                  decode_sleep: float = 0.0):
         env = {"PYTHONPATH": os.pathsep.join(
                    p for p in [REPO, os.environ.get("PYTHONPATH")] if p),
-               "JAX_PLATFORMS": "cpu", "BIGDL_TPU_POOL_CPU": "1"}
+               "JAX_PLATFORMS": "cpu"}
         if os.environ.get("BIGDL_TPU_FLEET_SLOTS"):
             env["BIGDL_TPU_FLEET_SLOTS"] = \
                 os.environ["BIGDL_TPU_FLEET_SLOTS"]

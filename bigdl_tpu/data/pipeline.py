@@ -5,7 +5,7 @@ Reference analog: BigDL 2.0 keeps the device fed by overlapping Spark block
 prefetch with per-executor transformer ThreadPools (SURVEY.md §4.1) — the
 read, transform, and batch-copy phases of consecutive iterations execute
 concurrently.  The seed repo ran those phases serially in the driver
-thread, which is why BENCH_r05 showed 1500 img/s device-resident but 58
+thread, which is why BENCH_r04 showed 1500 img/s device-resident but 58
 img/s host-fed: while decode ran, neither the record reader nor the
 host→device DMA had anything to do.
 

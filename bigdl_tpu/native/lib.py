@@ -1,6 +1,7 @@
 """Build-on-first-use loader + ctypes wrappers + numpy fallbacks."""
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -8,11 +9,17 @@ from typing import Optional
 
 import numpy as np
 
+from bigdl_tpu.utils.log import get_logger
+
+log = get_logger("bigdl_tpu.native")
+
 _SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))), "native", "bigdl_tpu_io.cpp")
-_CACHE_DIR = os.environ.get(
-    "BIGDL_TPU_NATIVE_CACHE",
-    os.path.join(os.path.expanduser("~"), ".cache", "bigdl_tpu"))
+# build products stay inside the checkout (a .gitignore'd directory): a
+# library built from another checkout's source can never be picked up
+_BUILD_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                          "_build")
+_CXX = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-march=native"]
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -21,29 +28,42 @@ _tried = False
 
 def _build_and_load() -> Optional[ctypes.CDLL]:
     if not os.path.exists(_SRC):
+        log.warning("native source %s not found; numpy fallbacks in use",
+                    _SRC)
         return None
-    os.makedirs(_CACHE_DIR, exist_ok=True)
-    so = os.path.join(_CACHE_DIR, "libbigdl_tpu_io.so")
-    if (not os.path.exists(so)
-            or os.path.getmtime(so) < os.path.getmtime(_SRC)):
-        base = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC",
-                "-march=native", "-o", so + ".tmp", _SRC, "-lpthread"]
+    # the artifact is named by the CONTENT of its source (and the flags):
+    # an edited source can never load a stale library, whatever the mtimes
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(
+            f.read() + " ".join(_CXX).encode()).hexdigest()[:16]
+    so = os.path.join(_BUILD_DIR, f"libbigdl_tpu_io-{digest}.so")
+    if not os.path.exists(so):
+        os.makedirs(_BUILD_DIR, exist_ok=True)
+        tmp = f"{so}.{os.getpid()}.tmp"
+        base = _CXX + ["-o", tmp, _SRC]
         # with libjpeg if the box has it; every other op still builds
         # without (python decode falls back to PIL)
-        for cmd in (base + ["-ljpeg"],
-                    base[:-1] + ["-DBTIO_NO_JPEG", "-lpthread"]):
+        errors = []
+        for cmd in (base + ["-lpthread", "-ljpeg"],
+                    base + ["-DBTIO_NO_JPEG", "-lpthread"]):
             try:
                 subprocess.run(cmd, check=True, capture_output=True,
                                timeout=120)
-                os.replace(so + ".tmp", so)
+                os.replace(tmp, so)
                 break
-            except (subprocess.SubprocessError, OSError):
-                continue
+            except subprocess.CalledProcessError as e:
+                errors.append(e.stderr.decode(errors="replace")[-300:])
+            except (subprocess.SubprocessError, OSError) as e:
+                errors.append(str(e))
         else:
+            log.warning("native library build failed (%s); numpy "
+                        "fallbacks in use", " | ".join(errors))
             return None
     try:
         lib = ctypes.CDLL(so)
-    except OSError:
+    except OSError as e:
+        log.warning("native library %s failed to load (%s); numpy "
+                    "fallbacks in use", so, e)
         return None
     # signatures
     u8p = ctypes.POINTER(ctypes.c_uint8)
@@ -88,6 +108,8 @@ def _build_and_load() -> Optional[ctypes.CDLL]:
         ctypes.c_int, ctypes.c_int, f32p, f32p, f32p, i32p]
     lib.btio_version.restype = ctypes.c_int
     if lib.btio_version() != 4:
+        log.warning("native library %s reports ABI version %s, want 4; "
+                    "numpy fallbacks in use", so, lib.btio_version())
         return None
     return lib
 

@@ -29,9 +29,7 @@ def _axis_bound(name: str) -> bool:
     """True when ``name`` is a mapped axis in the current trace (i.e. we
     are inside a shard_map/pmap that carries it)."""
     try:
-        from bigdl_tpu.runtime.mesh import axis_size
-
-        axis_size(name)
+        jax.lax.axis_size(name)
         return True
     except NameError:
         return False

@@ -18,60 +18,48 @@ agree):
 - training FLOPs = ``TRAIN_FLOPS_MULTIPLIER`` (3) x forward (fwd +
   input-grad + weight-grad).
 - MFU = achieved FLOP/s per chip / the chip's bf16 peak
-  (``peak_flops``); unknown device kinds yield ``None`` unless
-  ``BIGDL_TPU_PEAK_FLOPS`` / ``EngineConfig.peak_flops`` pins one.
+  (``peak_flops``): an exact ``device_kind`` table.  A TPU that is not in
+  it is an error; a non-TPU backend (CPU test meshes) has no peak and
+  exports no MFU gauge.
 """
 
-import os
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from bigdl_tpu.utils.log import get_logger
-
-log = get_logger("bigdl_tpu.obs")
-
 # fwd + input-grad + weight-grad — the standard training-FLOPs convention
 # (bench.py's analytic_3x_fwd)
 TRAIN_FLOPS_MULTIPLIER = 3.0
 
-# bf16 matmul peak FLOP/s by TPU generation (public spec sheets), keyed by
-# substrings of jax Device.device_kind.  THE process-wide source of truth:
-# bench.py / bench_lm.py delegate here.
-PEAK_BF16_FLOPS: List[Tuple[str, float]] = [
-    ("v6", 918e12),          # Trillium / v6e
-    ("v5p", 459e12),
-    ("v5 lite", 197e12),     # v5e reports device_kind "TPU v5 lite"
-    ("v5e", 197e12),
-    ("v5", 459e12),
-    ("v4 lite", 138e12),     # v4i
-    ("v4", 275e12),
-    ("v3", 123e12),
-    ("v2", 45e12),
-]
+# bf16 matmul peak FLOP/s per chip, keyed by the EXACT jax
+# ``Device.device_kind`` (the spellings jax's own
+# ``_src/pallas/mosaic/tpu_info.py`` matches on).  Values: Google Cloud TPU
+# documentation, system-architecture page of each generation ("TPU v5e":
+# 197 TFLOP/s bf16 per chip).  THE process-wide source of truth: bench.py /
+# bench_lm.py delegate here.
+PEAK_BF16_FLOPS: Dict[str, float] = {
+    "TPU v4": 275e12,
+    "TPU v5 lite": 197e12,   # v5e as libtpu reports it
+    "TPU v5e": 197e12,
+    "TPU v5": 459e12,        # v5p
+    "TPU v5p": 459e12,
+    "TPU v6 lite": 918e12,   # v6e (Trillium)
+    "TPU v6e": 918e12,
+}
 
 
-def peak_flops(device_kind: Optional[str],
-               override: Optional[float] = None) -> Optional[float]:
-    """Peak bf16 FLOP/s for one chip.  Resolution order:
-    ``BIGDL_TPU_PEAK_FLOPS`` env (operator pin for unknown hardware /
-    CPU test meshes) > explicit ``override`` (``EngineConfig.peak_flops``)
-    > the device-kind table > None."""
-    env = os.environ.get("BIGDL_TPU_PEAK_FLOPS")
-    if env:
-        try:
-            return float(env)
-        except ValueError:
-            log.warning("BIGDL_TPU_PEAK_FLOPS=%r is not a float; ignored",
-                        env)
-    if override:
-        return float(override)
-    kind = (device_kind or "").lower()
-    for key, peak in PEAK_BF16_FLOPS:
-        if key in kind:
-            return peak
-    return None
+def peak_flops(device_kind: str) -> Optional[float]:
+    """Peak bf16 FLOP/s for one chip of exactly this ``device_kind``.
+    A TPU kind missing from the table raises — a guessed denominator is a
+    wrong MFU; add the kind with its source.  Non-TPU kinds (CPU test
+    meshes) have no peak: ``None``, and the caller exports no gauge."""
+    peak = PEAK_BF16_FLOPS.get(device_kind)
+    if peak is None and str(device_kind).upper().startswith("TPU"):
+        raise ValueError(
+            f"no bf16 peak on record for device_kind {device_kind!r}; add "
+            "it to obs.cost.PEAK_BF16_FLOPS with its source")
+    return peak
 
 
 # ---------------------------------------------------------------------------

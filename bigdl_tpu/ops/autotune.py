@@ -36,11 +36,11 @@ the training path gets tuned tiles::
 Knobs: ``BIGDL_TPU_AUTOTUNE`` = ``0``/``off`` (defaults only), ``cache``
 (consult the cache, never measure — the default), ``1``/``online``
 (measure-and-cache on miss, eager calls only).  The env var is read at
-call time by this module (its single owner — mirrors the
-``BIGDL_TPU_PEAK_FLOPS`` pattern); ``EngineConfig.kernel_autotune`` is the
-in-process fallback when the env var is unset.
-``BIGDL_TPU_AUTOTUNE_CACHE`` overrides the cache directory (default
-``~/.cache/bigdl_tpu/autotune``).
+call time by this module (its single owner);
+``EngineConfig.kernel_autotune`` is the in-process fallback when the env
+var is unset.  ``BIGDL_TPU_AUTOTUNE_CACHE`` overrides the cache directory
+(default ``.autotune_cache`` in the checkout, beside the package — tiles
+tuned on one machine never follow the user's home directory onto another).
 """
 
 import argparse
@@ -104,17 +104,16 @@ def autotune_mode() -> str:
 
 
 def cache_dir() -> str:
+    from bigdl_tpu.runtime.engine import CHECKOUT
+
     return os.environ.get("BIGDL_TPU_AUTOTUNE_CACHE") or os.path.join(
-        os.path.expanduser("~"), ".cache", "bigdl_tpu", "autotune")
+        CHECKOUT, ".autotune_cache")
 
 
 def device_kind() -> str:
     import jax
 
-    try:
-        return jax.devices()[0].device_kind
-    except RuntimeError:  # pragma: no cover — no backend at all
-        return "unknown"
+    return jax.devices()[0].device_kind
 
 
 def is_concrete(*arrays) -> bool:

@@ -98,6 +98,16 @@ def _column_plan(arr: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
     return counts, idx
 
 
+def mosaic_tileable(block_k: int, block_n: int) -> bool:
+    """Whether Mosaic compiles the kernel for this weight-block shape.
+    Its blocks are x ``(bm, block_k)`` and w ``(block_k, block_n)``, and
+    the last two dims of a TPU block must be multiples of (8, 128) unless
+    they span the array — so both must be multiples of 128 (smaller
+    blocks are refused: "last two dimensions of your block shape are
+    divisible by 8 and 128").  Interpret mode takes any shape."""
+    return block_k % 128 == 0 and block_n % 128 == 0
+
+
 def _bs_kernel(counts_ref, idx_ref, x_ref, w_ref, o_ref):
     j = pl.program_id(1)
     t = pl.program_id(2)
@@ -249,7 +259,9 @@ class BlockSparseLinear(Linear):
         # matmul instead of the Pallas kernel: identical math (the mask
         # zeroes the same blocks), no Pallas dispatch — the right trade
         # for the tiny hidden sizes of a speculative draft model on CPU,
-        # where a grid launch per FFN costs more than the skipped FLOPs
+        # where a grid launch per FFN costs more than the skipped FLOPs.
+        # On TPU the forward also takes it for any block shape Mosaic
+        # cannot tile (mosaic_tileable)
         self.use_kernel = bool(use_kernel)
         self.mask: Optional[np.ndarray] = None
 
@@ -314,7 +326,8 @@ class BlockSparseLinear(Linear):
         from bigdl_tpu.tensor.policy import cast_compute
 
         xc, wc = cast_compute(x, params["weight"])
-        if self.use_kernel:
+        if self.use_kernel and (default_interpret()
+                                or mosaic_tileable(*self.block_shape)):
             y = block_sparse_matmul(
                 xc, wc, self.mask, block_k=self.block_shape[0],
                 block_n=self.block_shape[1]).astype(jnp.float32)

@@ -7,10 +7,11 @@ import jax
 
 @functools.lru_cache(maxsize=1)
 def on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:
-        return False
+    """Whether the default backend is a TPU.  A backend that fails to
+    initialize raises here (and is not cached): "no backend" must never
+    read as "not a TPU" — that would quietly put every kernel in interpret
+    mode and every matmul in float32."""
+    return jax.devices()[0].platform == "tpu"
 
 
 def default_interpret(interpret=None) -> bool:

@@ -240,7 +240,7 @@ def _decode_kernel(pt_ref, len_ref, *refs, sm_scale, page_size, quantized):
     # positions [j*page, (j+1)*page) attend when <= the slot's length
     @pl.when(j * page_size <= len_ref[s])
     def _step():
-        q = q_ref[0].astype(jnp.float32) * sm_scale      # (bh, d)
+        q = q_ref[...].astype(jnp.float32) * sm_scale    # (bh, d)
         k = k_ref[0].astype(jnp.float32)                 # (bh, page, d)
         v = v_ref[0].astype(jnp.float32)
         if quantized:
@@ -267,7 +267,7 @@ def _decode_kernel(pt_ref, len_ref, *refs, sm_scale, page_size, quantized):
     def _finish():
         l = l_scr[:, 0]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[:] / l_safe[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[:] / l_safe[:, None]).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
@@ -332,25 +332,29 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
     # trailing scalar refs instead of two — hence the arity split below)
     if quantized:
         def q_map(s, hb, j, pt, ln, ks, vs):
-            return (s, hb, 0)
+            return (s, hb, 0, 0)
 
         def kv_map(s, hb, j, pt, ln, ks, vs):
             return (pt[s, j], hb, 0, 0)
     else:
         def q_map(s, hb, j, pt, ln):
-            return (s, hb, 0)
+            return (s, hb, 0, 0)
 
         def kv_map(s, hb, j, pt, ln):
             return (pt[s, j], hb, 0, 0)
+    # q/out ride as (S, h/bh, bh, d) views with the two leading dims
+    # squeezed out of the block: Mosaic tiles the LAST TWO block dims in
+    # (8, 128) units unless they span the whole array dim, and a (bh, d)
+    # block of the (S, h, d) array (bh=4 of 12 heads) does neither
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4 if quantized else 2,
         grid=(S, h // bh, nb),
         in_specs=[
-            pl.BlockSpec((1, bh, d), q_map),
+            pl.BlockSpec((None, None, bh, d), q_map),
             pl.BlockSpec((1, bh, page, d), kv_map),
             pl.BlockSpec((1, bh, page, d), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, bh, d), q_map),
+        out_specs=pl.BlockSpec((None, None, bh, d), q_map),
         scratch_shapes=[
             pltpu.VMEM((bh, 1), jnp.float32),    # running max
             pltpu.VMEM((bh, 1), jnp.float32),    # running denom
@@ -365,9 +369,10 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, h // bh, bh, d), q.dtype),
         interpret=default_interpret(interpret),
-    )(*scalars, q, k_pages, v_pages)
+    )(*scalars, q.reshape(S, h // bh, bh, d), k_pages,
+      v_pages).reshape(S, h, d)
 
 
 def _verify_kernel(pt_ref, pos_ref, *refs, sm_scale, page_size, chunk,

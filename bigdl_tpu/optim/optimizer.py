@@ -536,8 +536,7 @@ class Optimizer:
             log.debug("analytic cost model unavailable (%s); no live MFU "
                       "gauge this run", e)
         self._peak_flops = obs_cost.peak_flops(
-            jax.devices()[0].device_kind,
-            getattr(engine.config, "peak_flops", None))
+            jax.devices()[0].device_kind)
         led = obs_cost.collective_ledger(step_engine)
         self._ici_bytes_step = led["ici_bytes_per_step"]
         self._dcn_bytes_step = led["dcn_bytes_per_step"]

@@ -42,7 +42,7 @@ from bigdl_tpu.obs.attr import expected_compile
 from bigdl_tpu.optim.validation import StatsAccumulator
 from bigdl_tpu.parallel import collectives
 from bigdl_tpu.runtime.mesh import (AXIS_DATA, AXIS_DCN, AXIS_SEQ,
-                                    axis_size, shard_map)
+                                    shard_map)
 
 
 def as_inputs(x):
@@ -446,7 +446,7 @@ class ShardedParameterStep:
             if dcn_axis:
                 replica = replica + ndev * jax.lax.axis_index(dcn_axis)
             if seq_par:
-                replica = (replica * axis_size(AXIS_SEQ)
+                replica = (replica * jax.lax.axis_size(AXIS_SEQ)
                            + jax.lax.axis_index(AXIS_SEQ))
             dev_rng = jax.random.fold_in(rng, replica)
 

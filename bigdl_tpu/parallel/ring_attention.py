@@ -20,8 +20,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from bigdl_tpu.runtime.mesh import axis_size
-
 
 NEG_INF = -1e30
 
@@ -59,7 +57,7 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = False,
     """
     b, h, c, d = q.shape
     scale = (1.0 / math.sqrt(d)) if scale is None else scale
-    n_blocks = axis_size(axis_name)
+    n_blocks = jax.lax.axis_size(axis_name)
     my_idx = jax.lax.axis_index(axis_name)
 
     q32 = q.astype(jnp.float32)

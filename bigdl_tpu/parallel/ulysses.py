@@ -27,8 +27,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from bigdl_tpu.runtime.mesh import axis_size
-
 
 def _a2a(x, axis_name: str, split_axis: int, concat_axis: int):
     """all_to_all that splits ``split_axis`` over the mesh axis and
@@ -52,7 +50,7 @@ def ulysses_attention(q, k, v, axis_name: str, causal: bool = False,
     from bigdl_tpu.nn.attention import dot_product_attention
 
     b, h, c, d = q.shape
-    p = axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     if h % p != 0:
         raise ValueError(
             f"ulysses needs heads ({h}) divisible by the seq axis ({p}); "

@@ -2,7 +2,7 @@
 
 Six layers (see ``docs/resilience.md`` for the failure model):
 
-- :mod:`.faults`    — deterministic fault injection (tests, bench_probe)
+- :mod:`.faults`    — deterministic fault injection (tests, chip_smoke.py)
 - :mod:`.detector`  — heartbeats (phi-accrual) + step watchdog
 - :mod:`.retry`     — retry policies, failure classification, FailurePolicy
 - :mod:`.membership`— epoch-numbered views over a shared control channel

@@ -29,35 +29,12 @@ AXIS_PIPE = "pipe"
 AXIS_DCN = "dcn_data"
 
 
-try:
-    from jax import shard_map as _shard_map  # jax >= 0.6
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# the replication-check kwarg was renamed check_rep -> check_vma across jax
-# versions; resolve the spelling once so call sites stay version-agnostic
-import inspect as _inspect
-
-_CHECK_KW = next((k for k in ("check_vma", "check_rep")
-                  if k in _inspect.signature(_shard_map).parameters), None)
-
-
 def shard_map(f, mesh, in_specs, out_specs, check: bool = False):
-    """Version-compat ``shard_map`` with the replication check off by
-    default (every caller here disables it: the train step's donated
-    buffers and psum_scatter/all_gather pattern trip false positives)."""
-    kw = {_CHECK_KW: check} if _CHECK_KW else {}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
-
-
-def axis_size(name: str) -> int:
-    """Version-compat ``jax.lax.axis_size``: older jax spells it
-    ``psum(1, axis)`` (constant-folds to the concrete size; raises
-    NameError for an unbound axis, same as the modern call)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
+    """``jax.shard_map`` with the replication check off by default (every
+    caller here disables it: the train step's donated buffers and
+    psum_scatter/all_gather pattern trip false positives)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def detect_slice_count(devices: Sequence) -> int:

@@ -36,8 +36,6 @@ def _bucket(n: int, buckets: Sequence[int]) -> int:
 _MESH_EXEC_LOCK = threading.Lock()
 
 
-
-
 class InferenceModel:
     """Wraps (model, variables) — or any callable — for concurrent serving."""
 
@@ -69,6 +67,12 @@ class InferenceModel:
         decode engine's programs inherit the same stored-int8 params.
         Quantization happens AFTER layout placement, so the int8
         tensors keep the layout's shardings."""
+        from bigdl_tpu.runtime.engine import enable_compile_cache
+
+        # a serving process builds no Engine; its bucketed programs (and
+        # the decode engine's two per length bucket) are what a restarted
+        # worker wants back from the persistent cache
+        enable_compile_cache()
         self.layout = None
         if layout is not None:
             from bigdl_tpu.parallel.mesh_policy import (ResolvedLayout,

@@ -6,9 +6,14 @@ the single engine loop: process isolation (a poisoned model copy cannot
 take the frontend down), horizontal scale-out (N task managers), and
 supervision (Flink restarts failed tasks).  The TPU-native equivalent is
 this pool: N worker subprocesses — each running the continuous-batching
-``ServingServer`` + ``HttpFrontend`` on its own port, each able to own
-its own device — behind one round-robin HTTP proxy that health-checks
-and RESTARTS dead workers.
+``ServingServer`` + ``HttpFrontend`` on its own port — behind one
+round-robin HTTP proxy that health-checks and RESTARTS dead workers.
+Workers are NOT pinned to a chip: a worker that initializes an
+accelerator backend claims every local chip, so ``start()`` refuses more
+than one worker unless the workers' environment says ``JAX_PLATFORMS=cpu``
+(``runtime.engine.require_one_chip_holder``).  On a chip host run one
+worker per pool; several in-process engines on distinct devices are the
+way to use several chips from one process.
 
     pool = ServingPool("my_pkg.my_mod:make_model", workers=2).start()
     # pool.url -> proxy endpoint: POST /predict, GET /health
@@ -78,10 +83,6 @@ def _worker_main(loader: str, batch_size: int, queue_capacity: int,
     """Entry point inside a worker subprocess."""
     import importlib
 
-    import jax
-
-    if os.environ.get("BIGDL_TPU_POOL_CPU"):
-        jax.config.update("jax_platforms", "cpu")
     # same rationale as the proxy (see ServingPool.start): the handler
     # threads stream per-token chunks and must not queue a GIL switch
     # interval behind the engine thread for every token they write
@@ -1229,6 +1230,12 @@ class ServingPool:
         # in every streaming client's TTFT and inter-token tail
         # (measured on the fleet bench: ~8x TTFT p99, ~30% tokens/s).
         sys.setswitchinterval(0.001)
+        from bigdl_tpu.runtime.engine import require_one_chip_holder
+
+        # no worker is pinned to a chip: an accelerator-holding pool is
+        # one worker; more need JAX_PLATFORMS=cpu in their environment
+        require_one_chip_holder(
+            self.max_workers, dict(os.environ, **(self.worker_env or {})))
         for i in range(self.n):
             # autoscaled workers (and unnamed slots) are "both": extra
             # capacity must be able to serve whatever the load needs

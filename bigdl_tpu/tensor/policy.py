@@ -18,13 +18,9 @@ _COMPUTE_DTYPE = [None]
 
 
 def _platform_default():
-    try:
-        import jax
+    from bigdl_tpu.ops.common import on_tpu
 
-        on_tpu = jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover — backend init failure
-        on_tpu = False
-    return jnp.dtype(jnp.bfloat16) if on_tpu else jnp.dtype(jnp.float32)
+    return jnp.dtype(jnp.bfloat16) if on_tpu() else jnp.dtype(jnp.float32)
 
 
 def set_compute_dtype(dtype) -> None:

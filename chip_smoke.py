@@ -1,0 +1,567 @@
+"""Chip smoke: the train and serve paths, once, on the TPU, at full width.
+
+    python chip_smoke.py                 # the chip run; exit 0 = every phase passed
+    python chip_smoke.py --rehearse-cpu  # tiny shapes on the CPU backend: a
+                                         # harness check, never a chip result
+
+One process drives every visible chip through the entry points a user calls:
+
+- ``kernels``        every Pallas kernel a TPU selector can reach, compiled by
+                     Mosaic at the LM's width and matched to its jnp reference
+- ``train_resnet50`` ``Optimizer(...).optimize()`` on ResNet-50 (s2d stem), 224x224
+- ``train_lm``       the same path on the 12-layer d768 ``Transformer(mode="lm")``
+                     at seq 1024 (flash-attention forward + blockwise backward
+                     inside the ZeRO-1 ``shard_map`` step)
+- ``serve_lm``       ``InferenceModel`` -> ``warmup()`` -> ``ServingServer`` +
+                     ``HttpFrontend``; concurrent ``/generate`` requests over
+                     localhost with drawn prompt lengths; zero compiles after
+                     warmup; the kernel path compared with the plain jnp path
+
+Weights are random (seeded), depth and width are the models' own.  Every phase
+prints ``platform``, ``device_kind``, the device count and PASS/FAIL; any FAIL
+makes the exit code non-zero.  Without a TPU it exits 2 before building
+anything and prints no result.  The last stdout line of a passing chip run is
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}``.
+
+Seconds printed here are set-up and wall seconds of a smoke, not performance.
+"""
+
+import argparse
+import gc
+import json
+import os
+import resource
+import shutil
+import sys
+import tempfile
+import threading
+import time
+import traceback
+
+import numpy as np
+
+# stated tolerances (max |kernel - reference| / max |reference|, reference in
+# float32 at matmul precision "highest"):
+TOL_F32 = 2e-3    # VPU/f32 kernels: only exp/rsqrt approximations differ
+TOL_MXU = 2e-2    # kernels whose dots take bf16 MXU passes (KERNELS_r04 used
+#                   0.02 forward / 0.05 backward for the same reason)
+TOL_MXU_BWD = 5e-2
+# serving, per generated token: both engines run the same bf16 projections
+# and FFN (the TPU compute dtype) and the same prefill; in the decode steps the
+# kernel does its attention dots in f32 on the VPU, the jnp path in single bf16
+# MXU passes (relative 2^-8 per product).  Measured on the v5e (PR 22):
+# 4.2e-4 and 5.3e-5 nats per token; the tolerance is ~20x that.  A wrong page
+# walk or mask is a difference of order 1.
+TOL_LOGP_NATS = 0.01
+
+FULL = dict(
+    resnet=dict(hw=224, classes=1000, batch_per_chip=128, steps=8),
+    lm=dict(layers=12, d=768, heads=12, vocab=32768, seq=1024,
+            batch_per_chip=8, steps=6),
+    serve=dict(slots=8, page_size=16, pages_per_slot=64, prompt_chunk=32,
+               max_new=12, requests=8, prompt_lens=(24, 640)),
+    kern=dict(slots=8, heads=12, hd=64, page=16, nb=64, chunk=5,
+              flash_b=2, flash_s=1024, ffn=(768, 3072), bs_block=(128, 128),
+              ln_rows=4096, mm=(256, 768, 3072)),
+)
+TINY = dict(
+    resnet=dict(hw=32, classes=10, batch_per_chip=8, steps=8),
+    lm=dict(layers=2, d=32, heads=2, vocab=64, seq=32,
+            batch_per_chip=4, steps=6),
+    serve=dict(slots=4, page_size=4, pages_per_slot=8, prompt_chunk=4,
+               max_new=4, requests=4, prompt_lens=(3, 24)),
+    kern=dict(slots=2, heads=2, hd=16, page=4, nb=2, chunk=3,
+              flash_b=1, flash_s=32, ffn=(32, 64), bs_block=(16, 16),
+              ln_rows=16, mm=(32, 128, 128)),
+)
+
+
+class Smoke:
+    def __init__(self, sizes, device):
+        self.sz = sizes
+        self.dev = device
+        self.results = {}
+        self.lm = None  # (model, variables) handed from train_lm to serve_lm
+
+    def say(self, phase, msg):
+        d = self.dev
+        print(f"[chip_smoke] phase={phase} platform={d['platform']} "
+              f"device_kind={d['kind']!r} devices={d['count']} {msg}",
+              flush=True)
+
+    def run(self, phase, fn):
+        """One phase.  A boundary that must keep running: a failed phase is
+        recorded with its traceback and the next one still reports."""
+        t0 = time.perf_counter()
+        detail = None
+        try:
+            fn()
+        except Exception:  # noqa: BLE001 — reported, and fails the run
+            detail = traceback.format_exc()
+        ok = self.results[phase] = detail is None
+        rss = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss // 1024
+        self.say(phase, f"result={'PASS' if ok else 'FAIL'} "
+                        f"seconds={time.perf_counter() - t0:.1f} "
+                        f"host_peak_rss_mb={rss}")
+        if not ok:
+            print(detail, flush=True)
+        gc.collect()
+
+    # -- phase: kernels ------------------------------------------------------
+    def kernels(self):
+        import jax
+        import jax.numpy as jnp
+
+        from bigdl_tpu.nn.attention import dot_product_attention
+        from bigdl_tpu.ops import autotune
+        from bigdl_tpu.ops.block_sparse import (block_sparse_matmul,
+                                                expand_mask)
+        from bigdl_tpu.ops.common import default_interpret
+        from bigdl_tpu.ops.flash_attention import (flash_attention,
+                                                   paged_decode_attention,
+                                                   paged_verify_attention)
+        from bigdl_tpu.ops.fused import fused_layernorm
+        from bigdl_tpu.ops.quantized import (dequantize_pages, int8_matmul,
+                                             quantize_pages)
+
+        k = self.sz["kern"]
+        S, h, d, page, nb, C = (k["slots"], k["heads"], k["hd"], k["page"],
+                                k["nb"], k["chunk"])
+        self.say("kernels", f"pallas_interpret={default_interpret()} "
+                            f"(False = compiled by Mosaic)")
+        if self.dev["platform"] == "tpu" and default_interpret():
+            raise AssertionError("kernels would run in interpret mode on TPU")
+        rs = np.random.RandomState(0)
+        bad = []
+
+        def check(name, got, want, tol):
+            got = np.asarray(got, np.float32)
+            want = np.asarray(want, np.float32)
+            err = float(np.max(np.abs(got - want))
+                        / max(float(np.max(np.abs(want))), 1e-6))
+            fine = bool(np.isfinite(got).all()) and err <= tol
+            self.say("kernels", f"kernel={name} rel_err={err:.2e} tol={tol} "
+                                f"{'ok' if fine else 'MISMATCH'}")
+            if not fine:
+                bad.append(name)
+
+        def ref(fn, *a):
+            with jax.default_matmul_precision("highest"):
+                return jax.jit(fn)(*a)
+
+        # flash attention, forward and backward, as MultiHeadAttention calls
+        # it in the LM (f32 q/k/v, causal)
+        B, T = k["flash_b"], k["flash_s"]
+        q, kk, v = (jnp.asarray(rs.randn(B, h, T, d), jnp.float32)
+                    for _ in range(3))
+        causal = jnp.tril(jnp.ones((T, T), bool))
+
+        def loss_of(att):
+            return lambda q, kk, v: jnp.sum(att(q, kk, v) ** 2)
+
+        flash = lambda q, kk, v: flash_attention(q, kk, v, causal=True)
+        plain = lambda q, kk, v: dot_product_attention(q, kk, v, mask=causal)
+        check("flash_attention_fwd", jax.jit(flash)(q, kk, v),
+              ref(plain, q, kk, v), TOL_MXU)
+        g_k = jax.jit(jax.grad(loss_of(flash), argnums=(0, 1, 2)))(q, kk, v)
+        g_r = ref(jax.grad(loss_of(plain), argnums=(0, 1, 2)), q, kk, v)
+        for n, a, b in zip("qkv", g_k, g_r):
+            check(f"flash_attention_bwd_d{n}", a, b, TOL_MXU_BWD)
+
+        # paged decode / verify attention over a page pool, f32 and int8
+        P = S * nb
+        kp = jnp.asarray(rs.randn(P, h, page, d), jnp.float32)
+        vp = jnp.asarray(rs.randn(P, h, page, d), jnp.float32)
+        pt = jnp.asarray(rs.permutation(P).reshape(S, nb), jnp.int32)
+        lens = jnp.asarray(rs.randint(0, nb * page - C, (S,)), jnp.int32)
+        qd = jnp.asarray(rs.randn(S, h, C, d), jnp.float32)
+        kq, ks = quantize_pages(kp)
+        vq, vs = quantize_pages(vp)
+
+        def gathered(pool):  # (P,h,page,d)[pt] -> (S,h,nb*page,d)
+            return pool[pt].transpose(0, 2, 1, 3, 4).reshape(
+                S, h, nb * page, d)
+
+        def attend(qc, kpool, vpool, first):
+            # query c of slot s sits at position first[s]+c, attends <= it
+            pos = first[:, None] + jnp.arange(qc.shape[2])[None, :]
+            valid = jnp.arange(nb * page)[None, None, :] <= pos[:, :, None]
+            sc = jnp.einsum("shcd,shkd->shck", qc, gathered(kpool)) \
+                * d ** -0.5
+            w = jax.nn.softmax(jnp.where(valid[:, None], sc, -1e30), -1)
+            return jnp.einsum("shck,shkd->shcd", w, gathered(vpool))
+
+        for tag, pools, scales in (
+                ("f32", (kp, vp), {}),
+                ("int8", (kq, vq), dict(k_scales=ks, v_scales=vs))):
+            deq = pools if not scales else (dequantize_pages(kq, ks),
+                                            dequantize_pages(vq, vs))
+            check(f"paged_decode_attention_{tag}",
+                  jax.jit(lambda q1: paged_decode_attention(
+                      q1, *pools, pt, lens, **scales))(qd[:, :, 0]),
+                  ref(attend, qd[:, :, :1], *deq, lens)[:, :, 0], TOL_F32)
+            check(f"paged_verify_attention_{tag}",
+                  jax.jit(lambda qc: paged_verify_attention(
+                      qc, *pools, pt, lens, **scales))(qd),
+                  ref(attend, qd, *deq, lens), TOL_F32)
+
+        # block-sparse matmul (the speculative draft's FFN), the compute
+        # dtype's inputs, forward and dx
+        K, N = k["ffn"]
+        bk, bn = k["bs_block"]
+        from bigdl_tpu.tensor.policy import get_compute_dtype
+
+        cdt = get_compute_dtype()
+        x = jnp.asarray(rs.randn(S, K), cdt)
+        w = jnp.asarray(rs.randn(K, N) / np.sqrt(K), cdt)
+        mask = rs.rand(K // bk, N // bn) < 0.5
+        mask[0, :] = True
+        wm = jnp.where(jnp.asarray(expand_mask(mask, K, N, bk, bn)),
+                       w.astype(jnp.float32), 0.0)
+        bs = lambda x: block_sparse_matmul(x, w, mask, block_k=bk,
+                                           block_n=bn)
+        dense = lambda x: x.astype(jnp.float32) @ wm
+        check("block_sparse_matmul_fwd", jax.jit(bs)(x), ref(dense, x),
+              TOL_MXU)
+        check("block_sparse_matmul_dx",
+              jax.jit(jax.grad(lambda x: jnp.sum(
+                  bs(x).astype(jnp.float32) ** 2)))(x),
+              ref(jax.grad(lambda x: jnp.sum(dense(x) ** 2)), x),
+              TOL_MXU_BWD)
+
+        # fused layernorm, int8 matmul
+        rows = k["ln_rows"]
+        xl = jnp.asarray(rs.randn(rows, K), jnp.float32)
+        gam = jnp.asarray(rs.randn(K), jnp.float32)
+        bet = jnp.asarray(rs.randn(K), jnp.float32)
+
+        def ln(x, g, b):
+            mu = x.mean(-1, keepdims=True)
+            var = ((x - mu) ** 2).mean(-1, keepdims=True)
+            return (x - mu) * jax.lax.rsqrt(var + 1e-5) * g + b
+
+        check("fused_layernorm", jax.jit(fused_layernorm)(xl, gam, bet),
+              ref(ln, xl, gam, bet), TOL_F32)
+        m_, k_, n_ = k["mm"]
+        a8 = rs.randint(-127, 128, (m_, k_)).astype(np.int8)
+        w8 = rs.randint(-127, 128, (k_, n_)).astype(np.int8)
+        check("int8_matmul", jax.jit(int8_matmul)(a8, w8),
+              a8.astype(np.int64) @ w8.astype(np.int64), 0.0)
+
+        # the committed default tiles every auto-resolved call above used
+        tiles = {
+            "flash_attention_fwd": autotune.resolve(
+                "flash_attention_fwd",
+                autotune.attention_key(q.shape, T, q.dtype)),
+            "flash_attention_bwd": autotune.resolve(
+                "flash_attention_bwd",
+                autotune.attention_key(q.shape, T, q.dtype)),
+            "flash_attention_decode": autotune.resolve(
+                "flash_attention_decode", autotune.decode_attention_key(
+                    S, h, page, d, nb, jnp.float32)),
+            "block_sparse_matmul": autotune.resolve(
+                "block_sparse_matmul", autotune.block_sparse_key(
+                    S, K, N, bk, bn, cdt)),
+            "fused_layernorm": autotune.resolve(
+                "fused_layernorm", autotune.rows_key(rows, K, jnp.float32)),
+            "int8_matmul": autotune.resolve(
+                "int8_matmul", autotune.matmul_key(m_, k_, n_, jnp.int8)),
+        }
+        self.say("kernels", f"autotune_cache={autotune.get_cache().path} "
+                            f"tiles={json.dumps(tiles)}")
+        if bad:
+            raise AssertionError(f"kernels off their reference: {bad}")
+
+    # -- phases: train -------------------------------------------------------
+    def _train(self, phase, model, x, y, method, steps, batch):
+        """``Optimizer(...).optimize()`` for ``steps`` steps on one batch's
+        worth of examples (every step sees the same examples, so a working
+        step must drive the loss down).  Asserts the loss curve, the mesh and
+        — on more than one device — where the state actually lives."""
+        import jax
+
+        from bigdl_tpu.data.dataset import DataSet
+        from bigdl_tpu.nn.criterion import CrossEntropyCriterion
+        from bigdl_tpu.optim.optimizer import Optimizer
+        from bigdl_tpu.optim.trigger import Trigger
+        from bigdl_tpu.runtime.engine import Engine
+
+        logdir = tempfile.mkdtemp(prefix="chip_smoke_")
+        try:
+            opt = Optimizer(model, DataSet.array(x, y),
+                            CrossEntropyCriterion(), batch_size=batch)
+            opt.set_optim_method(method)
+            opt.set_end_when(Trigger.max_iteration(steps))
+            opt.set_train_summary(logdir)
+            trained = opt.optimize()
+            losses = [v for _, v in opt._train_summary.read_scalar("loss")]
+            opt._train_summary.close()
+        finally:
+            shutil.rmtree(logdir, ignore_errors=True)
+        self.say(phase, "losses=" + ",".join(f"{v:.4f}" for v in losses))
+        assert len(losses) == steps, (len(losses), steps)
+        assert np.isfinite(losses).all(), "non-finite loss"
+        assert losses[-1] < losses[0], "loss did not fall"
+
+        devices = jax.devices()
+        mesh = Engine.get().mesh
+        eng = trained._engine
+        assert mesh.shape["data"] == len(devices) == eng.ndev, \
+            (dict(mesh.shape), len(devices))
+        placed = {"params": eng.flat_params,
+                  "opt_state": jax.tree_util.tree_leaves(eng.opt_state)[0],
+                  "batch": eng.shard_batch(x[:batch])}
+        report = {}
+        for name, arr in placed.items():
+            shards = arr.addressable_shards
+            assert {s.device for s in shards} == set(devices), \
+                f"{name} lives on {sorted(str(s.device) for s in shards)}"
+            report[name] = len({str(s.index) for s in shards})
+        # params replicate; ZeRO-1 optimizer state and the batch split
+        assert report["opt_state"] == report["batch"] == len(devices), report
+        mem = [(d.memory_stats() or {}).get("bytes_in_use") for d in devices]
+        if self.dev["platform"] == "tpu":
+            assert all(m and m > 0 for m in mem), f"bytes_in_use={mem}"
+        self.say(phase, f"mesh={dict(mesh.shape)} distinct_shards={report} "
+                        f"bytes_in_use={mem}")
+        return trained
+
+    def train_resnet50(self):
+        from bigdl_tpu.models.resnet import resnet50
+        from bigdl_tpu.optim.optim_method import SGD
+
+        c = self.sz["resnet"]
+        batch = c["batch_per_chip"] * self.dev["count"]
+        rs = np.random.RandomState(0)
+        x = rs.rand(batch, c["hw"], c["hw"], 3).astype(np.float32)
+        y = rs.randint(0, c["classes"], (batch,)).astype(np.int32)
+        self._train("train_resnet50",
+                    resnet50(classes=c["classes"], stem="s2d"), x, y,
+                    SGD(learning_rate=0.02, momentum=0.9), c["steps"], batch)
+
+    def _lm_model(self):
+        from bigdl_tpu.nn.attention import Transformer
+
+        c = self.sz["lm"]
+        return Transformer(vocab_size=c["vocab"], hidden_size=c["d"],
+                           num_heads=c["heads"], ffn_size=4 * c["d"],
+                           num_layers=c["layers"], dropout=0.0, mode="lm")
+
+    def train_lm(self):
+        from bigdl_tpu.optim.metrics import global_metrics
+        from bigdl_tpu.optim.optim_method import Adam
+
+        c = self.sz["lm"]
+        batch = c["batch_per_chip"] * self.dev["count"]
+        rs = np.random.RandomState(1)
+        ids = rs.randint(2, c["vocab"], (batch, c["seq"] + 1)).astype(
+            np.int32)
+        model = self._lm_model()
+        m = global_metrics()
+        before = (m.counter("ops.autotune_cache_hits")
+                  + m.counter("ops.autotune_cache_misses"))
+        trained = self._train("train_lm", model, ids[:, :-1], ids[:, 1:],
+                              Adam(learning_rate=3e-4), c["steps"], batch)
+        traced = (m.counter("ops.autotune_cache_hits")
+                  + m.counter("ops.autotune_cache_misses")) - before
+        self.say("train_lm", f"flash_attention tile resolutions while "
+                             f"tracing the step: {traced}")
+        if self.dev["platform"] == "tpu":
+            assert traced > 0, "the step never reached the flash kernel"
+        self.lm = (model, trained.variables)
+
+    # -- phase: serve ----------------------------------------------------------
+    def serve_lm(self):
+        import jax
+
+        from bigdl_tpu.obs.attr import expected_compile, recompile_sentinel
+        from bigdl_tpu.optim.metrics import global_metrics
+        from bigdl_tpu.serving import (DecodeConfig, HttpClient, HttpFrontend,
+                                       InferenceModel, ServingConfig,
+                                       ServingServer)
+        from bigdl_tpu.serving.decode_engine import DecodeRequest
+
+        c, lmc = self.sz["serve"], self.sz["lm"]
+        if self.lm is None:  # train_lm failed: still serve, from a fresh init
+            model = self._lm_model()
+            self.lm = (model, model.init(jax.random.PRNGKey(0),
+                                         np.zeros((1, 8), np.int32)))
+        model, variables = self.lm
+        if self.dev["count"] > 1:
+            # InferenceModel without layout= is single-device serving
+            variables = jax.device_put(variables, jax.devices()[0])
+            self.say("serve_lm", "one engine on one device "
+                                 f"({jax.devices()[0]}) of "
+                                 f"{self.dev['count']}")
+
+        def build(use_flash):
+            return InferenceModel(
+                model, variables, batch_buckets=(1,),
+                decode=DecodeConfig(
+                    slots=c["slots"], page_size=c["page_size"],
+                    pages_per_slot=c["pages_per_slot"],
+                    prompt_chunk=c["prompt_chunk"],
+                    max_new_tokens=c["max_new"], eos_id=1,
+                    use_flash_decode=use_flash))
+
+        sentinel = recompile_sentinel()
+        m = global_metrics()
+        # None = the engine's own choice, the kernel on TPU; the rehearsal
+        # forces the (interpreted) kernel so the comparison below is real
+        im = build(None if self.dev["platform"] == "tpu" else True)
+        eng = im.decode_engine
+        self.say("serve_lm", f"cap={eng.cfg.cap} "
+                             f"length_buckets={eng.cfg.len_buckets()} "
+                             f"flash_decode={eng._use_flash()}")
+        if self.dev["platform"] == "tpu":
+            assert eng._use_flash(), "TPU engine did not pick the kernel"
+        t0 = time.perf_counter()
+        im.warmup(np.zeros((8,), np.int32))  # raises if a program is refused
+        self.say("serve_lm", f"warmup_seconds={time.perf_counter() - t0:.1f}")
+        srv = ServingServer(im, ServingConfig(batch_size=4)).start()
+        fe = HttpFrontend(srv, port=0, predict_timeout=300.0).start()
+        ref_im = None
+        try:
+            rs = np.random.RandomState(2)
+            lo, hi = c["prompt_lens"]
+            prompts = [rs.randint(2, lmc["vocab"], (int(n),)).astype(np.int32)
+                       for n in rs.randint(lo, hi, (c["requests"],))]
+            compiles0 = m.counter("train.xla_compiles_total")
+            sentinel.mark_steady()
+            answers, errors = {}, {}
+
+            def client(i):
+                try:
+                    answers[i] = HttpClient(fe.url, timeout=300.0).generate(
+                        prompts[i], max_new_tokens=c["max_new"])
+                except Exception as e:  # noqa: BLE001 — counted below
+                    errors[i] = f"{type(e).__name__}: {e}"
+
+            threads = [threading.Thread(target=client, args=(i,))
+                       for i in range(len(prompts))]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=600.0)
+            hung = sum(t.is_alive() for t in threads)
+            compiled = m.counter("train.xla_compiles_total") - compiles0
+            unexpected = m.counter("train.unexpected_recompiles_total")
+            sentinel.mark_warmup()
+            buckets = sorted({eng.cfg.bucket_pages(len(p) + c["max_new"])
+                              for p in prompts})
+            self.say("serve_lm",
+                     f"requests={len(prompts)} answered={len(answers)} "
+                     f"errors={len(errors)} hung={hung} "
+                     f"prompt_lens={[len(p) for p in prompts]} "
+                     f"buckets_reached={buckets} "
+                     f"compiles_after_warmup={compiled:.0f} "
+                     f"unexpected_recompiles={unexpected:.0f} "
+                     f"engine_stats={eng.stats}")
+            assert not errors, errors
+            assert hung == 0
+            assert all(len(answers[i]) >= 1 for i in range(len(prompts)))
+            assert len(buckets) > 1, "only one length bucket was exercised"
+            assert compiled == 0 and unexpected == 0
+
+            # kernel path vs the plain jnp path (use_flash_decode=False):
+            # the shortest and the longest prompt, greedy, through both
+            # engines.  Same tokens (also as HTTP answered them) and summed
+            # log-prob within TOL_LOGP_NATS per token.  Seeds are fixed, so
+            # a near-tied argmax that flips under a numerics change fails
+            # here every time, not sometimes — look at the printed margin.
+            with expected_compile():
+                ref_im = build(False)
+                for i in (int(j) for j in np.argsort(
+                        [len(p) for p in prompts])[[0, -1]]):
+                    rk, rj = (e.submit(DecodeRequest(
+                        tokens=prompts[i], max_new_tokens=c["max_new"])
+                    ).wait(timeout=600.0)
+                        for e in (eng, ref_im.decode_engine))
+                    per_token = abs(rk.logp - rj.logp) / len(rj.tokens)
+                    self.say("serve_lm",
+                             f"prompt_len={len(prompts[i])} kernel_vs_jnp "
+                             f"tokens={rk.tokens.tolist()} vs "
+                             f"{rj.tokens.tolist()} logp={rk.logp:.4f}/"
+                             f"{rj.logp:.4f} per_token_abs_diff="
+                             f"{per_token:.1e} tol={TOL_LOGP_NATS}")
+                    assert np.array_equal(rk.tokens, rj.tokens), \
+                        "kernel and jnp paths chose different tokens"
+                    assert np.array_equal(rk.tokens, answers[i]), \
+                        "HTTP answer differs from the engine's own"
+                    assert per_token <= TOL_LOGP_NATS, per_token
+        finally:
+            fe.stop()
+            srv.stop()
+            eng.stop()
+            if ref_im is not None:
+                ref_im.decode_engine.stop()
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--rehearse-cpu", action="store_true",
+                    help="tiny shapes on the CPU backend; a harness check "
+                         "that never prints the chip result")
+    args = ap.parse_args(argv)
+    if args.rehearse_cpu:
+        os.environ["JAX_PLATFORMS"] = "cpu"  # read when jax is imported
+
+    # stay off the backend until the compile cache is placed
+    from bigdl_tpu.runtime.engine import enable_compile_cache
+
+    cache_dir = enable_compile_cache()
+
+    import jax
+
+    cache = {"requests": 0, "hits": 0}
+
+    def on_cache_event(event, **_):
+        if event == "/jax/compilation_cache/compile_requests_use_cache":
+            cache["requests"] += 1
+        elif event == "/jax/compilation_cache/cache_hits":
+            cache["hits"] += 1
+
+    jax.monitoring.register_event_listener(on_cache_event)
+
+    devices = jax.devices()
+    device = {"platform": devices[0].platform,
+              "kind": devices[0].device_kind, "count": len(devices)}
+    if device["platform"] != ("cpu" if args.rehearse_cpu else "tpu"):
+        print(f"chip_smoke: default backend is {device['platform']!r} "
+              f"({device['kind']}), not a TPU; nothing was built and there "
+              "is no result", file=sys.stderr)
+        return 2
+
+    from bigdl_tpu.native import lib as native
+    from bigdl_tpu.obs.attr import recompile_sentinel
+    from bigdl_tpu.optim.metrics import global_metrics
+
+    recompile_sentinel()  # counts every backend compile from here on
+    smoke = Smoke(TINY if args.rehearse_cpu else FULL, device)
+    smoke.say("setup", f"jax={jax.__version__} compile_cache={cache_dir} "
+                       f"native_lib_in_use={native.available()}")
+    t0 = time.perf_counter()
+    for phase in ("kernels", "train_resnet50", "train_lm", "serve_lm"):
+        smoke.run(phase, getattr(smoke, phase))
+    m = global_metrics()
+    compile_hist = m.hists.get("train.compile_time_s")
+    smoke.say("summary",
+              f"compiles={m.counter('train.xla_compiles_total'):.0f} "
+              f"compile_seconds={compile_hist.sum if compile_hist else 0:.1f} "
+              f"persistent_cache_hits={cache['hits']}/{cache['requests']} "
+              f"wall_seconds={time.perf_counter() - t0:.1f} "
+              + " ".join(f"{k}={'PASS' if v else 'FAIL'}"
+                         for k, v in smoke.results.items()))
+    ok = all(smoke.results.values())
+    if args.rehearse_cpu:
+        print("[chip_smoke] rehearsal on platform=cpu "
+              f"{'passed' if ok else 'FAILED'}: not a chip result")
+        return 0 if ok else 1
+    if not ok:
+        return 1
+    print(json.dumps({"ok": True, "device": device}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

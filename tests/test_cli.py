@@ -112,7 +112,7 @@ def test_cli_pack_npz_and_csv(tmp_path):
 
 def test_cli_doctor_reports_environment():
     env = _repo_env()
-    env["BIGDL_TPU_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     out = subprocess.run(
         [sys.executable, "-m", "bigdl_tpu.cli", "doctor"],
@@ -131,7 +131,7 @@ def test_cli_doctor_honors_dcn_env_and_fails_on_bad_mesh():
     import json
 
     env = _repo_env()
-    env["BIGDL_TPU_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["BIGDL_TPU_DCN_SLICES"] = "2"
     out = subprocess.run([sys.executable, "-m", "bigdl_tpu.cli", "doctor"],

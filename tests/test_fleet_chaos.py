@@ -861,8 +861,7 @@ def _pool_env():
         os.path.abspath(__file__)))
     pythonpath = os.pathsep.join(
         p for p in [repo_root, os.environ.get("PYTHONPATH")] if p)
-    return {"PYTHONPATH": pythonpath, "BIGDL_TPU_POOL_CPU": "1",
-            "JAX_PLATFORMS": "cpu",
+    return {"PYTHONPATH": pythonpath, "JAX_PLATFORMS": "cpu",
             "BIGDL_TPU_TEST_DECODE_SLEEP": "0.05"}
 
 

@@ -1,0 +1,127 @@
+"""Mosaic accepts every Pallas kernel at the chip smoke's widths — checked
+WITHOUT a chip: libtpu compiles ahead of time for a described v5e topology.
+
+This checks the lowering (block shapes, layouts, scratch), not the numbers —
+those need the device (``chip_smoke.py``).  ``slow``: each compile takes
+seconds and libtpu start-up is not free, so tier-1 skips it; run it after
+touching a kernel: ``pytest -m slow tests/test_mosaic_aot.py``.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from chip_smoke import FULL
+
+pytestmark = pytest.mark.slow
+
+_K = FULL["kern"]  # the smoke's widths: the LM's heads, the engine's pages
+S, H, D, PAGE, NB, CHUNK = (_K["slots"], _K["heads"], _K["hd"], _K["page"],
+                            _K["nb"], _K["chunk"])
+FFN_K, FFN_N = _K["ffn"]
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(topology_name="v5e:2x2",
+                                            platform="tpu")
+    except Exception as e:  # noqa: BLE001 — no libtpu on this box
+        pytest.skip(f"no ahead-of-time TPU topology here: {e}")
+    sharding = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=sharding)
+
+
+def _compile(fn, *args):
+    jax.jit(fn).lower(*args).compile()
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+@pytest.mark.parametrize("block_h", [None, 1, 4, 12])
+def test_paged_decode_attention(v5e, quantized, block_h):
+    """block_h=4 of 12 heads: the (1, 4, 64) q/out block of the (S, 12, 64)
+    array is what Mosaic refused before the (S, h/bh, bh, d) view."""
+    from bigdl_tpu.ops.flash_attention import paged_decode_attention
+
+    pages = v5e((S * NB, H, PAGE, D), jnp.int8 if quantized else jnp.float32)
+    scales = [v5e((S * NB,), jnp.float32)] * 2 if quantized else []
+
+    def fn(q, k, v, pt, ln, *sc):
+        kw = dict(k_scales=sc[0], v_scales=sc[1]) if sc else {}
+        return paged_decode_attention(q, k, v, pt, ln, block_h=block_h,
+                                      interpret=False, **kw)
+
+    _compile(fn, v5e((S, H, D), jnp.float32), pages, pages,
+             v5e((S, NB), jnp.int32), v5e((S,), jnp.int32), *scales)
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+def test_paged_verify_attention(v5e, quantized):
+    from bigdl_tpu.ops.flash_attention import paged_verify_attention
+
+    pages = v5e((S * NB, H, PAGE, D), jnp.int8 if quantized else jnp.float32)
+    scales = [v5e((S * NB,), jnp.float32)] * 2 if quantized else []
+
+    def fn(q, k, v, pt, pos, *sc):
+        kw = dict(k_scales=sc[0], v_scales=sc[1]) if sc else {}
+        return paged_verify_attention(q, k, v, pt, pos, interpret=False,
+                                      **kw)
+
+    _compile(fn, v5e((S, H, CHUNK, D), jnp.float32), pages, pages,
+             v5e((S, NB), jnp.int32), v5e((S,), jnp.int32), *scales)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_forward_and_backward(v5e, dtype):
+    from bigdl_tpu.ops.flash_attention import flash_attention
+
+    q = v5e((_K["flash_b"], H, _K["flash_s"], D), dtype)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True,
+                               interpret=False).astype(jnp.float32).sum()
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+
+
+@pytest.mark.parametrize("block,ok", [((128, 128), True), ((64, 64), False),
+                                      ((8, 8), False)])
+def test_block_sparse_matmul_needs_128_blocks(v5e, block, ok):
+    """The selector's shape test (mosaic_tileable) is Mosaic's own rule."""
+    from bigdl_tpu.ops.block_sparse import (block_sparse_matmul,
+                                            mosaic_tileable)
+
+    K, N = FFN_K, FFN_N
+    bk, bn = block
+    mask = np.random.RandomState(0).rand(K // bk, N // bn) < 0.5
+    mask[0, :] = True
+
+    def fn(x, w):
+        return block_sparse_matmul(x, w, mask, block_k=bk, block_n=bn,
+                                   interpret=False)
+
+    args = (v5e((S, K), jnp.bfloat16), v5e((K, N), jnp.bfloat16))
+    assert mosaic_tileable(bk, bn) is ok
+    if ok:
+        _compile(fn, *args)
+    else:
+        with pytest.raises(ValueError, match="divisible by 8 and 128"):
+            _compile(fn, *args)
+
+
+def test_fused_layernorm_and_int8_matmul(v5e):
+    from bigdl_tpu.ops.fused import fused_layernorm
+    from bigdl_tpu.ops.quantized import int8_matmul
+
+    g = v5e((FFN_K,), jnp.float32)
+    _compile(lambda x, g, b: fused_layernorm(x, g, b, interpret=False),
+             v5e((_K["ln_rows"], FFN_K), jnp.float32), g, g)
+    m, k, n = _K["mm"]
+    _compile(lambda a, w: int8_matmul(a, w, interpret=False),
+             v5e((m, k), jnp.int8), v5e((k, n), jnp.int8))

@@ -153,15 +153,10 @@ def test_cost_model_attention_counts_projections_and_scores():
     assert rep.flops == pytest.approx(proj + scores)
 
 
-def test_peak_flops_resolution(monkeypatch):
-    monkeypatch.delenv("BIGDL_TPU_PEAK_FLOPS", raising=False)
+def test_peak_flops_resolution():
     assert obs_cost.peak_flops("TPU v5 lite") == 197e12
     assert obs_cost.peak_flops("TPU v4") == 275e12
     assert obs_cost.peak_flops("cpu") is None
-    assert obs_cost.peak_flops("cpu", override=1e12) == 1e12
-    monkeypatch.setenv("BIGDL_TPU_PEAK_FLOPS", "5e11")
-    # env pin wins over both the table and the explicit override
-    assert obs_cost.peak_flops("TPU v4", override=1e12) == 5e11
     # 1e9 flops / 1ms / 2 chips = 5e11 FLOP/s/chip; peak 1e12 -> 50%
     assert obs_cost.mfu(1e9, 0.001, 2, 1e12) == pytest.approx(0.5)
     assert obs_cost.mfu(1e9, 0.001, 1, None) is None
@@ -196,7 +191,8 @@ def _train(monkeypatch, iterations=12, batch_size=16):
     from bigdl_tpu import nn, optim
     from bigdl_tpu.data import ArrayDataSet
 
-    monkeypatch.setenv("BIGDL_TPU_PEAK_FLOPS", "1e9")
+    # CPU has no peak on record (no gauge); give the test mesh one
+    monkeypatch.setitem(obs_cost.PEAK_BF16_FLOPS, "cpu", 1e9)
     x = np.random.RandomState(0).rand(64, 4).astype(np.float32)
     y = (x.sum(-1) > 2).astype(np.int32)
     model = nn.Sequential([nn.Linear(4, 8), nn.ReLU(), nn.Linear(8, 2),

@@ -481,8 +481,7 @@ def _pool_env(extra=None):
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     pythonpath = os.pathsep.join(
         p for p in [repo_root, os.environ.get("PYTHONPATH")] if p)
-    env = {"PYTHONPATH": pythonpath, "BIGDL_TPU_POOL_CPU": "1",
-           "JAX_PLATFORMS": "cpu"}
+    env = {"PYTHONPATH": pythonpath, "JAX_PLATFORMS": "cpu"}
     env.update(extra or {})
     return env
 

@@ -215,8 +215,7 @@ def test_serving_pool_scaleout_and_supervision():
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     pythonpath = os.pathsep.join(
         p for p in [repo_root, os.environ.get("PYTHONPATH")] if p)
-    env = {"PYTHONPATH": pythonpath, "BIGDL_TPU_POOL_CPU": "1",
-           "JAX_PLATFORMS": "cpu"}
+    env = {"PYTHONPATH": pythonpath, "JAX_PLATFORMS": "cpu"}
     pool = ServingPool("tests.test_serving_multiproc:_pool_loader",
                        workers=2, batch_size=8, worker_env=env,
                        supervise_interval_s=0.3)
