@@ -37,7 +37,12 @@ counters (``data.read_batches`` / ``data.decoded_images`` /
 ``data.ready_batches``), queue-depth gauges (``data.queue_depth.*``),
 per-stage spans (``data/read``, ``data/decode``), and the consumer-side
 ``train.data_wait_s`` histogram recorded by the optimizer — one scrape of
-``/metrics`` shows exactly which stage starves the device.
+``/metrics`` shows exactly which stage starves the device.  The wait
+itself is split where it is spent (:func:`timed_batches`,
+:func:`dispatch_to_device`): ``data.produce_s`` (the producer thread's
+seconds per batch), ``data.batch_wait_s`` (the driver blocked on the
+producer) and ``data.put_s`` (the driver inside the host→device put),
+with spans ``data/produce``, ``data/batch_wait``, ``data/put``.
 """
 
 import math
@@ -633,6 +638,29 @@ class StreamingPipeline:
             self._on_close()
 
 
+def timed_batches(batches: Iterable, stage: str, metrics,
+                  name: str = "data") -> Iterator:
+    """Pass ``batches`` through, timing every ``next()`` on it in the
+    thread that pulls: a ``<name>/<stage>`` span and one observation of
+    the ``<name>.<stage>_s`` histogram per batch (the exhausted pull is
+    not a batch).  Closing the wrapper closes ``batches``."""
+    it = iter(batches)
+    span, hist = f"{name}/{stage}", f"{name}.{stage}_s"
+    try:
+        while True:
+            with trace.timed(span) as t:
+                try:
+                    b = next(it)
+                except StopIteration:
+                    return
+            metrics.observe(hist, t.seconds)
+            yield b
+    finally:
+        close = getattr(batches, "close", None)
+        if close is not None:
+            close()
+
+
 def bundle_batches(batches: Iterable,
                    span: Callable[[], int]) -> Iterator[List[Any]]:
     """Group a device-ready batch iterator into bundles for fused
@@ -709,7 +737,10 @@ def dispatch_to_device(batches: Iterable, put: Callable[[Any], Any],
     ``<name>.dispatch.in_flight`` gauge (window depth) and the
     ``<name>.dispatch_overlapped_total`` counter (transfers issued while
     a previous one was still in the window; 0 means the double buffer
-    never engaged — the regression the bench smoke gates on)."""
+    never engaged — the regression the bench smoke gates on) — and the
+    two halves of every pull, both spent in the PULLING thread:
+    ``<name>.batch_wait_s`` (blocked on the upstream iterator) and
+    ``<name>.put_s`` (inside ``put``)."""
     import collections
 
     import jax
@@ -732,6 +763,16 @@ def dispatch_to_device(batches: Iterable, put: Callable[[Any], Any],
                 rel()
         if metrics is not None:
             metrics.gauge(f"{name}.dispatch.in_flight", len(pending))
+
+    if metrics is not None:
+        batches = timed_batches(batches, "batch_wait", metrics, name)
+        raw_put, put_span, put_hist = put, f"{name}/put", f"{name}.put_s"
+
+        def put(mb):
+            with trace.timed(put_span) as t:
+                dev = raw_put(mb)
+            metrics.observe(put_hist, t.seconds)
+            return dev
 
     def _put(mb):
         defer = getattr(mb, "defer_release", None)

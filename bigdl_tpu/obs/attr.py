@@ -3,23 +3,30 @@
 Reference analog (unverified — mount empty): ``dllib/optim/Metrics.scala``
 logged per-iteration "computing time average / get weights average / put
 gradient" splits; under XLA the iteration is one fused program, so the
-meaningful decomposition is host-side, assembled from the driver's existing
-``train/step|dispatch|data`` spans plus the bundle-edge device sync:
+meaningful decomposition is host-side: what the DRIVER THREAD was doing.
+Six components, each a mutually exclusive interval of that thread, each
+measured where the work happens through one helper
+(:meth:`StepAttribution.phase`: span and histogram observation of the
+same interval):
 
-- **data**     — host time blocked on the input pipeline (device idle,
-  input-bound; the ``train.data_wait_s`` samples)
-- **dispatch** — host time issuing the jitted bundle (python + transfer
-  argument plumbing)
-- **overhead** — trigger work at bundle edges: validation, checkpoint
-  writes, parameter histograms, callbacks
-- **device**   — the residual: device compute the host waited out at the
-  log-point sync (plus any untracked host time — kept honest by the
-  residual construction, the four components sum to the window wall by
-  definition)
+- **data**     — blocked in ``next()`` on the input pipeline
+  (``train.data_wait_s``; split further by ``data.batch_wait_s`` /
+  ``data.put_s``, docs/data.md)
+- **dispatch** — issuing the jitted bundle, compile seconds taken out
+- **compile**  — tracing, lowering and XLA compilation (or the load of a
+  cached program) that fell inside a dispatch
+- **sync**     — the loss fetch at a log point: the host waiting for the
+  device
+- **overhead** — triggers (validation, checkpoints, histograms, the
+  ``end_when`` calls) and the log point's bookkeeping after the fetch
+- **other**    — each loop iteration's wall minus the five above: host time
+  nobody timed.  Reported, never hidden; it should stay near zero
 
-Per-step values land in ``train.attr.*_s`` histograms on ``/metrics``; the
-run total is the end-of-run "where did the time go" table
-(:meth:`StepAttribution.table`).
+Every occurrence lands in its ``train.attr.<name>_s`` histogram on
+``/metrics`` (``data`` keeps its older name); the run total is the
+end-of-run "where did the time go" table (:meth:`StepAttribution.table`).
+:func:`idle_by_phase` carries the same names onto a device trace: idle
+seconds of the chip by what the driver was doing meanwhile.
 
 This module also owns two run-health sentinels:
 
@@ -34,22 +41,64 @@ This module also owns two run-health sentinels:
 """
 
 import threading
+import time
+from collections import defaultdict
 from contextlib import contextmanager
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
 
-from bigdl_tpu.obs import flight
+from bigdl_tpu.obs import flight, trace
 from bigdl_tpu.utils.log import get_logger
 
 log = get_logger("bigdl_tpu.obs")
 
-COMPONENTS = ("data", "dispatch", "device", "overhead")
+COMPONENTS = ("data", "dispatch", "compile", "sync", "overhead", "other")
+# one name per interval: the data phase IS the data wait
+HISTOGRAMS = {c: f"train.attr.{c}_s" for c in COMPONENTS}
+HISTOGRAMS["data"] = "train.data_wait_s"
+
+
+class _Phase:
+    """One occurrence of a driver phase (see :meth:`StepAttribution.phase`)."""
+
+    __slots__ = ("_attr", "_name", "_steps", "_timed", "_compile0")
+
+    def __init__(self, attr: "StepAttribution", name: str, steps: int,
+                 attrs):
+        self._attr = attr
+        self._name = name
+        self._steps = steps
+        self._timed = trace.timed(f"train/{name}", **attrs)
+
+    @property
+    def seconds(self) -> float:
+        """The whole interval (a dispatch's compile seconds included)."""
+        return self._timed.seconds
+
+    def __enter__(self) -> "_Phase":
+        if self._name == "dispatch":
+            self._compile0 = compile_seconds()
+        self._timed.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._timed.__exit__(*exc)
+        secs = self._timed.seconds
+        if self._name == "dispatch":
+            compiled = min(compile_seconds() - self._compile0, secs)
+            if compiled > 0:
+                self._attr.book("compile", compiled)
+                secs -= compiled
+        self._attr.book(self._name, secs)
+        self._attr.steps += self._steps
+        return False
 
 
 class StepAttribution:
-    """Accumulates per-window wall-time decompositions and exports them as
-    ``train.attr.*`` histograms plus an end-of-run table."""
+    """Books every occurrence of a driver phase into its histogram and the
+    run totals, closes each loop iteration on its wall time (``other`` is
+    what the phases left over), and prints the end-of-run table."""
 
     def __init__(self, metrics=None):
         if metrics is None:
@@ -60,31 +109,45 @@ class StepAttribution:
         self.steps = 0
         self.wall_s = 0.0
         self.totals: Dict[str, float] = {c: 0.0 for c in COMPONENTS}
-        self.windows = 0
+        self.windows = 0  # log points seen (one sync each)
+        self._t_iter: Optional[float] = None  # open iteration's start
+        self._booked = 0.0  # phase seconds booked since then
 
-    def window(self, steps: int, wall_s: float, data_s: float,
-               dispatch_s: float, overhead_s: float) -> Dict[str, float]:
-        """Record one log window of ``steps`` steps.  ``device`` is the
-        residual (wall minus the tracked host components), clamped at 0 —
-        so the components always sum back to the window wall (to within
-        the clamp, which only engages when host timers overlap)."""
-        if steps <= 0 or wall_s <= 0:
-            return {}
-        comps = {
-            "data": max(data_s, 0.0),
-            "dispatch": max(dispatch_s, 0.0),
-            "overhead": max(overhead_s, 0.0),
-        }
-        comps["device"] = max(wall_s - sum(comps.values()), 0.0)
-        self.steps += steps
-        self.wall_s += wall_s
-        self.windows += 1
-        for name, v in comps.items():
-            self.totals[name] += v
-            # per-step values: comparable across log cadences and bundle
-            # sizes, like train.step_time_s
-            self.metrics.observe(f"train.attr.{name}_s", v / steps)
-        return comps
+    def phase(self, name: str, steps: int = 0, **attrs) -> _Phase:
+        """Context manager around one occurrence of phase ``name`` on the
+        driver thread: the ``train/<name>`` span (a no-op when the tracer
+        is off; ``attrs`` are its attributes; under ``set_profile()``'s
+        own trace it is recorded for the idle-by-phase table whatever the
+        tracer) and, always, the elapsed seconds into the phase's
+        histogram — one interval, so span and counter cannot disagree.
+        A ``dispatch`` names the train ``steps`` it issues and books the
+        compile seconds that fell inside it under ``compile`` instead."""
+        return _Phase(self, name, steps, attrs)
+
+    def book(self, name: str, seconds: float) -> None:
+        self.totals[name] += seconds
+        self._booked += seconds
+        if name == "sync":
+            self.windows += 1
+        self.metrics.observe(HISTOGRAMS[name], seconds)
+
+    def begin(self, now: Optional[float] = None) -> None:
+        """The loop starts (or restarts after a recovery) NOW."""
+        self._t_iter = time.perf_counter() if now is None else now
+        self._booked = 0.0
+
+    def end_iteration(self, now: Optional[float] = None) -> None:
+        """One pass of the driver loop ends NOW: its wall time minus what
+        its phases booked is ``other``.  The phases are disjoint intervals
+        inside the iteration, so ``other`` is the host time between them
+        and cannot be negative."""
+        if self._t_iter is None:
+            return
+        now = time.perf_counter() if now is None else now
+        wall = now - self._t_iter
+        self.book("other", wall - self._booked)
+        self.wall_s += wall
+        self._t_iter, self._booked = now, 0.0
 
     def report(self) -> Dict[str, Any]:
         """Run totals + fractions — the machine-readable table."""
@@ -103,7 +166,7 @@ class StepAttribution:
 
     def table(self) -> str:
         """The end-of-run "where did the time go" table (logged by the
-        driver; first window includes compile, which lands in device)."""
+        driver)."""
         rep = self.report()
         lines = [
             f"step-time attribution over {rep['steps']} steps "
@@ -117,6 +180,96 @@ class StepAttribution:
                 f"  {name:<10} {c['total_s']:>10.3f} "
                 f"{c['per_step_s'] * 1e3:>12.3f} {c['fraction']:>8.1%}")
         return "\n".join(lines)
+
+
+# ---------------------------------------------------------------------------
+# device idle time, by what the driver was doing
+# ---------------------------------------------------------------------------
+
+Interval = Tuple[float, float, str]
+
+
+def _innermost(intervals: Iterable[Interval]) -> List[Interval]:
+    """Properly nested ``(start, end, name)`` intervals of ONE thread ->
+    disjoint segments in time order, each named by the innermost interval
+    that covers it (``data/put`` inside ``train/data`` wins there)."""
+    out: List[Interval] = []
+    stack: List[Tuple[float, str]] = []  # (end, name), outermost first
+    cur = 0.0
+
+    def close(t: float) -> None:
+        nonlocal cur
+        if t > cur:
+            out.append((cur, t, stack[-1][1]))
+        cur = max(cur, t)
+
+    for s, e, name in sorted(intervals, key=lambda iv: (iv[0], -iv[1])):
+        while stack and stack[-1][0] <= s:
+            close(stack[-1][0])
+            stack.pop()
+        if stack:
+            close(s)
+        else:
+            cur = s
+        stack.append((e, name))
+    while stack:
+        close(stack[-1][0])
+        stack.pop()
+    return out
+
+
+def clock_offset(dispatch_starts: Iterable[float],
+                 program_starts: Iterable[float]) -> Optional[float]:
+    """Host clock minus device-trace clock, from the k-th ``train/dispatch``
+    span and the k-th run of the step program on the device: a program
+    cannot start before the call that issued it began, so the offset is
+    the largest ``dispatch start - program start``.  Its error is the
+    launch latency of that one call (well under a dispatch's few ms).
+    None unless the two counts agree: steps were in flight when the trace
+    started or stopped, and the pairing would be a guess."""
+    d, p = list(dispatch_starts), list(program_starts)
+    if not d or len(d) != len(p):
+        return None
+    return max(a - b for a, b in zip(d, p))
+
+
+def idle_by_phase(device_intervals: Iterable[Tuple[float, float]],
+                  host_intervals: Iterable[Interval]) -> Optional[dict]:
+    """Union-and-gaps walk over one chip's op intervals ``(start, end)``,
+    each idle gap's length divided among the driver-thread intervals
+    ``(start, end, name)`` that cover it (nested ones: the innermost);
+    what no interval covers goes under ``"none"``.  Both on one clock, in
+    one unit, which is the unit of the result: ``{"busy", "idle",
+    "window", "by_phase": {name: idle}}``.  Pure; None without ops."""
+    dev = sorted((float(iv[0]), float(iv[1])) for iv in device_intervals)
+    if not dev:
+        return None
+    gaps, busy = [], 0.0
+    cur_s, cur_e = dev[0]
+    for s, e in dev[1:]:
+        if s > cur_e:
+            busy += cur_e - cur_s
+            gaps.append((cur_e, s))
+            cur_s, cur_e = s, e
+        else:
+            cur_e = max(cur_e, e)
+    busy += cur_e - cur_s
+    segs = _innermost(host_intervals)
+    by: Dict[str, float] = defaultdict(float)
+    i = 0
+    for g0, g1 in gaps:
+        while i < len(segs) and segs[i][1] <= g0:
+            i += 1
+        covered, j = 0.0, i
+        while j < len(segs) and segs[j][0] < g1:
+            s, e, name = segs[j]
+            overlap = min(g1, e) - max(g0, s)
+            by[name] += overlap
+            covered += overlap
+            j += 1
+        by["none"] += (g1 - g0) - covered
+    return {"busy": busy, "idle": sum(g1 - g0 for g0, g1 in gaps),
+            "window": cur_e - dev[0][0], "by_phase": dict(by)}
 
 
 # ---------------------------------------------------------------------------
@@ -142,6 +295,37 @@ def expected_compile():
         _expected.depth = _expected_depth() - 1
 
 
+_compiling = threading.local()  # .total seconds, .spans [(start, end)]
+
+
+def compile_seconds() -> float:
+    """Seconds the CALLING thread has spent tracing, lowering and
+    compiling (or loading cached programs) so far: the union of the
+    intervals of every ``/jax/core/compile/*_duration`` event it emitted.
+    A phase takes the difference around a call to learn how much of the
+    call was compilation."""
+    return getattr(_compiling, "total", 0.0)
+
+
+def _note_compile(duration_s: float) -> None:
+    # the listener runs on the compiling thread as the timed region ends,
+    # so the region is [now - duration, now].  Regions nest (an inner
+    # jit's trace inside the outer's) and arrive inner first: a later one
+    # swallows the earlier ones it contains, so nothing counts twice
+    now = time.perf_counter()
+    start = now - duration_s
+    spans = _compiling.__dict__.setdefault("spans", [])
+    total = compile_seconds()
+    while spans and spans[-1][0] >= start:
+        a, b = spans.pop()
+        total -= b - a
+    if spans and spans[-1][1] > start:
+        start = spans[-1][1]
+    spans.append((start, now))
+    del spans[:-64]
+    _compiling.total = total + (now - start)
+
+
 class RecompileSentinel:
     """Counts XLA backend compiles via ``jax.monitoring`` events.
 
@@ -155,6 +339,9 @@ class RecompileSentinel:
     multiplies step time."""
 
     EVENT = "/jax/core/compile/backend_compile_duration"
+    # tracing, lowering and backend compilation: what compile_seconds()
+    # totals per thread (the step attribution's ``compile`` phase)
+    COMPILE_PREFIX = "/jax/core/compile/"
 
     def __init__(self):
         self._steady = False
@@ -174,6 +361,8 @@ class RecompileSentinel:
         return self
 
     def _on_event(self, name: str, duration_s: float, **kw) -> None:
+        if name.startswith(self.COMPILE_PREFIX):
+            _note_compile(float(duration_s))
         if name != self.EVENT:
             return
         try:

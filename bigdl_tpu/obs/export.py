@@ -77,15 +77,20 @@ def _fmt(v: float) -> str:
 DEFAULT_HELP = {
     "train.step_time_s": "step wall time (window mean at coarse log "
                          "cadence)",
-    "train.data_wait_s": "host time blocked on the input pipeline per "
-                         "fetch (input-bound signal)",
-    "train.attr.data_s": "per-step attributed time: input-pipeline wait",
-    "train.attr.dispatch_s": "per-step attributed time: host dispatch of "
-                             "the jitted step",
-    "train.attr.device_s": "per-step attributed time: device compute "
-                           "(residual at the log-point sync)",
-    "train.attr.overhead_s": "per-step attributed time: trigger work "
-                             "(validation/checkpoint/callbacks)",
+    "train.data_wait_s": "driver phase data: host time blocked on the "
+                         "input pipeline per fetch (input-bound signal)",
+    "train.attr.dispatch_s": "driver phase dispatch: issuing the jitted "
+                             "bundle, compile seconds taken out",
+    "train.attr.compile_s": "driver phase compile: tracing, lowering and "
+                            "XLA compilation (or cache load) inside a "
+                            "dispatch",
+    "train.attr.sync_s": "driver phase sync: the loss fetch at a log "
+                         "point, the host waiting for the device",
+    "train.attr.overhead_s": "driver phase overhead: triggers "
+                             "(validation/checkpoint/callbacks, end_when) "
+                             "and log-point bookkeeping",
+    "train.attr.other_s": "per loop iteration: wall time no driver phase "
+                          "covered (should stay near zero)",
     "train.mfu": "live model-flop utilization (analytic cost model over "
                  "the device-kind bf16 peak); DENSE-EQUIVALENT under "
                  "block sparsity — see train.effective_mfu",
@@ -372,6 +377,15 @@ DEFAULT_HELP = {
                                 "were free — high means the read stage "
                                 "caps the pipeline (a full ring, i.e. a "
                                 "slow consumer, does not count here)",
+    "data.produce_s": "producer thread: seconds to make one batch "
+                      "(can the producer keep pace with the step?)",
+    "data.batch_wait_s": "driver thread blocked on the producer per pull "
+                         "(part of train.data_wait_s)",
+    "data.put_s": "driver thread inside the host-to-device put per batch "
+                  "(part of train.data_wait_s)",
+    "data.epoch_first_wait_s": "the first pull of an epoch's iterator: "
+                               "the epoch-boundary stall (also counted in "
+                               "train.data_wait_s)",
     "data.dispatch.in_flight": "host-to-device transfers still unsynced "
                                "in the dispatch double-buffer window",
     "data.dispatch_overlapped_total": "transfers issued while a previous "

@@ -5,7 +5,7 @@ Reference analog (unverified — mount empty): the reference's per-iteration
 it cannot correlate one serving request (or one training step) across
 subsystems.  Spans do: every span has a ``span_id``, a ``parent_id`` (the
 context-local current span at creation), a ``trace_id`` shared by the whole
-tree, wall-clock start/duration, and free-form attributes.  Serving spans
+tree, start/end stamps, and free-form attributes.  Serving spans
 additionally carry ``request_id`` so the enqueue→batch→predict→publish path
 of one request joins across the client thread / engine thread boundary,
 where parent links cannot reach (the batch loop serves many requests at
@@ -14,6 +14,14 @@ once — correlation there is by attribute, by design).
 Export is the Chrome trace-event format (``{"traceEvents": [...]}``, phase
 ``"X"`` complete events) which Perfetto and ``chrome://tracing`` load
 directly; span ids/attributes ride in ``args``.
+
+Clock: spans stamp ``time.monotonic_ns()`` (``start_ns``/``end_ns``) — the
+clock the benchmark's marks use (``time.monotonic()``), one clock for
+every process of the machine, and one that cannot step.  Each ``Tracer``
+keeps a single wall-clock anchor taken at construction; ``start_s`` /
+``end_s`` and the Chrome export's ``ts`` are the monotonic stamps carried
+to wall-clock seconds through that anchor, and the export also carries the
+raw stamps in ``args`` (``mono_start_ns``/``mono_end_ns``).
 
 Cost when disabled: one module-global ``None`` check per ``span()`` call
 (the same posture as ``resilience.faults.fire``).  Enable programmatically
@@ -43,8 +51,8 @@ class Span:
     module-level ``span``); ``set_attribute`` adds attributes mid-flight
     (e.g. a request id only known after admission)."""
 
-    __slots__ = ("name", "span_id", "parent_id", "trace_id", "start_s",
-                 "end_s", "attrs", "_tracer", "_token", "_tid")
+    __slots__ = ("name", "span_id", "parent_id", "trace_id", "start_ns",
+                 "end_ns", "attrs", "_tracer", "_token", "_tid")
 
     def __init__(self, tracer: "Tracer", name: str, span_id: str,
                  parent_id: Optional[str], trace_id: str,
@@ -54,11 +62,20 @@ class Span:
         self.parent_id = parent_id
         self.trace_id = trace_id
         self.attrs = attrs
-        self.start_s = 0.0
-        self.end_s = 0.0
+        self.start_ns = 0  # time.monotonic_ns()
+        self.end_ns = 0
         self._tracer = tracer
         self._token = None
         self._tid = threading.get_ident()
+
+    @property
+    def start_s(self) -> float:
+        """Wall-clock seconds (through the tracer's anchor)."""
+        return self._tracer.wall_s(self.start_ns)
+
+    @property
+    def end_s(self) -> float:
+        return self._tracer.wall_s(self.end_ns) if self.end_ns else 0.0
 
     def set_attribute(self, key: str, value: Any) -> "Span":
         self.attrs[key] = value
@@ -66,7 +83,7 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._token = _current.set(self)
-        self.start_s = time.time()
+        self.start_ns = time.monotonic_ns()
         return self
 
     def end(self) -> "Span":
@@ -75,9 +92,9 @@ class Span:
         completion to the client: ending before that write guarantees a
         reader reacting to the completion event sees the span exported,
         instead of racing the handler thread to the context exit."""
-        if self.end_s:
+        if self.end_ns:
             return self
-        self.end_s = time.time()
+        self.end_ns = time.monotonic_ns()
         if self._token is not None:
             _current.reset(self._token)
             self._token = None
@@ -121,6 +138,16 @@ class Tracer:
         self._spans: "deque[Span]" = deque(maxlen=max_spans)
         self._lock = threading.Lock()
         self._ids = itertools.count(1)
+        # the one wall-clock reading: (wall seconds, monotonic ns) of the
+        # same moment.  Everything exported as wall time goes through it
+        self._anchor = (time.time(), time.monotonic_ns())
+
+    def wall_s(self, mono_ns: int) -> float:
+        """A ``time.monotonic_ns()`` stamp as wall-clock seconds."""
+        return self._anchor[0] + (mono_ns - self._anchor[1]) * 1e-9
+
+    def _mono_ns(self, wall_s: float) -> int:
+        return self._anchor[1] + int(round((wall_s - self._anchor[0]) * 1e9))
 
     def _next_id(self) -> str:
         with self._lock:
@@ -140,16 +167,25 @@ class Tracer:
 
     def add_event(self, name: str, start_s: float, end_s: float,
                   **attrs) -> Span:
-        """Append an explicitly-timed span — for call sites that time a
+        """Append an explicitly-timed span (``start_s``/``end_s`` in
+        wall-clock ``time.time()`` seconds, carried onto the span clock
+        through the anchor) — for call sites that time a
         region themselves (the decode engine's per-token steps span a
         jitted call shared by many requests; each request's event carries
         the same wall window with its own ``request_id``).  No
         contextvars involvement: these events correlate by attribute, not
         by parent link (docs/observability.md §Decode timelines)."""
+        return self.add_span(name, self._mono_ns(float(start_s)),
+                             self._mono_ns(float(end_s)), **attrs)
+
+    def add_span(self, name: str, start_ns: int, end_ns: int,
+                 **attrs) -> Span:
+        """:meth:`add_event` for a region stamped on the span clock itself
+        (``time.monotonic_ns()``), by the thread that ran it."""
         sid = self._next_id()
         s = Span(self, name, sid, None, sid, attrs)
-        s.start_s = float(start_s)
-        s.end_s = float(max(end_s, start_s))
+        s.start_ns = int(start_ns)
+        s.end_ns = max(int(end_ns), s.start_ns)
         self._finish(s)
         return s
 
@@ -167,14 +203,15 @@ class Tracer:
         events = []
         pid = os.getpid()
         for s in self.spans():
-            args = {"span_id": s.span_id, "trace_id": s.trace_id}
+            args = {"span_id": s.span_id, "trace_id": s.trace_id,
+                    "mono_start_ns": s.start_ns, "mono_end_ns": s.end_ns}
             if s.parent_id is not None:
                 args["parent_id"] = s.parent_id
             args.update(s.attrs)
             events.append({
                 "name": s.name, "cat": s.name.split("/", 1)[0], "ph": "X",
                 "ts": s.start_s * 1e6,
-                "dur": max(s.end_s - s.start_s, 0.0) * 1e6,
+                "dur": max(s.end_ns - s.start_ns, 0) * 1e-3,
                 "pid": pid, "tid": s._tid, "args": args})
         return {"traceEvents": events, "displayTimeUnit": "ms"}
 
@@ -271,3 +308,49 @@ def span(name: str, **attrs):
     None check after the lazy env probe)."""
     t = active()
     return _NULL if t is None else t.span(name, **attrs)
+
+
+# -- timed regions -----------------------------------------------------------
+
+_collector: Optional[Tracer] = None  # a program-owned device trace is running
+
+
+def collect_into(tracer: Optional[Tracer]) -> None:
+    """``utils.profiling.IterationProfiler`` hands over a tracer of its own
+    while ITS ``jax.profiler`` trace runs (None when it stops): every
+    :class:`timed` region is then also recorded there, whether or not the
+    process tracer is on, so the program's phases can be laid over the
+    device's ops.  Otherwise (the default, and under anyone else's
+    profiler) a region checks this one variable and does nothing more."""
+    global _collector
+    _collector = tracer
+
+
+class timed:
+    """One instrumented region that is span and stopwatch at once, so the
+    two cannot disagree about where it starts and ends: opens
+    ``span(name, **attrs)`` (a no-op when the tracer is off), is recorded
+    under :func:`collect_into`, and always leaves the elapsed
+    ``time.perf_counter()`` seconds in ``.seconds``."""
+
+    __slots__ = ("name", "attrs", "seconds", "_span", "_ns0", "_t0")
+
+    def __init__(self, name: str, **attrs):
+        self.name = name
+        self.attrs = attrs
+        self.seconds = 0.0
+
+    def __enter__(self) -> "timed":
+        self._span = span(self.name, **self.attrs).__enter__()
+        self._ns0 = time.monotonic_ns() if _collector is not None else 0
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.seconds = time.perf_counter() - self._t0
+        c = _collector
+        if c is not None and self._ns0:
+            c.add_span(self.name, self._ns0, time.monotonic_ns(),
+                       **self.attrs)
+        self._span.__exit__(*exc)
+        return False

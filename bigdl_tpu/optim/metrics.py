@@ -138,12 +138,6 @@ class Metrics:
         with self._lock:
             return self.counters.get(name, 0.0)
 
-    def total(self, name: str) -> float:
-        """Sum of a per-window timer (``add``) since the last ``reset`` —
-        the attribution layer reads window totals, not means."""
-        with self._lock:
-            return self.sums.get(name, 0.0)
-
     def mean(self, name: str) -> float:
         with self._lock:
             c = self.counts.get(name, 0)

@@ -27,7 +27,7 @@ from bigdl_tpu.obs import attr as obs_attr
 from bigdl_tpu.obs import cost as obs_cost
 from bigdl_tpu.obs import flight, trace
 from bigdl_tpu.optim import checkpoint as ckpt
-from bigdl_tpu.optim.metrics import Metrics, SummaryWriter, Timer
+from bigdl_tpu.optim.metrics import Metrics, SummaryWriter
 from bigdl_tpu.optim.optim_method import OptimMethod, SGD
 from bigdl_tpu.optim.train_step import (
     GradientClipping, ShardedParameterStep, host_fetch, put_sharded,
@@ -224,13 +224,9 @@ class Optimizer:
         self._last_dispatch_end: Optional[float] = None
         self._inflight = 0
         # perf attribution (docs/observability.md §Step-time attribution):
-        # per-window wall-time decomposition + live MFU/collective-bytes
+        # the driver thread's time by phase + live MFU/collective-bytes
         # accounting, resolved per optimize() run
-        self.attribution: Optional[obs_attr.StepAttribution] = None
-        self._attr_t0: Optional[float] = None
-        self._attr_prev_it = 0
-        self._attr_dispatch = 0.0
-        self._attr_overhead = 0.0
+        self.attribution = obs_attr.StepAttribution(self.metrics)
         self._flops_per_step: Optional[float] = None
         self._eff_flops_per_step: Optional[float] = None
         self._peak_flops: Optional[float] = None
@@ -510,9 +506,6 @@ class Optimizer:
         recompilation sentinel.  Best-effort — a cost-model failure
         degrades observability, never training."""
         self.attribution = obs_attr.StepAttribution(self.metrics)
-        self._attr_t0 = None
-        self._attr_dispatch = 0.0
-        self._attr_overhead = 0.0
         self._recompile = obs_attr.recompile_sentinel()
         self._recompile.mark_warmup()
         self._flops_per_step = None
@@ -559,9 +552,9 @@ class Optimizer:
         retries_by_cause: Dict[Any, int] = {}
         max_retries = engine.config.failure_retry_times
         t_loop = time.perf_counter()
-        self._attr_t0 = t_loop
-        self._attr_prev_it = state["iteration"]
-        while not self.end_when(state):
+        attribution = self.attribution
+        attribution.begin(t_loop)
+        while not self._end_reached(state):
             if self._preempted:
                 # signal landed during epoch-boundary work (validation,
                 # triggers) — still honour the save-before-stop contract
@@ -606,16 +599,15 @@ class Optimizer:
                     self._one_bundle(step_engine, state, mbs)
                     if self._should_log(prev_it, state["iteration"]):
                         self._log_progress(state, t_loop)
-                    t_trig = time.perf_counter()
-                    self._fire_triggers(step_engine, state)
-                    trig_dt = time.perf_counter() - t_trig
-                    # attribution: trigger work is the "overhead" component
-                    self._attr_overhead += trig_dt
+                    # attribution: trigger work is the "overhead" phase
+                    with attribution.phase("overhead") as trig:
+                        self._fire_triggers(step_engine, state)
                     # trigger work (validation/checkpoint/histograms) is not
                     # step time: shift the log window start past it
                     if getattr(self, "_last_log", None) is not None:
-                        self._last_log = (self._last_log[0] + trig_dt,
-                                          self._last_log[1])
+                        self._last_log = (
+                            self._last_log[0] + trig.seconds,
+                            self._last_log[1])
                     if self.cluster is not None \
                             and self.cluster.preempt_pending \
                             and not self._preempted:
@@ -638,7 +630,9 @@ class Optimizer:
                             self.cluster.notify_preemption()
                         self._save_checkpoint_once(step_engine, state)
                         break
-                    if self.end_when(state):
+                    done = self._end_reached(state)
+                    attribution.end_iteration()
+                    if done:
                         break
                 else:
                     # epoch boundary: fire epoch triggers while `epoch` still
@@ -650,7 +644,8 @@ class Optimizer:
                     # would double-feed plateau schedules.
                     if ran_any or skip == 0 or reshard is not None:
                         state["epoch_finished"] = True
-                        self._fire_triggers(step_engine, state)
+                        with attribution.phase("overhead"):
+                            self._fire_triggers(step_engine, state)
                     state["epoch"] += 1
                     # a resharded epoch's plan dies with the epoch: later
                     # epochs use the normal (seed, epoch, process_count)
@@ -665,6 +660,7 @@ class Optimizer:
                 # resume point.
                 retries += 1
                 t_fail = time.perf_counter()
+                attribution.end_iteration(t_fail)
                 # dispatched-but-unfetched bundle results are part of the
                 # rolled-back step chain; drop them so the next log window
                 # never feeds pre-failure losses to the watchdog
@@ -716,17 +712,12 @@ class Optimizer:
                     self.cluster.note_recovered(
                         time.perf_counter() - t_fail)
                 self._last_log = None  # don't count recovery in step time
-                # recovery is not attributable step time either: restart
-                # the attribution window at the resumed iteration, and
-                # clear the per-window timers (data_time et al.) with it —
-                # pre-failure data waits in a post-recovery window would
-                # over-attribute input time against the restarted wall
+                # recovery is not attributable step time either: the failed
+                # pass was closed where it failed, the next one starts here
                 self.metrics.reset()
-                self._attr_t0 = time.perf_counter()
-                self._attr_prev_it = state["iteration"]
-                self._attr_dispatch = 0.0
-                self._attr_overhead = 0.0
+                attribution.begin()
 
+        attribution.end_iteration()  # the tail: the last end_when call
         if self._recompile is not None:
             # the step loop is over: run-tail work (final checkpoint,
             # get_variables' unravel ops) compiles legitimately
@@ -744,7 +735,7 @@ class Optimizer:
                 log.error("synchronous checkpoint retry also failed: %s", e2)
         variables = step_engine.get_variables()
         self._final_state = dict(state)  # observability: final step/epoch
-        if self.attribution is not None and self.attribution.steps:
+        if attribution.steps:
             # the end-of-run "where did the time go" table; also available
             # programmatically via Optimizer.attribution.report()
             log.info("%s", self.attribution.table())
@@ -771,7 +762,7 @@ class Optimizer:
         the rest re-stride over the new process set
         (``DataSet.resharded_batches``); later epochs revert to the
         normal plan."""
-        from bigdl_tpu.data.pipeline import dispatch_to_device
+        from bigdl_tpu.data.pipeline import dispatch_to_device, timed_batches
 
         engine = Engine.get()
         kw = dict(shuffle=True, seed=self.seed, epoch=epoch,
@@ -791,6 +782,12 @@ class Optimizer:
                 close = getattr(inner, "close", None)
                 if close is not None:
                     close()
+
+        def _lookahead(batch_iter):
+            # the producer thread; data.produce_s is ITS seconds per batch
+            return thread_prefetch(
+                timed_batches(batch_iter, "produce", self.metrics),
+                depth=self.host_prefetch)
 
         def _dispatch(batch_iter):
             # dispatch lookahead: host→device DMA double-buffers behind
@@ -825,8 +822,7 @@ class Optimizer:
             if skip:
                 batch_iter = _skip_closing(batch_iter, skip)
             if self.host_prefetch and not stream:
-                batch_iter = thread_prefetch(batch_iter,
-                                             depth=self.host_prefetch)
+                batch_iter = _lookahead(batch_iter)
             return _dispatch(batch_iter)
         stream = (self.streaming and self.host_prefetch > 0
                   and hasattr(self.dataset, "stream_batches"))
@@ -846,25 +842,34 @@ class Optimizer:
             # (Never stacked on the streaming path: buffering RingBatches
             # in a queue would let their slots be recycled under the
             # consumer; the ring provides the lookahead there.)
-            batch_iter = thread_prefetch(batch_iter,
-                                         depth=self.host_prefetch)
+            batch_iter = _lookahead(batch_iter)
         return _dispatch(batch_iter)
 
+    def _end_reached(self, state) -> bool:
+        with self.attribution.phase("overhead"):
+            return self.end_when(state)
+
     def _traced_data(self, batch_iter):
-        """The data phase under a span + timer: each ``next()`` on the
-        prefetch pipeline is host time the device spends idle.  Waits land
-        in the ``train.data_wait_s`` histogram — the /metrics signal that a
-        run is input-bound rather than device-bound."""
+        """The data phase: each ``next()`` on the prefetch pipeline is
+        host time the device may spend idle.  Waits land in the
+        ``train.data_wait_s`` histogram — the /metrics signal that a run is
+        input-bound rather than device-bound; ``data.batch_wait_s`` and
+        ``data.put_s`` (data/pipeline.py) say which part of the pipeline.
+        The first pull of an epoch's iterator starts its producer and
+        refills the lookahead: it is ALSO observed in
+        ``data.epoch_first_wait_s``, the epoch-boundary stall."""
         it = iter(batch_iter)
+        first = True
         while True:
-            with trace.span("train/data"), Timer(self.metrics, "data_time"):
-                t0 = time.perf_counter()
+            with self.attribution.phase("data") as wait:
                 try:
                     mb = next(it)
                 except StopIteration:
                     return
-                self.metrics.observe("train.data_wait_s",
-                                     time.perf_counter() - t0)
+            if first:
+                self.metrics.observe("data.epoch_first_wait_s",
+                                     wait.seconds)
+                first = False
             yield mb
 
     def _bundle_span(self, state) -> int:
@@ -924,18 +929,20 @@ class Optimizer:
             for j in range(k):
                 with trace.span("train/step", step=it0 + j):
                     if self._profiler is not None:
-                        self._profiler.step(it0 + j)
+                        # starting, stopping and reading back a trace is
+                        # driver time: booked, not left to "other"
+                        with self.attribution.phase("overhead"):
+                            self._profiler.step(it0 + j)
             xs = [mb[0] for mb in mbs]
             ys = [mb[1] for mb in mbs]
-            with trace.span("train/dispatch", step=it0, size=k):
-                t0 = time.perf_counter()
+            with self.attribution.phase("dispatch", steps=k, step=it0,
+                                        size=k) as disp:
                 losses, gnorms = step_engine.train_bundle_device(
                     it0, xs, ys)
-                disp_dt = time.perf_counter() - t0
-                # per-step normalized so the mean stays comparable
-                # across bundle sizes (the auto-K pick reads it)
-                self.metrics.add("step_dispatch", disp_dt / k)
-                self._attr_dispatch += disp_dt
+                last_loss = losses[-1]  # a device op of its own
+            # per-step normalized so the mean stays comparable across
+            # bundle sizes (the auto-K pick reads it)
+            self.metrics.add("step_dispatch", disp.seconds / k)
         self._last_dispatch_end = time.perf_counter()
         if self._recompile is not None:
             self._recompile.note_step(it0 + k)
@@ -951,7 +958,7 @@ class Optimizer:
         self._inflight += k
         self.metrics.gauge("train.steps_in_flight", self._inflight)
         self.metrics.gauge("train.bundle_size", k)
-        state["loss"] = losses[-1]  # device scalar; float() when read
+        state["loss"] = last_loss  # device scalar; float() when read
         state["iteration"] = it0 + k
         state["epoch_batch"] = state.get("epoch_batch", 0) + k
 
@@ -967,10 +974,16 @@ class Optimizer:
         # the wall-clock window between log points measures real step
         # time — not async dispatch time, which flatters when the in-flight
         # queue hides device latency.
-        with trace.span("train/device_sync", step=it):
+        with self.attribution.phase("sync", step=it):
             pending, self._pending_losses = self._pending_losses, []
             fetched = jax.device_get([(lv, gv) for _, lv, gv in pending])
             loss = float(state["loss"])
+        with self.attribution.phase("overhead"):
+            self._record_progress(state, it, loss, pending, fetched)
+
+    def _record_progress(self, state, it, loss, pending, fetched):
+        """The log point after the fetch: curves, watchdog, step time,
+        gauges, the log line."""
         state["loss"] = loss
         self._inflight = 0
         self.metrics.gauge("train.steps_in_flight", 0)
@@ -1009,7 +1022,7 @@ class Optimizer:
         if self._bundle_auto and not self._bundle_picked \
                 and dt_is_wall and dt > 0:
             self._pick_bundle_size(dt)
-        self._account_window(it, now, dt, dt_is_wall)
+        self._account_window(it, dt, dt_is_wall)
         self.metrics.reset()  # rolling window: throughput reflects recent steps
         lr = float(np.asarray(self.optim_method.get_learning_rate(it - 1)))
         throughput = self.batch_size / max(dt, 1e-9)
@@ -1020,32 +1033,18 @@ class Optimizer:
             self._train_summary.add_scalar("lr", lr, it)
             self._train_summary.add_scalar("throughput", throughput, it)
 
-    def _account_window(self, it: int, now: float, dt: float,
+    def _account_window(self, it: int, dt: float,
                         dt_is_wall: bool) -> None:
-        """Close one attribution window at a log point: decompose the
-        window's wall time into data/dispatch/device/overhead, export the
-        live MFU gauge, and (multi-process) the straggler-skew gauges.
-        Reads the per-window timers BEFORE the caller's metrics.reset().
+        """A log point's gauges: live MFU and (multi-process) straggler
+        skew; after two log points the recompile sentinel goes steady.
         ``dt_is_wall=False`` marks the dispatch-mean proxy windows (first
         window, first after recovery): a proxy dt is ~1000x the true wall
         off on real hardware, so MFU/straggler gauges skip those — the
         warmup is symmetric across hosts, so the allgather stays matched."""
-        steps_w = it - self._attr_prev_it
-        t0 = self._attr_t0
-        if steps_w > 0 and t0 is not None and self.attribution is not None:
-            self.attribution.window(
-                steps_w, now - t0,
-                data_s=self.metrics.total("data_time"),
-                dispatch_s=self._attr_dispatch,
-                overhead_s=self._attr_overhead)
-        self._attr_t0 = now
-        self._attr_prev_it = it
-        self._attr_dispatch = 0.0
-        self._attr_overhead = 0.0
-        if self._recompile is not None and self.attribution is not None \
+        if self._recompile is not None \
                 and self.attribution.windows >= 2 \
                 and not self._recompile.steady:
-            # warmup is over after TWO full windows: the first holds the
+            # warmup is over after TWO log points: the first holds the
             # train-program compile, the second flushes the log-point's
             # own eager-op compiles (LR schedule math, summary plumbing).
             # New bundle-size/eval programs announce themselves via

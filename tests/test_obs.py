@@ -244,15 +244,15 @@ def test_render_prometheus_new_perf_gauge_lines_parse():
     m.gauge("train.collective_ici_bytes_per_step", 204e6)
     m.inc("train.collective_ici_bytes_total", 204e6 * 10)
     for v in (0.01, 0.02):
-        m.observe("train.attr.device_s", v)
+        m.observe("train.attr.sync_s", v)
     text = render_prometheus(m)
     for line in text.strip().split("\n"):
         assert _LINE.match(line), f"unparseable exposition line: {line!r}"
     assert "# TYPE train_mfu gauge" in text
     assert re.search(r"^train_mfu 0\.187$", text, re.M)
     assert "# HELP train_mfu " in text
-    assert "# TYPE train_attr_device_s histogram" in text
-    assert 'train_attr_device_s_bucket{le="+Inf"} 2' in text
+    assert "# TYPE train_attr_sync_s histogram" in text
+    assert 'train_attr_sync_s_bucket{le="+Inf"} 2' in text
     assert re.search(r"^train_collective_ici_bytes_total 2040000000\.0$",
                      text, re.M)
 
