@@ -162,6 +162,34 @@ def resharded_batch_index_plan(n: int, batch_size: int, *,
         yield sel, n_real
 
 
+def gather_rows(src: np.ndarray, sel: np.ndarray,
+                out: np.ndarray) -> np.ndarray:
+    """Rows ``sel`` of ``src`` into the preallocated ``out`` (a ring slot,
+    a staging buffer), with no temporary.  ``np.take(..., out=)`` at its
+    default ``mode="raise"`` buffers the WHOLE output so that a bad index
+    leaves ``out`` untouched (PERF.md §7: 258 ms against 15 for 154 MB), so
+    the indices are checked here, once, and the copy runs unchecked."""
+    sel = np.asarray(sel)
+    if len(sel) and not 0 <= sel.min() <= sel.max() < len(src):
+        raise IndexError(
+            f"row indices span [{sel.min()}, {sel.max()}] but the source "
+            f"has {len(src)} rows")
+    return np.take(src, sel, axis=0, out=out, mode="clip")
+
+
+# An in-memory batch is gathered in one part per this many bytes, each part
+# by its own worker, and a batch that makes a single part keeps the serial
+# path (ArrayDataSet._stream).  Measured on the v5e's host (13 cores, PR 27,
+# PERF.md §7) for a 154 MB batch of 256 float32 images: one thread copies
+# into a reused buffer at 10.4 GB/s (3 ms for 32 MB), 2 threads reach 18,
+# 4 reach 27 and 8 to 13 the plateau of 30-33 GB/s; in the training loop
+# every worker beyond 4 bought nothing (the batch is made in 6-8 ms of a
+# 137 ms step either way) and cost the driver's thread 0.3-0.5 ms a step:
+# 4 parts of 38 MB ran the step in 137.2 ms, 7 of 22 MB in 138.8, 11 of
+# 14 MB in 140.6-141.0.
+_PART_BYTES = 32 << 20
+
+
 class ArrayDataSet(DataSet):
     """In-memory (host RAM) dataset over numpy arrays, with optional
     per-sample transform applied at batch time (the Transformer chain hook)."""
@@ -189,6 +217,8 @@ class ArrayDataSet(DataSet):
             raise ValueError(
                 f"data/labels length mismatch: {self.size()} vs {len(self.labels)}")
         self.transform = transform
+        self._slot_cache: Dict = {}  # ring buffers reused across epochs
+        self._row_spec: Optional[tuple] = None  # transform's output, probed
 
     def size(self) -> int:
         return len(self.data[0]) if self.multi else len(self.data)
@@ -236,6 +266,135 @@ class ArrayDataSet(DataSet):
             old_process_count=old_process_count, shuffle=shuffle,
             seed=seed, epoch=epoch, drop_last=drop_last,
             process_id=process_id, process_count=process_count))
+
+    def stream_batches(self, batch_size, *, shuffle=True, seed=0, epoch=0,
+                       drop_last=True, process_id=0, process_count=1,
+                       workers=None, parts_per_batch=None,
+                       raw_depth=None, ring_depth=None, metrics=None):
+        """:meth:`batches` behind a host-side lookahead (docs/data.md
+        §In-memory arrays), byte-identical to it for every argument.  How
+        follows from the batch's bytes (:data:`_PART_BYTES`): a batch
+        worth several parts is gathered by a worker pool straight into
+        reused slots of a :class:`~bigdl_tpu.data.pipeline.BufferRing`
+        and comes out as a ``RingBatch``; a batch that makes one part — a
+        few KB of features, nearly every caller — comes from one
+        ``thread_prefetch`` thread running :meth:`batches`, the path such
+        a dataset always took.  ``workers`` overrides the pool's width,
+        ``parts_per_batch`` the bytes rule.  Ring slots are cached on the
+        dataset: at most one stream of a dataset may be live at a time."""
+        kw = dict(shuffle=shuffle, seed=seed, epoch=epoch,
+                  drop_last=drop_last, process_id=process_id,
+                  process_count=process_count)
+        return self._stream("batches", batch_index_plan, batch_size, kw,
+                            workers, parts_per_batch, raw_depth, ring_depth,
+                            metrics)
+
+    def resharded_stream_batches(self, batch_size, *, trained_batches,
+                                 old_process_count, shuffle=True, seed=0,
+                                 epoch=0, drop_last=True, process_id=0,
+                                 process_count=1, workers=None,
+                                 parts_per_batch=None, raw_depth=None,
+                                 ring_depth=None, metrics=None):
+        """:meth:`resharded_batches` the way :meth:`stream_batches` serves
+        :meth:`batches`: an elastic mid-epoch resume keeps its feed."""
+        kw = dict(trained_batches=trained_batches,
+                  old_process_count=old_process_count, shuffle=shuffle,
+                  seed=seed, epoch=epoch, drop_last=drop_last,
+                  process_id=process_id, process_count=process_count)
+        return self._stream(
+            "resharded_batches", resharded_batch_index_plan, batch_size, kw,
+            workers, parts_per_batch, raw_depth, ring_depth, metrics)
+
+    def _ring_spec(self, rows: int) -> Dict[str, tuple]:
+        """Full-batch shape and dtype of every buffer of a ring slot.  A
+        transform's output row is probed once, from a copy of row 0."""
+        if self.multi:
+            spec = {f"input:{i}": ((rows,) + a.shape[1:], a.dtype)
+                    for i, a in enumerate(self.data)}
+        elif self.transform is None:
+            spec = {"input": ((rows,) + self.data.shape[1:],
+                              self.data.dtype)}
+        else:
+            if self._row_spec is None:
+                row = np.asarray(self.transform(self.data[[0]][0]))
+                self._row_spec = (row.shape, row.dtype)
+            shape, dtype = self._row_spec
+            spec = {"input": ((rows,) + shape, dtype)}
+        if self.labels is not None:
+            spec["target"] = ((rows,) + self.labels.shape[1:],
+                              self.labels.dtype)
+        spec["weight"] = ((rows,), np.float32)
+        return spec
+
+    def _stream(self, serial, planner, batch_size, kw, workers, parts,
+                raw_depth, ring_depth, metrics):
+        """``serial`` names the method whose batches are wanted, ``planner``
+        is the index plan behind it, ``kw`` the arguments of both."""
+        from bigdl_tpu.data.pipeline import (
+            StreamingPipeline, autotune_depths, autotune_workers,
+            cached_slots, fill_pad_weights, timed_batches,
+        )
+        from bigdl_tpu.data.prefetch import thread_prefetch
+
+        rows = _per_host_batch(batch_size, kw["process_count"])
+        # a subclass that assembles batches its own way is not second-
+        # guessed: only this class's own _emit is what the ring reproduces
+        own = all(getattr(type(self), m) is getattr(ArrayDataSet, m)
+                  for m in ("_emit", serial))
+        inputs = ([(f"input:{i}", a) for i, a in enumerate(self.data)]
+                  if self.multi else [("input", self.data)])
+        sources = inputs + ([("target", self.labels)]
+                            if self.labels is not None else [])
+        if not own or not self.size():
+            parts = 1
+        elif parts is None:
+            # the bytes a batch copies out of the source
+            nbytes = rows * sum(a.nbytes // len(a) for _, a in sources)
+            parts = max(1, min(nbytes // _PART_BYTES, autotune_workers(),
+                               rows))
+        if parts == 1:
+            batches = getattr(self, serial)(batch_size, **kw)
+            if metrics is not None:
+                batches = timed_batches(batches, "produce", metrics)
+            return thread_prefetch(batches)
+        if workers is None:
+            workers = min(parts, autotune_workers())
+        if raw_depth is None or ring_depth is None:
+            tuned = autotune_depths(0, 0, workers, parts_per_batch=parts)
+            raw_depth = raw_depth or tuned["raw_depth"]
+            ring_depth = ring_depth or tuned["ring_depth"]
+        spec = self._ring_spec(rows)
+
+        def decode(item, raw, buffers, lo, hi, slot):
+            sel, n_real = item
+            part = sel[lo:hi]
+            for name, src in sources:
+                if name == "input" and self.transform is not None:
+                    # _emit's rows are copies: a transform may write to its
+                    # argument, never to the source
+                    for i, row in enumerate(src[part], lo):
+                        buffers[name][i] = self.transform(row)
+                else:
+                    gather_rows(src, part, buffers[name][lo:hi])
+            fill_pad_weights(buffers["weight"], n_real, lo, hi)
+            return {"n_real": n_real}
+
+        def finalize(buffers, meta):
+            fields = {"input": (tuple(buffers[k] for k, _ in inputs)
+                                if self.multi else buffers["input"])}
+            if self.labels is not None:
+                fields["target"] = buffers["target"]
+            if meta["n_real"] < rows:
+                fields["weight"] = buffers["weight"]
+            return fields
+
+        return StreamingPipeline(
+            planner(self.size(), batch_size, **kw),
+            lambda item, slot: None,  # the source is already in memory
+            decode, spec, rows=rows, workers=workers, parts_per_batch=parts,
+            raw_depth=raw_depth, ring_depth=ring_depth,
+            slots=cached_slots(self._slot_cache, spec, ring_depth),
+            finalize=finalize, metrics=metrics)
 
     def steps_per_epoch(self, batch_size: int, process_count: int = 1,
                         drop_last: bool = True) -> int:

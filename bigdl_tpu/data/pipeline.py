@@ -39,10 +39,12 @@ per-stage spans (``data/read``, ``data/decode``), and the consumer-side
 ``train.data_wait_s`` histogram recorded by the optimizer — one scrape of
 ``/metrics`` shows exactly which stage starves the device.  The wait
 itself is split where it is spent (:func:`timed_batches`,
-:func:`dispatch_to_device`): ``data.produce_s`` (the producer thread's
-seconds per batch), ``data.batch_wait_s`` (the driver blocked on the
-producer) and ``data.put_s`` (the driver inside the host→device put),
-with spans ``data/produce``, ``data/batch_wait``, ``data/put``.
+:func:`dispatch_to_device`): ``data.produce_s`` (the producer's seconds per
+batch: one thread's around its ``next()``, or a :class:`StreamingPipeline`
+pool's from a batch's first part taken to its last part done),
+``data.batch_wait_s`` (the driver blocked on the producer) and
+``data.put_s`` (the driver inside the host→device put), with spans
+``data/produce``, ``data/batch_wait``, ``data/put``.
 """
 
 import math
@@ -157,9 +159,9 @@ class BufferRing:
     def buffers(self, slot: int) -> Dict[str, np.ndarray]:
         return self._slots[slot]
 
-    def part_done(self, slot: int, meta: Optional[dict] = None) -> None:
+    def part_done(self, slot: int, meta: Optional[dict] = None) -> bool:
         """One decode sub-range finished; the slot turns READY when every
-        part has reported."""
+        part has reported.  True for the part that made it READY."""
         with self._lock:
             if self._state[slot] != _ASSIGNED:
                 raise PipelineError(
@@ -168,9 +170,11 @@ class BufferRing:
             if meta:
                 self._meta[slot].update(meta)
             self._pending[slot] -= 1
-            if self._pending[slot] == 0:
-                self._state[slot] = _READY
-                self._ready_cv.notify_all()
+            if self._pending[slot]:
+                return False
+            self._state[slot] = _READY
+            self._ready_cv.notify_all()
+            return True
 
     # -- consumer side -----------------------------------------------------
     def pop(self, seq: int, stop: threading.Event,
@@ -396,6 +400,10 @@ class StreamingPipeline:
         self._read_blocked_s = 0.0
         self._decode_starved_s = 0.0
         self._rows_out = 0
+        # when a worker took each in-flight batch's FIRST part: (perf
+        # seconds, span clock ns); read by whichever worker finishes its
+        # LAST part — <name>.produce_s is the pool's seconds per batch
+        self._produce_t0: Dict[int, tuple] = {}
         self._rate_lock = threading.Lock()  # decode counters are updated
         #                                     from every worker thread
         self._closed = False
@@ -492,9 +500,14 @@ class StreamingPipeline:
                         self._decode_starved_s += (
                             time.perf_counter() - tb)
                 continue
+            if job is None:  # close()'s wake-up: one per worker
+                return
             seq, item, raw, slot, lo, hi = job
             try:
                 t0 = time.perf_counter()
+                with self._rate_lock:
+                    began = self._produce_t0.setdefault(
+                        seq, (t0, time.monotonic_ns()))
                 with trace.span(f"{self._name}/decode", seq=seq,
                                 rows=hi - lo):
                     meta = self._decode(item, raw, self.ring.buffers(slot),
@@ -503,11 +516,25 @@ class StreamingPipeline:
                     self._decode_s += time.perf_counter() - t0
                     self._decode_n += 1
                 self._count("decoded_images", hi - lo)
-                self.ring.part_done(slot, meta)
-                self._count("ready_batches", 1.0 / self.parts)
+                if self.ring.part_done(slot, meta):
+                    self._batch_made(seq, *began)
             except BaseException as e:  # noqa: BLE001 — surfaces at consumer
                 self._fail(e)
                 return
+
+    def _batch_made(self, seq: int, t0: float, ns0: int) -> None:
+        """Batch ``seq``'s last part is in its slot: the seconds since a
+        worker took its first part are what the producer — here a pool —
+        needed to make one batch, the quantity ``timed_batches(...,
+        "produce")`` observes around a one-thread producer."""
+        with self._rate_lock:
+            del self._produce_t0[seq]
+        self._count("ready_batches")
+        if self._metrics is not None:
+            self._metrics.observe(f"{self._name}.produce_s",
+                                  time.perf_counter() - t0)
+        trace.record(f"{self._name}/produce", ns0, time.monotonic_ns(),
+                     seq=seq)
 
     # -- metrics helpers ---------------------------------------------------
     def _count(self, key: str, n: float = 1) -> None:
@@ -615,11 +642,26 @@ class StreamingPipeline:
     def close(self) -> None:
         """Stop every stage thread and drop queued work.  Idempotent; also
         runs when a consumer abandons the iterator (generator close)."""
+        import queue as _queue
+
         if self._closed:
             return
         self._closed = True
         self._stop.set()
         self.ring._wake_all()
+        # one wake-up per worker, over whatever work is still queued: an
+        # idle worker sits in a timed get(), and joining it would cost the
+        # driver up to a poll interval at every epoch's end
+        for _ in range(self.workers):
+            while True:
+                try:
+                    self._raw.put_nowait(None)
+                    break
+                except _queue.Full:
+                    try:
+                        self._raw.get_nowait()
+                    except _queue.Empty:
+                        pass
         for t in self._threads:
             t.join(timeout=5)
         if self._metrics is not None:

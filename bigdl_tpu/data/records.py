@@ -23,7 +23,7 @@ from typing import Dict, Optional
 import numpy as np
 
 from bigdl_tpu.data.dataset import (
-    DataSet, MiniBatch, _per_host_batch, batch_index_plan,
+    DataSet, MiniBatch, _per_host_batch, batch_index_plan, gather_rows,
     resharded_batch_index_plan,
 )
 from bigdl_tpu.utils import storage
@@ -236,7 +236,7 @@ class RecordDataSet(DataSet):
         stage's no-allocation path)."""
         if self._reader is not None:
             return self._reader.gather(sel, out=out)
-        return np.take(self._mm, sel, axis=0, out=out)
+        return gather_rows(self._mm, sel, out)
 
     def _decode(self, raw: np.ndarray, name: str) -> np.ndarray:
         fld = next(f for f in self._fields if f["name"] == name)

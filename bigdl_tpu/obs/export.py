@@ -377,8 +377,9 @@ DEFAULT_HELP = {
                                 "were free — high means the read stage "
                                 "caps the pipeline (a full ring, i.e. a "
                                 "slow consumer, does not count here)",
-    "data.produce_s": "producer thread: seconds to make one batch "
-                      "(can the producer keep pace with the step?)",
+    "data.produce_s": "producer (one thread, or a pipeline's worker "
+                      "pool): seconds to make one batch (can the "
+                      "producer keep pace with the step?)",
     "data.batch_wait_s": "driver thread blocked on the producer per pull "
                          "(part of train.data_wait_s)",
     "data.put_s": "driver thread inside the host-to-device put per batch "

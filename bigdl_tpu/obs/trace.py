@@ -326,6 +326,16 @@ def collect_into(tracer: Optional[Tracer]) -> None:
     _collector = tracer
 
 
+def record(name: str, start_ns: int, end_ns: int, **attrs) -> None:
+    """A region whose two ends were stamped (``time.monotonic_ns()``) by
+    different threads — a batch made by a worker pool starts in one
+    worker and ends in another, so no ``with`` block can hold it.  Lands
+    where a :class:`timed` region would: the process tracer when it is
+    on, and the collector of a program-owned device trace."""
+    for t in {active(), _collector} - {None}:
+        t.add_span(name, start_ns, end_ns, **attrs)
+
+
 class timed:
     """One instrumented region that is span and stopwatch at once, so the
     two cannot disagree about where it starts and ends: opens

@@ -9,9 +9,11 @@ import time
 import numpy as np
 import pytest
 
+from bigdl_tpu.data import dataset as dataset_mod
+from bigdl_tpu.data.dataset import ArrayDataSet, gather_rows
 from bigdl_tpu.data.pipeline import (
     BufferRing, PipelineError, RingBatch, StreamingPipeline,
-    autotune_depths, dispatch_to_device,
+    autotune_depths, autotune_workers, dispatch_to_device,
 )
 from bigdl_tpu.data.prefetch import prefetch_to_device
 from bigdl_tpu.data.records import RecordDataSet, write_records
@@ -241,7 +243,8 @@ def test_ring_batch_release_idempotent():
 # dispatch + prefetch satellites
 # ---------------------------------------------------------------------------
 
-def test_dispatch_to_device_survives_slot_reuse(rec):
+@pytest.mark.parametrize("source", ["records", "arrays"])
+def test_dispatch_to_device_survives_slot_reuse(rec, source):
     """Device arrays keep their batch's data even after the ring slot they
     came from is recycled many times over — the XLA:CPU zero-copy
     device_put alias trap (a released slot refilled under a live device
@@ -249,11 +252,12 @@ def test_dispatch_to_device_survives_slot_reuse(rec):
     heavy reuse; every device array must still match the serial epoch."""
     import jax
 
-    p, x, _ = rec
-    ds = RecordDataSet(p)
+    p, x, y = rec
+    ds = RecordDataSet(p) if source == "records" else ArrayDataSet(x, y)
     for epoch in range(3):
         stream = ds.stream_batches(10, shuffle=True, seed=7, epoch=epoch,
-                                   workers=2, ring_depth=2, raw_depth=1)
+                                   workers=2, parts_per_batch=2,
+                                   ring_depth=2, raw_depth=1)
         devs = list(dispatch_to_device(
             stream, lambda mb: (jax.device_put(np.asarray(mb["input"])),
                                 jax.device_put(np.asarray(mb["target"]))),
@@ -263,7 +267,8 @@ def test_dispatch_to_device_survives_slot_reuse(rec):
         for (xd, yd), mb in zip(devs, ref):
             np.testing.assert_array_equal(np.asarray(xd), mb["input"])
             np.testing.assert_array_equal(np.asarray(yd), mb["target"])
-    ds.close()
+    if source == "records":
+        ds.close()
 
 
 class _ClosableIter:
@@ -380,3 +385,250 @@ def test_shared_memory_decode_pool_matches_native(img_rec):
     for x1, x2 in zip(a, b):
         np.testing.assert_array_equal(x1["target"], x2["target"])
         np.testing.assert_allclose(x1["input"], x2["input"], atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# in-memory arrays on the ring (docs/data.md §In-memory arrays)
+# ---------------------------------------------------------------------------
+
+def _arrays(form, n=103):
+    rs = np.random.RandomState(11)
+    x = rs.rand(n, 5, 3).astype(np.float32)
+    y = rs.randint(0, 5, n).astype(np.int32)
+    if form == "one_array":
+        return ArrayDataSet(x, y)
+    if form == "tuple_of_arrays":
+        return ArrayDataSet((x, rs.randint(0, 9, (n, 2))), y)
+    return ArrayDataSet(x, y, transform=lambda row: (row * 2).sum(0))
+
+
+def _snap_any(mb):
+    return {k: (tuple(np.array(t) for t in v) if isinstance(v, tuple)
+                else np.array(v)) for k, v in mb.items()}
+
+
+def _assert_same_batches(ref, got):
+    assert len(got) == len(ref) > 0
+    for a, b in zip(ref, got):
+        assert list(a) == list(b)  # the same fields, in the same order
+        for k in a:
+            for u, v in zip(*[t if isinstance(t, tuple) else (t,)
+                              for t in (a[k], b[k])]):
+                assert u.dtype == v.dtype and u.shape == v.shape
+                assert u.tobytes() == v.tobytes()
+
+
+FORMS = ["one_array", "tuple_of_arrays", "transform"]
+
+
+@pytest.mark.parametrize("process_count", [1, 2])
+@pytest.mark.parametrize("workers", [1, 3])
+@pytest.mark.parametrize("form", FORMS)
+def test_array_stream_matches_serial(form, workers, process_count):
+    """The ring path is byte-identical to batches(): every field, every
+    worker count, every host's share, with a cyclic-padded tail (103 rows
+    in batches of 16 leave 7, or 3 and 4 over two hosts)."""
+    ds = _arrays(form)
+    for pid in range(process_count):
+        kw = dict(shuffle=True, seed=3, epoch=2, drop_last=False,
+                  process_id=pid, process_count=process_count)
+        ref = [_snap_any(mb) for mb in ds.batches(16, **kw)]
+        assert "weight" in ref[-1] and "weight" not in ref[0]
+        stream = ds.stream_batches(16, workers=workers, parts_per_batch=3,
+                                   **kw)
+        assert isinstance(stream, StreamingPipeline)
+        got = []
+        for mb in stream:
+            assert isinstance(mb, RingBatch)
+            got.append(_snap_any(mb))
+        _assert_same_batches(ref, got)
+
+
+@pytest.mark.parametrize("form", FORMS)
+def test_array_resharded_stream_matches_serial(form):
+    """The elastic remainder epoch (2 hosts trained 2 batches, 3 finish)
+    through the ring equals resharded_batches()."""
+    ds = _arrays(form)
+    for pid in range(3):
+        kw = dict(trained_batches=2, old_process_count=2, shuffle=True,
+                  seed=5, epoch=1, drop_last=False, process_id=pid,
+                  process_count=3)
+        ref = [_snap_any(mb) for mb in ds.resharded_batches(12, **kw)]
+        got = [_snap_any(mb) for mb in ds.resharded_stream_batches(
+            12, workers=2, parts_per_batch=2, **kw)]
+        _assert_same_batches(ref, got)
+
+
+@pytest.mark.parametrize("sel, ok", [
+    ([3, 0, 3, 9], True), ([], True), ([0, -1], False), ([10], False)])
+def test_gather_rows_is_the_fancy_index_with_indices_checked(sel, ok):
+    src = np.arange(40, dtype=np.float32).reshape(10, 4)
+    sel = np.asarray(sel, np.int64)
+    out = np.full((len(sel), 4), -1, np.float32)
+    if ok:
+        assert gather_rows(src, sel, out) is out
+        np.testing.assert_array_equal(out, src[sel])
+    else:
+        with pytest.raises(IndexError):
+            gather_rows(src, sel, out)
+        assert (out == -1).all()  # checked before a byte moved
+
+
+def _pool_threads():
+    return [t.name for t in threading.enumerate()
+            if t.name.startswith("bigdl-tpu-data-")]
+
+
+def _optimize_arrays(ds, steps, batch_size=16):
+    """A short seeded run; per step, the loss and the pool threads alive."""
+    from bigdl_tpu import nn, optim
+
+    model = nn.Sequential([nn.Flatten(), nn.Linear(15, 5), nn.LogSoftMax()])
+    opt = optim.Optimizer(model, ds, nn.ClassNLLCriterion(),
+                          batch_size=batch_size, seed=4)
+    opt.set_optim_method(optim.SGD(learning_rate=0.1))
+    seen = {}
+
+    def end(state):
+        it = state["iteration"]
+        if it and it not in seen:
+            seen[it] = (float(state["loss"]), _pool_threads())
+        return it >= steps
+
+    opt.set_end_when(optim.Trigger(end, "steps"))
+    opt.optimize()
+    return opt, seen
+
+
+@pytest.mark.parametrize("batch", ["large", "small"])
+def test_bytes_rule_decides_the_path_of_an_optimizer_run(batch, monkeypatch):
+    """A batch worth several parts rides the ring (data.ready_batches
+    counts every step, a worker pool runs); a batch worth one keeps the
+    one-thread path: counter silent, no pipeline thread ever started.
+    Either way data.produce_s is observed once per batch made."""
+    ds = _arrays("one_array", n=96)  # a batch is 16 x 64 bytes
+    if batch == "large":
+        monkeypatch.setattr(dataset_mod, "_PART_BYTES", 256)
+    opt, seen = _optimize_arrays(ds, steps=9)  # crosses an epoch's end
+    snap = opt.metrics.snapshot()
+    made = snap["hists"]["data.produce_s"]["n"]
+    ready = snap["counters"].get("data.ready_batches", 0)
+    pools = [names for _, names in seen.values()]
+    assert sorted(seen) == list(range(1, 10)) and made >= 9
+    if batch == "large":
+        assert ready == made
+        assert any("bigdl-tpu-data-decode-0" in names for names in pools)
+    else:
+        assert ready == 0 and not any(pools)
+    assert not _pool_threads()  # nothing outlives the run
+
+
+def test_bytes_rule_at_real_sizes():
+    """No knob turned: a batch worth three parts is split in three (one
+    worker each), the same rows in a batch just under two parts' worth,
+    and any subclass that assembles batches its own way, keep the serial
+    generator."""
+    part = dataset_mod._PART_BYTES
+    x = np.zeros((16, part // 16), np.float32)  # rows of a quarter part
+    ds = ArrayDataSet(x, np.zeros(16, np.int32))
+    sp = ds.stream_batches(12, ring_depth=2)
+    try:
+        assert isinstance(sp, StreamingPipeline)
+        assert sp.parts == sp.workers == min(3, autotune_workers())
+    finally:
+        sp.close()
+    assert not isinstance(ds.stream_batches(7), StreamingPipeline)
+
+    class Own(ArrayDataSet):
+        def _emit(self, plan):
+            return super()._emit(plan)
+
+    own = Own(x, np.zeros(16, np.int32)).stream_batches(12)
+    assert not isinstance(own, StreamingPipeline)
+    assert len(list(own)) == 1
+
+
+def test_loss_trajectory_is_the_same_on_both_paths(monkeypatch):
+    ds = _arrays("one_array", n=96)
+    _, serial = _optimize_arrays(ds, steps=8)
+    monkeypatch.setattr(dataset_mod, "_PART_BYTES", 256)
+    opt, ring = _optimize_arrays(ds, steps=8)
+    assert opt.metrics.snapshot()["counters"]["data.ready_batches"] >= 8
+    assert [l for l, _ in ring.values()] == [l for l, _ in serial.values()]
+
+
+@pytest.mark.parametrize("path", ["ring", "serial"])
+def test_produce_seconds_observed_once_per_batch(path):
+    from bigdl_tpu.obs import trace
+
+    ds = _arrays("one_array")
+    m = Metrics()
+    tracer = trace.enable()
+    try:
+        n = len(list(ds.stream_batches(
+            16, metrics=m, parts_per_batch=3 if path == "ring" else 1)))
+        spans = [s for s in tracer.spans() if s.name == "data/produce"]
+    finally:
+        trace.disable()
+    assert n == 6
+    assert m.snapshot()["hists"]["data.produce_s"]["n"] == n
+    # (one thread's exhausted pull is a span too, and no batch)
+    assert len(spans) == n + (path == "serial")
+    assert all(s.end_ns >= s.start_ns for s in spans)
+    ready = m.snapshot()["counters"].get("data.ready_batches", 0)
+    assert ready == (n if path == "ring" else 0)
+
+
+@pytest.mark.parametrize("state", ["idle", "drained", "abandoned"])
+def test_close_costs_no_poll_interval(state):
+    """Workers parked in their timed get() are woken, not waited out: an
+    epoch's end must not stall the driver (they used to cost up to 100 ms
+    a close)."""
+    ds = _arrays("one_array")
+    took = []
+    for _ in range(3):  # a busy test host may delay one join, not three
+        sp = ds.stream_batches(16, workers=3, parts_per_batch=3)
+        it = iter(sp)
+        if state == "drained":
+            for _ in it:
+                pass
+        elif state == "abandoned":
+            next(it)
+        time.sleep(0.15)  # every worker is back in its get() by now
+        t0 = time.perf_counter()
+        sp.close()
+        took.append(time.perf_counter() - t0)
+        assert all(not t.is_alive() for t in sp._threads)
+        if took[-1] < 0.02:
+            break
+    assert min(took) < 0.02, f"close() took {min(took) * 1e3:.1f} ms"
+
+
+def test_array_slot_not_refilled_before_its_transfer_is_released(
+        monkeypatch):
+    """On an accelerator the dispatch stage owns a slot's release until
+    the batch's transfer has landed: while batch k is being put, batch
+    k-1's slot (transfer still in the window) must still hold batch k-1,
+    however eager the pool is and however small the ring."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    ds = _arrays("one_array")
+    kw = dict(shuffle=True, seed=9, epoch=0)
+    ref = [np.array(mb["input"]) for mb in ds.batches(8, **kw)]
+    lent = []
+
+    def put(mb):
+        lent.append(mb["input"])  # a view over the slot
+        time.sleep(0.005)         # the pool gets every chance to run on
+        for k in (len(lent) - 2, len(lent) - 1):
+            if k >= 0:
+                np.testing.assert_array_equal(lent[k], ref[k])
+        return np.array(mb["input"])
+
+    stream = ds.stream_batches(8, workers=2, parts_per_batch=2,
+                               ring_depth=2, raw_depth=1, **kw)
+    out = list(dispatch_to_device(stream, put, size=2))
+    assert len(out) == len(ref) == 12
+    for a, b in zip(out, ref):
+        np.testing.assert_array_equal(a, b)
