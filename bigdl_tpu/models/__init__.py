@@ -11,6 +11,7 @@ from bigdl_tpu.models.transformer_zoo import (
 )
 from bigdl_tpu.models.recsys import NeuralCF, WideAndDeep
 from bigdl_tpu.models.maskrcnn import MaskRCNN, maskrcnn_resnet50
+from bigdl_tpu.models.mla_moe_lm import MLAMoEConfig, MLAMoELM
 
 __all__ = [
     "LeNet5", "resnet_cifar", "resnet50", "BasicBlock", "Bottleneck",
@@ -18,5 +19,5 @@ __all__ = [
     "vgg16", "vgg_cifar10", "char_rnn",
     "Seq2Seq", "autoencoder", "Encoder", "TransformerEncoder", "BERT",
     "BERTClassifier", "NeuralCF", "WideAndDeep", "MaskRCNN",
-    "maskrcnn_resnet50",
+    "maskrcnn_resnet50", "MLAMoEConfig", "MLAMoELM",
 ]

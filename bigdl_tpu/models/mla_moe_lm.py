@@ -1,0 +1,144 @@
+"""Sparse-expert decoder LM with latent attention, assembled from a config.
+
+The family DeepSeek-V2 opened (arXiv:2405.04434) and that GLM-4.7-Flash,
+among others, publishes in 2026: pre-norm residual blocks ``h += MLA(
+RMSNorm(h))``, ``h += FFN(RMSNorm(h))``; the first ``first_k_dense_replace``
+layers carry a dense gated-SiLU FFN, the rest a routed expert layer with a
+shared expert (``parallel.moe.HeldMoE``: sigmoid scores, top-k of score +
+correction bias, no token dropped); final RMSNorm; an UNTIED output head.
+
+:class:`MLAMoEConfig` takes the published ``config.json`` keys as they are
+(``MLAMoEConfig.from_dict`` ignores the keys that say nothing about the
+shape).  ``held_experts=(first, count)`` makes the model ONE chip's share of
+an expert-parallel job: every expert layer holds ``count`` of the
+``n_routed_experts`` the router scores (docs/parallelism.md §Held-share
+expert layer); ``vocab_size`` is then that chip's slice of the vocabulary —
+a sliced vocabulary is a smaller vocabulary.
+
+Precision is the repo's policy: float32 parameters, bfloat16 matmul inputs
+on a TPU with float32 accumulation; router, softmax, RMSNorm and RoPE in
+float32.  When training, every layer is recomputed in the backward pass
+(``jax.checkpoint`` per layer; the layers' inputs are what is kept): there
+is no switch."""
+
+import functools
+from dataclasses import dataclass, fields
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from bigdl_tpu.nn.attention import LatentAttention
+from bigdl_tpu.nn.layers import rms_norm
+from bigdl_tpu.nn.module import EMPTY, Module
+from bigdl_tpu.parallel.moe import HeldMoE, swiglu, swiglu_init
+from bigdl_tpu.tensor.policy import cast_compute
+
+
+@dataclass(frozen=True)
+class MLAMoEConfig:
+    vocab_size: int
+    hidden_size: int
+    num_hidden_layers: int
+    num_attention_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    intermediate_size: int
+    moe_intermediate_size: int
+    n_routed_experts: int
+    num_experts_per_tok: int
+    n_shared_experts: int = 1
+    first_k_dense_replace: int = 1
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    rope_theta: float = 10000.0
+    rms_norm_eps: float = 1e-6
+    # this chip's share of the experts, (first, count); None = all of them
+    held_experts: Optional[Tuple[int, int]] = None
+
+    @classmethod
+    def from_dict(cls, cfg: dict) -> "MLAMoEConfig":
+        names = {f.name for f in fields(cls)}
+        kw = {k: v for k, v in cfg.items() if k in names}
+        if kw.get("held_experts") is not None:
+            kw["held_experts"] = tuple(kw["held_experts"])
+        return cls(**kw)
+
+
+class MLAMoELM(Module):
+    """``forward(params, state, ids)`` → (batch, seq, vocab) float32 logits
+    and the new state (each expert layer's correction bias and routing
+    statistics)."""
+
+    def __init__(self, config: MLAMoEConfig, name=None):
+        super().__init__(name)
+        c = self.config = config
+        self.attn = LatentAttention(
+            c.hidden_size, c.num_attention_heads, q_rank=c.q_lora_rank,
+            kv_rank=c.kv_lora_rank, nope_dim=c.qk_nope_head_dim,
+            rope_dim=c.qk_rope_head_dim, v_dim=c.v_head_dim,
+            rope_theta=c.rope_theta, eps=c.rms_norm_eps)
+        self.moe = HeldMoE(
+            c.n_routed_experts, c.moe_intermediate_size,
+            c.num_experts_per_tok, held=c.held_experts,
+            shared_hidden=c.n_shared_experts * c.moe_intermediate_size,
+            scale=c.routed_scaling_factor, norm_topk=c.norm_topk_prob)
+
+    def _is_dense(self, i: int) -> bool:
+        return i < self.config.first_k_dense_replace
+
+    def init(self, rng, ids):
+        c = self.config
+        d = c.hidden_size
+        ks = jax.random.split(rng, c.num_hidden_layers + 2)
+        x = jnp.zeros(jnp.shape(ids) + (d,), jnp.float32)
+        params = {"embed": jax.random.normal(ks[0], (c.vocab_size, d)),
+                  "ln_out": jnp.ones((d,)),
+                  "head": jax.random.normal(ks[1], (d, c.vocab_size))
+                  * d ** -0.5}
+        state = {}
+        for i in range(c.num_hidden_layers):
+            ka, kf = jax.random.split(ks[i + 2])
+            layer = {"ln1": jnp.ones((d,)), "ln2": jnp.ones((d,)),
+                     "attn": self.attn.init(ka, x)["params"]}
+            if self._is_dense(i):
+                layer["ffn"] = swiglu_init(kf, d, c.intermediate_size)
+            else:
+                v = self.moe.init(kf, x)
+                layer["moe"] = v["params"]
+                state[f"layer{i}"] = v["state"]
+            params[f"layer{i}"] = layer
+        return {"params": params, "state": state}
+
+    def _layer(self, i: int, p, st, h):
+        eps = self.config.rms_norm_eps
+        a, _ = self.attn.forward(p["attn"], EMPTY,
+                                 rms_norm(h, p["ln1"], eps))
+        h = h + a
+        x = rms_norm(h, p["ln2"], eps)
+        if self._is_dense(i):
+            with jax.named_scope("lm/dense_ffn"):
+                return h + swiglu(x, p["ffn"]), st
+        y, st = self.moe.forward(p["moe"], st, x)
+        return h + y, st
+
+    def forward(self, params, state, ids, training=False, rng=None):
+        c = self.config
+        h = jnp.take(params["embed"], ids.astype(jnp.int32), axis=0)
+        new_state = {}
+        for i in range(c.num_hidden_layers):
+            key = f"layer{i}"
+            fn = functools.partial(self._layer, i)
+            if training:
+                fn = jax.checkpoint(fn)
+            h, st = fn(params[key], state.get(key, EMPTY), h)
+            if st:
+                new_state[key] = st
+        h = rms_norm(h, params["ln_out"], c.rms_norm_eps)
+        with jax.named_scope("lm/head"):
+            logits = jnp.matmul(cast_compute(h), cast_compute(params["head"]),
+                                preferred_element_type=jnp.float32)
+        return logits, new_state

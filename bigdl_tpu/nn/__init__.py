@@ -58,7 +58,7 @@ from bigdl_tpu.nn.attention import (
     MultiHeadAttention, PositionwiseFFN, PositionalEncoding,
     TransformerLayer, TransformerDecoderLayer, Transformer, Attention,
     FeedForwardNetwork, dot_product_attention, positional_encoding,
-    transformer_decode, transformer_decode_cached,
+    transformer_decode, transformer_decode_cached, LatentAttention, rope,
 )
 from bigdl_tpu.nn.criterion import (
     Criterion, ClassNLLCriterion, CrossEntropyCriterion, MSECriterion,

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from bigdl_tpu.nn import init as init_mod
-from bigdl_tpu.nn.layers import Dropout, LayerNorm, Linear
+from bigdl_tpu.nn.layers import Dropout, LayerNorm, Linear, rms_norm
 from bigdl_tpu.nn.module import EMPTY, Module
 from bigdl_tpu.tensor.policy import cast_compute
 
@@ -184,6 +184,115 @@ class MultiHeadAttention(Module):
                         preferred_element_type=jnp.float32)
              + params["bo"]).astype(x.dtype)
         return y, EMPTY
+
+
+def rope(x, theta: float = 10000.0, offset=0):
+    """Rotary position embedding over the last axis of ``x`` (..., len,
+    dim), in float32, rotate-half pairing: dim ``i`` turns with dim
+    ``i + dim/2`` by the angle ``pos * theta ** (-2i / dim)``.  (The
+    interleaved pairing some checkpoints use is a fixed permutation of the
+    projection's columns: same shapes, same work.)"""
+    length, dim = x.shape[-2], x.shape[-1]
+    half = dim // 2
+    pos = (jnp.arange(length) + offset).astype(jnp.float32)[:, None]
+    freq = jnp.power(float(theta),
+                     -jnp.arange(half, dtype=jnp.float32) * 2.0 / dim)
+    angle = pos * freq[None, :]
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., :half], x32[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def _project(x, w):
+    """bf16-in / f32-accumulate matmul under the compute policy."""
+    return jnp.matmul(cast_compute(x), cast_compute(w),
+                      preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+class LatentAttention(Module):
+    """Multi-head latent attention (MLA; DeepSeek-V2, arXiv:2405.04434
+    §2.1), causal self-attention in its expanded (training) form.
+
+    Queries go through a low-rank pair ``wq_a`` (d, q_rank) → RMSNorm →
+    ``wq_b`` (q_rank, heads * (nope + rope)).  Keys and values share one
+    joint projection ``wkv_a`` (d, kv_rank + rope): the first ``kv_rank``
+    columns are the latent, normalised and expanded by ``wkv_b`` (kv_rank,
+    heads * (nope + v_dim)) to per-head no-position keys and values; the
+    last ``rope`` columns are ONE rotary key shared by every head.  Only the
+    rotary slices of q and k carry positions.  Scores are scaled by
+    ``(nope + rope) ** -0.5``.  No biases.
+
+    The latent (``kv_rank + rope`` per token) is what a decode cache would
+    hold; this module does not cache — serving through the paged engine
+    needs an absorbed decode path that the repo does not have yet."""
+
+    def __init__(self, hidden_size: int, num_heads: int, *, q_rank: int,
+                 kv_rank: int, nope_dim: int, rope_dim: int, v_dim: int,
+                 rope_theta: float = 10000.0, eps: float = 1e-6,
+                 use_flash=None, name=None):
+        super().__init__(name)
+        self.hidden_size, self.num_heads = hidden_size, num_heads
+        self.q_rank, self.kv_rank = q_rank, kv_rank
+        self.nope_dim, self.rope_dim, self.v_dim = nope_dim, rope_dim, v_dim
+        self.rope_theta, self.eps = rope_theta, eps
+        # None = the Pallas flash kernel on a TPU, XLA attention elsewhere
+        self.use_flash = use_flash
+
+    def build(self, rng, x):
+        d, h = self.hidden_size, self.num_heads
+        qk = self.nope_dim + self.rope_dim
+        ks = jax.random.split(rng, 5)
+
+        def w(key, fan_in, fan_out):
+            return jax.random.normal(key, (fan_in, fan_out)) * fan_in ** -0.5
+
+        return {"wq_a": w(ks[0], d, self.q_rank),
+                "q_norm": jnp.ones((self.q_rank,)),
+                "wq_b": w(ks[1], self.q_rank, h * qk),
+                "wkv_a": w(ks[2], d, self.kv_rank + self.rope_dim),
+                "kv_norm": jnp.ones((self.kv_rank,)),
+                "wkv_b": w(ks[3], self.kv_rank,
+                           h * (self.nope_dim + self.v_dim)),
+                "wo": w(ks[4], h * self.v_dim, d)}, EMPTY
+
+    def forward(self, params, state, x, training=False, rng=None):
+        b, t, _ = x.shape
+        h, nope, rp, vd = (self.num_heads, self.nope_dim, self.rope_dim,
+                           self.v_dim)
+        with jax.named_scope("mla/proj"):
+            cq = rms_norm(_project(x, params["wq_a"]), params["q_norm"],
+                          self.eps)
+            q = _project(cq, params["wq_b"]).reshape(
+                b, t, h, nope + rp).transpose(0, 2, 1, 3)
+            kv = _project(x, params["wkv_a"])
+            ckv = rms_norm(kv[..., :self.kv_rank], params["kv_norm"],
+                           self.eps)
+            k_pe = rope(kv[..., None, :, self.kv_rank:], self.rope_theta)
+            kv = _project(ckv, params["wkv_b"]).reshape(
+                b, t, h, nope + vd).transpose(0, 2, 1, 3)
+            q = jnp.concatenate(
+                [q[..., :nope], rope(q[..., nope:], self.rope_theta)], -1)
+            k = jnp.concatenate(
+                [kv[..., :nope], jnp.broadcast_to(k_pe, (b, h, t, rp))], -1)
+            v = kv[..., nope:]
+        with jax.named_scope("mla/attn"):
+            use_flash = self.use_flash
+            if use_flash is None:
+                from bigdl_tpu.ops.common import on_tpu
+
+                use_flash = on_tpu()
+            if use_flash and vd == nope + rp:
+                from bigdl_tpu.ops.flash_attention import flash_attention
+
+                out = flash_attention(q, k, v, causal=True)
+            else:
+                out = dot_product_attention(
+                    q, k, v, mask=jnp.tril(jnp.ones((t, t), bool)))
+        with jax.named_scope("mla/proj"):
+            out = out.transpose(0, 2, 1, 3).reshape(b, t, h * vd)
+            return _project(out, params["wo"]), EMPTY
 
 
 class PositionwiseFFN(Module):

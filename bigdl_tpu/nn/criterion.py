@@ -63,6 +63,18 @@ class CrossEntropyCriterion(Criterion):
         self.size_average = size_average
 
     def forward(self, input, target):
+        if target.ndim == input.ndim - 1:
+            # integer labels: the label's logit is picked by a comparison
+            # that fuses into the reduction, so no (..., classes) one-hot
+            # and no second log-softmax copy exist beside the logits (a
+            # language model's are 8,192 x 19,360 floats a step)
+            lse = jax.nn.logsumexp(input, axis=-1)
+            classes = jax.lax.broadcasted_iota(jnp.int32, input.shape,
+                                               input.ndim - 1)
+            picked = jnp.sum(jnp.where(
+                classes == target.astype(jnp.int32)[..., None], input, 0.0),
+                axis=-1)
+            return _reduce(lse - picked, self.size_average)
         logp = jax.nn.log_softmax(input, axis=-1)
         onehot = _as_onehot(target, input.shape[-1])
         return -_reduce(jnp.sum(onehot * logp, axis=-1), self.size_average)

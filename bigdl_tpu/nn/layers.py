@@ -361,6 +361,13 @@ class LayerNorm(Module):
         return (y * params["weight"] + params["bias"]).astype(x.dtype), EMPTY
 
 
+def rms_norm(x, weight, eps: float = 1e-6):
+    """``x / rms(x) * weight`` over the last axis, computed in float32."""
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (y * weight).astype(x.dtype)
+
+
 class RMSNorm(Module):
     """TPU-era extra (not in reference): RMS normalization for LLM blocks."""
 
@@ -375,9 +382,7 @@ class RMSNorm(Module):
         return {"weight": jnp.ones((c,))}, EMPTY
 
     def forward(self, params, state, x, training=False, rng=None):
-        x32 = x.astype(jnp.float32)
-        y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
-        return (y * params["weight"]).astype(x.dtype), EMPTY
+        return rms_norm(x, params["weight"], self.eps), EMPTY
 
 
 # ---------------------------------------------------------------------------

@@ -1,0 +1,105 @@
+"""Counters that a layer keeps INSIDE the jitted train step and the driver
+books into the metric registry at its log point.
+
+A layer that wants to report what only the device sees (how a router spread
+its tokens, say) cannot call ``Metrics.inc`` from traced code.  It keeps a
+small subtree in its model state instead::
+
+    state["metrics"] = new_state_metrics(counters=("moe.local_pairs",),
+                                         means=("moe.load_imbalance",))
+    ...
+    new_state["metrics"] = bump_state_metrics(
+        state["metrics"], {"moe.local_pairs": n}, {"moe.load_imbalance": r})
+
+Every leaf is a cumulative ``uint32`` that wraps around: a counter adds its
+events; a mean adds its value in 16.16 fixed point and ``n`` counts the
+additions.  The train step sums each replica's additions over the data axes
+(``optim/train_step.py``: unsigned leaves are event counters), so the
+totals are the job's.  On the host a :class:`StateMetricsBooker` rides the
+one ``device_get`` the driver's log point makes anyway, takes the
+difference from the last fetch modulo 2**32 (exact as long as fewer than
+4.29e9 events, or a summed mean under 65,536, fall between two log points)
+and books it: ``inc(name, delta)`` for a counter, one ``observe(name,
+delta_sum / delta_n)`` per subtree for a mean.  The one convention: a state
+dict with the key ``"metrics"`` built by :func:`new_state_metrics`.
+"""
+
+from typing import Dict, Iterable, List, Tuple
+
+KEY = "metrics"
+_FIXED = 65536.0
+
+
+def new_state_metrics(counters: Iterable[str] = (),
+                      means: Iterable[str] = ()):
+    import jax.numpy as jnp
+
+    zero = lambda: jnp.zeros((), jnp.uint32)
+    return {"counters": {c: zero() for c in counters},
+            "means": {m: zero() for m in means}, "n": zero()}
+
+
+def bump_state_metrics(tree, counters: Dict[str, object],
+                       means: Dict[str, object]):
+    """The subtree after one forward pass: each counter plus its events,
+    each mean plus its value (fixed point), ``n`` plus one."""
+    import jax.numpy as jnp
+
+    u32 = lambda v: jnp.asarray(v).astype(jnp.uint32)
+    return {
+        "counters": {k: v + u32(counters[k])
+                     for k, v in tree["counters"].items()},
+        "means": {k: v + u32(jnp.round(jnp.asarray(means[k], jnp.float32)
+                                       * _FIXED))
+                  for k, v in tree["means"].items()},
+        "n": tree["n"] + jnp.uint32(1)}
+
+
+def _subtrees(state, path=()) -> List[Tuple[tuple, dict]]:
+    if not isinstance(state, dict):
+        return []
+    found = []
+    for k, v in state.items():
+        if k == KEY and isinstance(v, dict) and "counters" in v:
+            found.append((path, v))
+        else:
+            found.extend(_subtrees(v, path + (k,)))
+    return found
+
+
+class StateMetricsBooker:
+    """Host side: finds the ``"metrics"`` subtrees of a model state, hands
+    their leaves to the caller's fetch and books the differences."""
+
+    def __init__(self, model_state, metrics):
+        self.metrics = metrics
+        self.rebase(model_state)
+        # a counter exists from the start, at 0: a reader tells "never
+        # happened" (0) from "not instrumented" (absent)
+        for tree in self._last.values():
+            for name in tree["counters"]:
+                metrics.inc(name, 0)
+
+    def rebase(self, model_state) -> None:
+        """Count from this state's values on (the start of a run, or the
+        state a resume restored)."""
+        import jax
+
+        self._last = jax.device_get(dict(_subtrees(model_state)))
+
+    def leaves(self, model_state):
+        """What to add to the log point's ``device_get`` (nothing, and no
+        walk, for a model that keeps no such subtree)."""
+        return dict(_subtrees(model_state)) if self._last else {}
+
+    def book(self, fetched) -> None:
+        delta = lambda new, old: (int(new) - int(old)) % 2 ** 32
+        for path, tree in fetched.items():
+            last, self._last[path] = self._last[path], tree
+            for name, v in tree["counters"].items():
+                self.metrics.inc(name, delta(v, last["counters"][name]))
+            n = delta(tree["n"], last["n"])
+            for name, v in tree["means"].items():
+                if n:
+                    self.metrics.observe(
+                        name, delta(v, last["means"][name]) / _FIXED / n)

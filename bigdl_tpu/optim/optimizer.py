@@ -26,6 +26,7 @@ from bigdl_tpu.data.prefetch import thread_prefetch
 from bigdl_tpu.obs import attr as obs_attr
 from bigdl_tpu.obs import cost as obs_cost
 from bigdl_tpu.obs import flight, trace
+from bigdl_tpu.obs.state_metrics import StateMetricsBooker
 from bigdl_tpu.optim import checkpoint as ckpt
 from bigdl_tpu.optim.metrics import Metrics, SummaryWriter
 from bigdl_tpu.optim.optim_method import OptimMethod, SGD
@@ -466,6 +467,10 @@ class Optimizer:
         # resume if a checkpoint exists
         if self._ckpt_path:
             self._try_resume(step_engine, state)
+        # counters the model keeps in its state (a router's load, say)
+        # are booked at the log points, counted from here on
+        self._state_metrics = StateMetricsBooker(step_engine.model_state,
+                                                 self.metrics)
 
         # preemption-aware save: flag-based — the handler must not touch jax
         # from signal context, so the loop checkpoints at the next iteration
@@ -512,7 +517,10 @@ class Optimizer:
         self._eff_flops_per_step = None
         # kept for _refresh_cost_model: a block-sparse mask restore at
         # resume changes effective FLOPs after this first pass ran
-        self._cost_model_args = (init_vars, init_args)
+        # (shapes only: the walk runs under eval_shape, and the arrays
+        # themselves would pin a second copy of the parameters for the run)
+        self._cost_model_args = (jax.eval_shape(lambda v: v, init_vars),
+                                 init_args)
         try:
             # shape-capturing walk under eval_shape: no compute, no
             # compile; FLOPs scale linearly from the batch-1 sample to the
@@ -598,7 +606,7 @@ class Optimizer:
                     prev_it = state["iteration"]
                     self._one_bundle(step_engine, state, mbs)
                     if self._should_log(prev_it, state["iteration"]):
-                        self._log_progress(state, t_loop)
+                        self._log_progress(step_engine, state)
                     # attribution: trigger work is the "overhead" phase
                     with attribution.phase("overhead") as trig:
                         self._fire_triggers(step_engine, state)
@@ -703,6 +711,7 @@ class Optimizer:
                 with trace.span("resilience/in_run_resume",
                                 cause=cause.value, retry=retries):
                     self._try_resume(step_engine, state)
+                self._state_metrics.rebase(step_engine.model_state)
                 self.metrics.inc("recoveries_total")
                 self.metrics.inc(f"retries_by_cause.{cause.value}")
                 self.metrics.inc("time_lost_to_recovery_s",
@@ -967,7 +976,7 @@ class Optimizer:
         # bundles quantize the cadence up to their edges
         return it // self.log_every > prev_it // self.log_every
 
-    def _log_progress(self, state, t_loop):
+    def _log_progress(self, step_engine, state):
         it = state["iteration"]
         # fetching the loss VALUES blocks until the step chain has actually
         # executed (they are data-dependent on every dispatched bundle), so
@@ -976,9 +985,12 @@ class Optimizer:
         # queue hides device latency.
         with self.attribution.phase("sync", step=it):
             pending, self._pending_losses = self._pending_losses, []
-            fetched = jax.device_get([(lv, gv) for _, lv, gv in pending])
+            fetched, counted = jax.device_get((
+                [(lv, gv) for _, lv, gv in pending],
+                self._state_metrics.leaves(step_engine.model_state)))
             loss = float(state["loss"])
         with self.attribution.phase("overhead"):
+            self._state_metrics.book(counted)
             self._record_progress(state, it, loss, pending, fetched)
 
     def _record_progress(self, state, it, loss, pending, fetched):

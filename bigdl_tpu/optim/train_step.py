@@ -324,9 +324,14 @@ class ShardedParameterStep:
         self._sharded_vec = NamedSharding(mesh, P(AXIS_DATA))
         self._batch_sh = NamedSharding(mesh, P(self._batch_axes))
 
-        # initial device state
-        self.flat_params = jax.device_put(
-            jnp.pad(flat, (0, self.n_pad - self.n_real)), self._rep)
+        # initial device state.  The flat vector is the only copy of the
+        # parameters this object makes (0.6 B parameters are 2.4 GB a
+        # copy): padded only if the shards need it, never kept twice
+        dtype = flat.dtype
+        if self.n_pad != self.n_real:
+            flat = jnp.pad(flat, (0, self.n_pad - self.n_real))
+        self.flat_params = jax.device_put(flat, self._rep)
+        del flat
         self.model_state = jax.device_put(init_variables.get("state", {}),
                                           self._rep)
         # jnp.copy: device_put of an already-placed array is a no-op and
@@ -338,10 +343,14 @@ class ShardedParameterStep:
         # (donating flat_params twice is an XLA error); it is re-captured
         # from the step output each iteration (donation aliases it through)
         self._ema_dummy = (None if self.ema_decay else
-                           jax.device_put(jnp.zeros((1,), flat.dtype),
+                           jax.device_put(jnp.zeros((1,), dtype),
                                           self._rep))
         if self.optim.elementwise:
-            opt_state = self.optim.init_state(jnp.zeros((self.n_pad,), flat.dtype))
+            # made in place, sharded, by one program: no zeros vector
+            # beside the moments and no copy into the sharding
+            init_opt = lambda: self.optim.init_state(
+                jnp.zeros((self.n_pad,), dtype))
+            opt_state = jax.eval_shape(init_opt)
             if len(self._bucket_cols) > 1:
                 # per-bucket updates slice every state leaf like the
                 # param slice; a leaf that is NOT per-element (scalar
@@ -358,14 +367,15 @@ class ShardedParameterStep:
                         f"({self.n_pad},)); {type(self.optim).__name__} "
                         f"has leaves shaped {bad} — use "
                         "comm_bucket_bytes=None with this OptimMethod")
-            self.opt_state = jax.device_put(opt_state, self._sharded_vec)
+            self.opt_state = jax.jit(
+                init_opt, out_shardings=self._sharded_vec)()
         else:
             opt_state = self.optim.init_state(init_variables["params"])
             self.opt_state = jax.device_put(opt_state, self._rep)
         # host-side structure templates for checkpoint load (safe to use even
         # when device buffers were consumed by a failed donated step)
         _z = lambda t: jax.tree_util.tree_map(
-            lambda x: np.zeros(jnp.shape(x), jnp.asarray(x).dtype), t)
+            lambda x: np.zeros(jnp.shape(x), x.dtype), t)
         self.opt_template = _z(opt_state)
         self.model_state_template = _z(init_variables.get("state", {}))
 
@@ -635,10 +645,25 @@ class ShardedParameterStep:
             # moments must not drift parameters that carry no gradient
             new_flat = jnp.where(mask > 0, new_flat, flat_p)
             loss = jax.lax.pmean(loss, stat_axes)
-            new_mstate = jax.tree_util.tree_map(
-                lambda a: jax.lax.pmean(a, stat_axes)
-                if jnp.issubdtype(jnp.asarray(a).dtype, jnp.floating) else a,
-                new_mstate)
+            # model state across replicas: floating leaves (running
+            # statistics) are averaged; unsigned leaves are event counters
+            # (obs/state_metrics.py), to which every replica added its own
+            # events: the job's count is the old value plus the sum of the
+            # additions; anything else is each replica's own
+            old_leaf = dict(jax.tree_util.tree_flatten_with_path(mstate)[0])
+
+            def sync_state(path, a):
+                dtype = jnp.asarray(a).dtype
+                if jnp.issubdtype(dtype, jnp.floating):
+                    return jax.lax.pmean(a, stat_axes)
+                if jnp.issubdtype(dtype, jnp.unsignedinteger) \
+                        and path in old_leaf:
+                    return old_leaf[path] + jax.lax.psum(
+                        a - old_leaf[path], stat_axes)
+                return a
+
+            new_mstate = jax.tree_util.tree_map_with_path(
+                sync_state, new_mstate)
             new_ema = (ema_decay * ema + (1.0 - ema_decay) * new_flat
                        if ema_decay else ema)
             return new_flat, new_ema, new_opt, new_mstate, loss, gnorm
@@ -1071,7 +1096,12 @@ class ShardedParameterStep:
         src = self.ema_flat if (ema and self.ema_flat is not None) \
             else self.flat_params
         flat = np.asarray(src)[: self.n_real]
-        return {"params": self.unravel(jnp.asarray(flat)),
+        # split on the host: on the device the flat copy, its pieces and
+        # their reshapes would stand beside the training state, three more
+        # vectors of the model's size where one is wanted
+        with jax.default_device(jax.local_devices(backend="cpu")[0]):
+            host = jax.device_get(self.unravel(flat))
+        return {"params": jax.tree_util.tree_map(jnp.asarray, host),
                 "state": jax.device_get(self.model_state)}
 
     def predict_fn(self):

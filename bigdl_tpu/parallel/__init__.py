@@ -18,7 +18,9 @@ This package adds the TPU-native axes on the same ``Mesh``:
 - ``pp``: pipeline parallelism — GPipe schedule as ONE SPMD ``lax.scan``
   over the "pipe" axis, activations rotating via ``ppermute``.
 - ``moe``: mixture-of-experts with expert parallelism — capacity-bounded
-  top-k dispatch, ONE ``all_to_all`` each way over the "expert" axis.
+  top-k dispatch, ONE ``all_to_all`` each way over the "expert" axis; and
+  the held-share, no-drop layer (``HeldMoE``: sigmoid routing over all
+  experts, this shard's experts by grouped matrix products).
 - ``layout`` / ``mesh_policy``: the DECLARATIVE sharding layer (docs/
   parallelism.md §Declarative layouts) — a frozen ``SpecLayout`` of
   canonical PartitionSpecs over a named (data, fsdp, tp, seq) mesh,
@@ -38,7 +40,9 @@ from bigdl_tpu.parallel.pp import (
     spmd_pipeline_circular, stack_stage_params,
     stack_stage_params_circular, unmicrobatch,
 )
-from bigdl_tpu.parallel.moe import MoE, moe_apply_ep, moe_apply_local
+from bigdl_tpu.parallel.moe import (HeldMoE, MoE, held_experts_apply,
+                                    moe_apply_ep, moe_apply_local,
+                                    route_sigmoid_topk)
 from bigdl_tpu.parallel.pp_train import PipelineTrainStep
 from bigdl_tpu.parallel.gspmd import (GSPMDTrainStep, build_param_specs,
                                       fit_layout, tp_spec_for_path)
@@ -78,5 +82,8 @@ __all__ = [
     "MoE",
     "moe_apply_ep",
     "moe_apply_local",
+    "HeldMoE",
+    "held_experts_apply",
+    "route_sigmoid_topk",
     "PipelineTrainStep",
 ]

@@ -16,6 +16,19 @@ Two entry points:
 - ``moe_apply_ep``: expert-parallel functional form, call inside shard_map
   with tokens sharded over data and experts sharded over the expert axis.
 - ``MoE``: nn.Module wrapper (local experts) for Sequential/keras use.
+
+Beside that capacity path (which DROPS what overflows an expert's buffer)
+stands the **held-share, no-drop layer** a 2026 expert model trains with:
+``route_sigmoid_topk`` (sigmoid scores over ALL experts, top-k of score +
+correction bias, weights from the scores alone), ``held_experts_apply`` (the
+part of the result the experts ``held=(first, count)`` give, for every
+token routed to them, whatever the imbalance: sort the (token, choice)
+pairs by expert, one grouped matrix product per projection, un-sort) and
+the module ``HeldMoE`` (router + held experts + shared expert).  A chip of
+an expert-parallel job holds ``count`` of the experts and computes its own
+part; what the absent experts add arrives by the exchange between chips,
+which this file does not have yet — on one chip the layer runs without it
+and nothing stands in for it (docs/parallelism.md §Held-share expert layer).
 """
 
 from typing import Callable, NamedTuple, Optional, Tuple
@@ -25,7 +38,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from bigdl_tpu.nn.module import Module, EMPTY
+from bigdl_tpu.obs.state_metrics import (bump_state_metrics,
+                                         new_state_metrics)
 from bigdl_tpu.runtime.mesh import AXIS_EXPERT
+from bigdl_tpu.tensor.policy import cast_compute
 
 
 class GateOutput(NamedTuple):
@@ -185,3 +201,204 @@ class MoE(Module):
                                  k=self.k, act=self.act)
         # expose aux loss through state so criteria/training can pick it up
         return y.reshape(shape), {"aux_loss": aux * self.aux_weight}
+
+
+# ---------------------------------------------------------------------------
+# Held-share, no-drop expert layer (sigmoid routing, gated experts)
+# ---------------------------------------------------------------------------
+
+def route_sigmoid_topk(x, w_router, bias, k: int, scale: float = 1.0,
+                       norm_topk: bool = True):
+    """Aux-loss-free routing (DeepSeek-V3 ``noaux_tc`` with one group), all
+    in float32.  x: (T, d); w_router: (E, d), one row an expert (the
+    layout checkpoints publish); bias: (E,) correction bias.  ``s =
+    sigmoid(x W^T)``; the ``k`` largest of ``s + bias`` are chosen; the
+    weights are ``s`` of the chosen (the bias moves the choice, never the
+    weight), divided by their sum when ``norm_topk``, times ``scale``.
+    Returns (idx (T, k) int32, weights (T, k) float32)."""
+    s = jax.nn.sigmoid(jnp.einsum(
+        "td,ed->te", x.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if norm_topk:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w * scale
+
+
+@jax.custom_vjp
+def _permute(x, perm, inv):
+    """``x[perm]`` for a permutation whose inverse is at hand: the backward
+    is the gather ``g[inv]``, where autodiff would emit a scatter-add."""
+    return x[perm]
+
+
+def _permute_fwd(x, perm, inv):
+    return x[perm], (perm, inv)
+
+
+def _permute_bwd(res, g):
+    perm, inv = res
+    return g[inv], None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def swiglu_init(rng, d: int, hidden: int):
+    """Parameters of a gated-SiLU feed-forward block, N(0, 1/fan_in)."""
+    kg, ku, kd = jax.random.split(rng, 3)
+    return {"w_gate": jax.random.normal(kg, (d, hidden)) * d ** -0.5,
+            "w_up": jax.random.normal(ku, (d, hidden)) * d ** -0.5,
+            "w_down": jax.random.normal(kd, (hidden, d)) * hidden ** -0.5}
+
+
+def swiglu(x, p):
+    """``W_down(silu(W_gate x) * W_up x)`` with ``p`` = {w_gate, w_up,
+    w_down}: bf16-in / f32-accumulate under the compute policy, the gate in
+    float32."""
+    xc = cast_compute(x)
+    g = jnp.matmul(xc, cast_compute(p["w_gate"]),
+                   preferred_element_type=jnp.float32)
+    u = jnp.matmul(xc, cast_compute(p["w_up"]),
+                   preferred_element_type=jnp.float32)
+    return jnp.matmul(cast_compute(jax.nn.silu(g) * u),
+                      cast_compute(p["w_down"]),
+                      preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+def held_experts_apply(params, x, idx, weights, held: Tuple[int, int]):
+    """The held experts' part of a routed layer, dropping nothing.
+
+    x: (T, d); idx, weights: (T, k) from the router, over ALL experts;
+    ``held=(first, count)``: this shard holds experts ``first .. first +
+    count - 1`` as ``params`` {w_gate (count, d, h), w_up (count, d, h),
+    w_down (count, h, d)}.  Returns ``(y, rows, dropped)``: ``y`` (T, d) =
+    sum over a token's chosen AND held experts of weight * Expert(x);
+    ``rows`` (count,) int32, the rows each held expert was given;
+    ``dropped`` int32, the held pairs whose row came back from the grouped
+    products unserved (all zero, or not finite): read off the result, not
+    off the sizes, so a product that skips rows shows here.  (A token whose
+    input row is exactly zero would read as unserved too; a normed stream
+    has none.)
+
+    The T*k (token, choice) pairs are sorted by held expert (pairs of
+    absent experts last), the tokens' rows gathered in that order, and each
+    projection is ONE grouped matrix product (``jax.lax.ragged_dot``) whose
+    group sizes are ``rows``: an expert computes exactly the rows routed to
+    it — all T of them if every token chooses it — and the tail of absent
+    pairs is never multiplied.  Buffers hold all T*k pairs, the worst case,
+    so no imbalance overflows them."""
+    first, count = held
+    t, k = idx.shape
+    local = (idx >= first) & (idx < first + count)
+    slot = jnp.where(local, idx - first, count).reshape(-1)        # (T*k,)
+    order = jnp.argsort(slot, stable=True).astype(jnp.int32)
+    inv = jnp.zeros_like(order).at[order].set(
+        jnp.arange(t * k, dtype=jnp.int32))
+    rows = jnp.sum(slot[:, None] == jnp.arange(count)[None, :], axis=0,
+                   dtype=jnp.int32)
+    pairs = jnp.broadcast_to(cast_compute(x)[:, None, :],
+                             (t, k, x.shape[-1])).reshape(t * k, -1)
+    # rows past the held pairs belong to no group.  The grouped product
+    # never writes them, forward or backward (on the TPU they hold whatever
+    # the buffer held), so they are cut off on both sides: ``ys`` before it
+    # reaches the sum, and ``xs`` so that its cotangent's tail is dropped
+    # before it is un-sorted into the tokens' gradient
+    held_row = (jnp.arange(t * k) < jnp.sum(rows))[:, None]
+    xs = jnp.where(held_row, _permute(pairs, order, inv), 0)
+
+    def grouped(a, w):
+        return jax.lax.ragged_dot(a, cast_compute(w), rows,
+                                  preferred_element_type=jnp.float32)
+
+    hid = jax.nn.silu(grouped(xs, params["w_gate"])) \
+        * grouped(xs, params["w_up"])
+    ys = grouped(cast_compute(hid), params["w_down"])    # (T*k, d) float32
+    ys = jnp.where(held_row, ys, 0.0)
+    y = _permute(ys, inv, order).reshape(t, k, -1)
+    served = jnp.all(jnp.isfinite(y), -1) & jnp.any(y != 0, -1)
+    dropped = jnp.sum(local & ~served, dtype=jnp.int32)
+    y = jnp.sum(y * jnp.where(local, weights, 0.0)[..., None], axis=1)
+    return y.astype(x.dtype), rows, dropped
+
+
+class HeldMoE(Module):
+    """Expert feed-forward layer of a sparse decoder, as ONE shard of an
+    expert-parallel job sees it: ``y = Shared(x) + sum over chosen and held
+    experts of w_e * Expert_e(x)``.
+
+    ``num_experts`` is the router's width (all experts, wherever they
+    live); ``held=(first, count)`` the experts whose weights this shard has
+    (default: all).  Routing is :func:`route_sigmoid_topk`; the correction
+    bias is model STATE (zero, not trained, not updated here: its update
+    rule belongs to a training recipe).  Experts and the ``shared`` expert
+    (width ``shared_hidden``, 0 = none) are gated SiLU without biases.
+
+    Routing statistics ride the model state (``state["metrics"]``,
+    :func:`bigdl_tpu.optim.metrics.bump_state_metrics`) out of the jitted
+    step and are booked into the metric registry at the driver's log point:
+    counters ``moe.routed_pairs`` (tokens x k), ``moe.local_pairs`` (those
+    whose expert is held), ``moe.dropped_pairs`` (held pairs whose expert
+    output came back all zero or not finite: must stay 0), and the
+    histogram ``moe.load_imbalance`` (rows of the busiest held expert over
+    the mean rows of a held expert)."""
+
+    COUNTERS = ("moe.routed_pairs", "moe.local_pairs", "moe.dropped_pairs")
+    MEANS = ("moe.load_imbalance",)
+
+    def __init__(self, num_experts: int, hidden: int, k: int, *,
+                 held: Optional[Tuple[int, int]] = None,
+                 shared_hidden: int = 0, scale: float = 1.0,
+                 norm_topk: bool = True, name: Optional[str] = None):
+        super().__init__(name)
+        self.num_experts, self.hidden, self.k = num_experts, hidden, k
+        self.held = tuple(held) if held is not None else (0, num_experts)
+        if not (0 <= self.held[0]
+                and self.held[0] + self.held[1] <= num_experts
+                and self.held[1] >= 1):
+            raise ValueError(f"held={held}: (first, count) inside "
+                             f"[0, {num_experts})")
+        self.shared_hidden = shared_hidden
+        self.scale, self.norm_topk = scale, norm_topk
+
+    def build(self, rng, x):
+        d, h, n = x.shape[-1], self.hidden, self.held[1]
+        ks = jax.random.split(rng, 5)
+
+        def w(key, shape, fan_in):
+            return jax.random.normal(key, shape) * fan_in ** -0.5
+
+        params = {"w_router": w(ks[0], (self.num_experts, d), d),
+                  "experts": {"w_gate": w(ks[1], (n, d, h), d),
+                              "w_up": w(ks[2], (n, d, h), d),
+                              "w_down": w(ks[3], (n, h, d), h)}}
+        if self.shared_hidden:
+            params["shared"] = swiglu_init(ks[4], d, self.shared_hidden)
+        state = {"router_bias": jnp.zeros((self.num_experts,)),
+                 "metrics": new_state_metrics(self.COUNTERS, self.MEANS)}
+        return params, state
+
+    def forward(self, params, state, x, training=False, rng=None):
+        shape = x.shape
+        flat = x.reshape(-1, shape[-1])
+        with jax.named_scope("moe/route"):
+            idx, w = route_sigmoid_topk(
+                flat, params["w_router"], state["router_bias"], self.k,
+                self.scale, self.norm_topk)
+        with jax.named_scope("moe/experts"):
+            y, rows, dropped = held_experts_apply(
+                params["experts"], flat, idx, w, self.held)
+        if self.shared_hidden:
+            with jax.named_scope("moe/shared"):
+                y = y + swiglu(flat, params["shared"])
+        with jax.named_scope("moe/route"):
+            n_local = jnp.sum(rows)
+            mean_rows = jnp.maximum(n_local, 1) / self.held[1]
+            metrics = bump_state_metrics(
+                state["metrics"],
+                {"moe.routed_pairs": idx.size, "moe.local_pairs": n_local,
+                 "moe.dropped_pairs": dropped},
+                {"moe.load_imbalance": jnp.max(rows) / mean_rows})
+        return y.reshape(shape), {"router_bias": state["router_bias"],
+                                  "metrics": metrics}

@@ -125,3 +125,33 @@ def test_fused_layernorm_and_int8_matmul(v5e):
     m, k, n = _K["mm"]
     _compile(lambda a, w: int8_matmul(a, w, interpret=False),
              v5e((m, k), jnp.int8), v5e((k, n), jnp.int8))
+
+
+def test_latent_attention_head_size_256(v5e):
+    """GLM-4.7-Flash's expanded attention: 20 heads of 256 over 4,096
+    positions, two sequences: a head size the kernel never ran before
+    PR 28."""
+    from bigdl_tpu.ops.flash_attention import flash_attention
+
+    q = v5e((2, 20, 4096, 256), jnp.float32)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False).sum()
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+
+
+def test_grouped_expert_product_at_the_cell_sizes(v5e):
+    """The held-share expert layer's grouped products (``jax.lax.
+    ragged_dot``: 32,768 pair rows, 8 experts of 2048 x 1536) and their
+    gradients compile for the chip."""
+    def loss(x, w, sizes):
+        return jax.lax.ragged_dot(
+            x, w, sizes, preferred_element_type=jnp.float32).sum()
+
+    # production's matmul precision, not the test session's "highest"
+    # (tests/conftest.py), which the chip's grouped kernel refuses
+    with jax.default_matmul_precision("bfloat16"):
+        _compile(jax.grad(loss, argnums=(0, 1)),
+                 v5e((32768, 2048), jnp.bfloat16),
+                 v5e((8, 2048, 1536), jnp.bfloat16), v5e((8,), jnp.int32))
