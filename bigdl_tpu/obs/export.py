@@ -108,7 +108,13 @@ DEFAULT_HELP = {
     "ops.autotune_cache_hits": "kernel tile lookups answered from the "
                                "autotune cache",
     "ops.autotune_cache_misses": "kernel tile lookups that fell back to "
-                                 "hand-picked defaults (no cache entry)",
+                                 "the defaults (no cache entry)",
+    "kernel.flash.traces": "flash_attention lowerings traced, by direction "
+                           "(fwd, bwd), implementation and operand dtype",
+    "kernel.flash.tile_share": "tiles the last-traced flash_attention "
+                               "kernels visit / tiles in the q-block x "
+                               "k-block rectangle, by direction (causal "
+                               "skipping: 0.5625 at 8 x 8 blocks)",
     "parallel.layout.replicated_params": "parameters the declarative "
                                          "layout silently replicated "
                                          "(matched no table rule / rank-"

@@ -24,7 +24,8 @@ Guarantees:
   the cache entirely for that axis.
 
 Resolution order at kernel call time (``resolve``): explicit kwarg >
-cached winner > registry default.  Online tuning (measure on first miss)
+cached winner > registry default (for flash attention a rule on the shape
+the call sees, ``KernelSpec.default_rule``).  Online tuning (measure on first miss)
 only ever happens on CONCRETE arrays — inside a ``jit`` trace the kernel
 sees tracers and falls back to cache/defaults, so the offline CLI is how
 the training path gets tuned tiles::
@@ -58,6 +59,7 @@ import numpy as np
 
 from bigdl_tpu.automl import hp as hp_mod
 from bigdl_tpu.automl.search import GridSearcher, TPESearcher
+from bigdl_tpu.ops.flash_attention import BLOCK_CHOICES, default_blocks
 from bigdl_tpu.utils.log import get_logger
 
 log = get_logger(__name__)
@@ -223,6 +225,17 @@ class KernelSpec:
     key_fn: Callable[[Tuple], str] = None
     # CLI bench shapes: {label: shape_key}; "small" labels run under --small
     bench_shapes: Dict[str, Tuple] = dataclasses.field(default_factory=dict)
+    # defaults that follow the shape: (shape_key) -> tiles.  Where set, the
+    # fixed ``defaults`` above are only the answer when no shape is known
+    default_rule: Optional[Callable[[Tuple], Dict[str, int]]] = None
+
+    def defaults_for(self, shape_key: Optional[Tuple] = None
+                     ) -> Dict[str, int]:
+        """The untuned tiles at ``shape_key``: the rule's pick where the
+        kernel has one, else the fixed defaults."""
+        if self.default_rule is None or shape_key is None:
+            return dict(self.defaults)
+        return dict(self.default_rule(tuple(shape_key)))
 
 
 def _flash_inputs(shape_key):
@@ -234,6 +247,16 @@ def _flash_inputs(shape_key):
     k = jnp.asarray(rs.randn(b, h, s, d), dtype)
     v = jnp.asarray(rs.randn(b, h, s, d), dtype)
     return q, k, v
+
+
+def _flash_rule(direction):
+    """``flash_attention``'s block rule (``ops.flash_attention.
+    default_blocks``) at a ``(b, h, s, d, dtype)`` bench shape."""
+    def rule(shape_key):
+        _, _, s, d, dtype = shape_key
+        return default_blocks(direction, s, s, d, np.dtype(dtype).itemsize)
+
+    return rule
 
 
 def _flash_fwd_builder(shape_key):
@@ -261,10 +284,11 @@ def _flash_bwd_builder(shape_key):
 
     def make(cfg):
         def loss(qq):
+            # an explicit block_q is the backward pair's too (and the
+            # forward's, whose block_k stays its own default)
             return flash_attention(
-                qq, k, v, causal=True, block_q=cfg.get("block_q", 128),
-                block_k=128, block_k_bwd=cfg["block_k"]).astype(
-                    jnp.float32).sum()
+                qq, k, v, causal=True, block_q=cfg["block_q"],
+                block_k_bwd=cfg["block_k"]).astype(jnp.float32).sum()
 
         return jax.jit(lambda: jax.grad(loss)(q))
 
@@ -357,13 +381,16 @@ def _bs_builder(shape_key):
 
 
 _TILE_CHOICES = [64, 128, 256, 512]
+# what flash_attention's block rule chooses among: the spaces hold its picks
+_FLASH_BLOCKS = list(BLOCK_CHOICES)
 
 REGISTRY: Dict[str, KernelSpec] = {
     "flash_attention_fwd": KernelSpec(
         name="flash_attention_fwd",
-        space={"block_q": hp_mod.choice([64, 128, 256, 512]),
-               "block_k": hp_mod.choice([128, 256, 512, 1024])},
+        space={"block_q": hp_mod.choice(_FLASH_BLOCKS),
+               "block_k": hp_mod.choice(_FLASH_BLOCKS)},
         defaults={"block_q": 128, "block_k": 128},
+        default_rule=_flash_rule("fwd"),
         builder=_flash_fwd_builder,
         key_fn=lambda sk: attention_key(sk[:4], sk[2], sk[4]),
         bench_shapes={
@@ -372,8 +399,10 @@ REGISTRY: Dict[str, KernelSpec] = {
         }),
     "flash_attention_bwd": KernelSpec(
         name="flash_attention_bwd",
-        space={"block_k": hp_mod.choice([64, 128, 256, 512])},
-        defaults={"block_k": 128},
+        space={"block_q": hp_mod.choice(_FLASH_BLOCKS),
+               "block_k": hp_mod.choice(_FLASH_BLOCKS)},
+        defaults={"block_q": 128, "block_k": 128},
+        default_rule=_flash_rule("bwd"),
         builder=_flash_bwd_builder,
         key_fn=lambda sk: attention_key(sk[:4], sk[2], sk[4]),
         bench_shapes={
@@ -521,7 +550,8 @@ def tune(kernel: str, shape_key: Tuple, *, key: Optional[str] = None,
         _metrics().inc("ops.autotune_trials")
         return _measure_ms(make(cfg), repeats=repeats)
 
-    default_ms = trial_fn(dict(spec.defaults))
+    defaults = spec.defaults_for(shape_key)
+    default_ms = trial_fn(dict(defaults))
     if _space_size(spec.space) <= max(GRID_LIMIT, n_trials):
         searcher = GridSearcher(mode="min")
         n = 0  # grid: exhaust the space
@@ -532,7 +562,7 @@ def tune(kernel: str, shape_key: Tuple, *, key: Optional[str] = None,
     if best.error is None and best.metric < default_ms:
         tiles, best_ms, winner = dict(best.config), best.metric, "searched"
     else:  # the guarantee: never slower than the hand-picked defaults
-        tiles, best_ms, winner = dict(spec.defaults), default_ms, "default"
+        tiles, best_ms, winner = dict(defaults), default_ms, "default"
     tiles = {k: v for k, v in tiles.items() if not k.startswith("_")}
     entry = {"tiles": tiles, "best_ms": round(best_ms, 4),
              "default_ms": round(default_ms, 4), "trials": trials["n"],
@@ -555,13 +585,17 @@ def _shape_label(shape_key: Tuple) -> str:
 
 def resolve(kernel: str, shape_key: str,
             explicit: Optional[Dict[str, Optional[int]]] = None,
-            online_shape: Optional[Tuple] = None) -> Dict[str, int]:
+            online_shape: Optional[Tuple] = None,
+            defaults: Optional[Dict[str, int]] = None) -> Dict[str, int]:
     """Tiles for one kernel call.  Per axis: explicit kwarg (not None) >
-    cached winner > registry default.  In ``online`` mode a cache miss
-    with a concrete ``online_shape`` triggers a tuning run first (eager
-    calls only — the kernels never pass ``online_shape`` from a trace)."""
+    cached winner > default.  ``defaults`` is the caller's pick for the
+    shape it sees (a kernel whose registry default is a rule passes the
+    rule's answer); without it the registry's fixed defaults apply.  In
+    ``online`` mode a cache miss with a concrete ``online_shape`` triggers
+    a tuning run first (eager calls only — the kernels never pass
+    ``online_shape`` from a trace)."""
     spec = REGISTRY[kernel]
-    tiles = dict(spec.defaults)
+    tiles = dict(spec.defaults if defaults is None else defaults)
     explicit = {k: v for k, v in (explicit or {}).items() if v is not None}
     mode = autotune_mode()
     if mode != "off" and len(explicit) < len(tiles):

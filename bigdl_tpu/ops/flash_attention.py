@@ -1,28 +1,44 @@
-"""Blockwise fused (flash) attention — Pallas TPU kernel.
+"""Blockwise fused (flash) attention — Pallas TPU kernels.
 
 Reference analog: the reference has NO fused attention — its
 ``nn/Attention.scala`` / Keras ``TransformerLayer`` materialise the full
-O(S²) score matrix on one device (SURVEY.md §6.7).  This kernel is the
-TPU-native upgrade: online-softmax blockwise attention that keeps exactly
-one (block_q × d) query tile and one (block_k × d) key/value tile in VMEM
-at a time, so peak on-chip memory is O(block·d) and the matmuls stay on
-the MXU.
+O(S²) score matrix on one device (SURVEY.md §6.7).  These kernels are the
+TPU-native upgrade: online-softmax blockwise attention that keeps one
+(block_q × d) query tile and one (block_k × d) key/value tile in VMEM at a
+time, so peak on-chip memory is O(block·d) and the matmuls stay on the
+MXU.
 
-Forward is a Pallas kernel with grid (batch·heads, q-blocks, k-blocks);
-the k dimension is innermost and iterates sequentially on-core, carrying
-the online-softmax running (max, denom, accumulator) in VMEM scratch —
-the k/v BlockSpecs stream one tile per step from HBM.  Backward is a
-custom VJP: the standard flash-attention backward recurrence evaluated
-blockwise with a ``lax.scan`` over k/v tiles using the saved logsumexp,
-so the O(S²) score matrix is never materialised in either direction
-(single-chip long context; cross-chip sequence parallelism lives in
-``bigdl_tpu/parallel/ring_attention.py``).
+Training path (``flash_attention``): three Pallas kernels under one custom
+VJP.  The forward (grid batch·heads × q-blocks × k-blocks, k innermost)
+carries the online-softmax running (max, denom, accumulator) in VMEM
+scratch and saves the logsumexp; the backward is the standard pair that
+rebuilds ``p`` from it: a dk/dv kernel (grid over k-blocks, q-blocks
+innermost) and a dq kernel (grid over q-blocks, k-blocks innermost), with
+``delta = rowsum(g ⊙ out)`` computed once outside.  All three:
+
+- take their operands in the policy's compute dtype
+  (``tensor.policy.cast_compute``, as ``nn.attention.
+  dot_product_attention`` does) and multiply the tiles as they come with
+  float32 accumulation; scores, softmax statistics, ``lse``, ``delta``
+  and every accumulator are float32, ``p``/``ds`` are rounded to the
+  compute dtype only as a second product's operand;
+- visit only the tiles the causal mask leaves: a tile strictly above the
+  diagonal is neither multiplied nor fetched (its index map is clamped to
+  the nearest tile the row/column does visit, so the skipped grid step
+  names a tile already resident), and the element mask is applied only on
+  tiles the diagonal (or the key padding) crosses;
+- take their blocks from :func:`default_blocks`, a rule on the shape the
+  call can see (docs/performance.md §Kernel autotuning).
+
+Cross-chip sequence parallelism lives in
+``bigdl_tpu/parallel/ring_attention.py``; the paged decode/verify kernels
+below are the serving-side siblings.
 
 Shapes: q, k, v are (batch, heads, seq, head_dim); output matches q.
 """
 
 import functools
-from typing import Optional
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -30,97 +46,233 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from bigdl_tpu.ops.common import default_interpret, round_up
+from bigdl_tpu.tensor.policy import cast_compute
+from bigdl_tpu.utils.log import get_logger
+
+log = get_logger(__name__)
 
 _NEG_INF = -1e30
+# contract the last dim of both operands: a @ b.T without the transpose
+_NT = (((1,), (1,)), ((), ()))
+
+# What a training kernel may hold in VMEM (Mosaic's scoped default is 16 MiB
+# of the v5e core's 128): the limit handed to the compiler, and the share of
+# it the block rule fills with what it can count (the pipeline's double
+# buffers, the scratch accumulators, the float32 score-tile temporaries);
+# the rest is the compiler's own.
+_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+_VMEM_BLOCK_BUDGET = _VMEM_LIMIT_BYTES // 2
+_LANES = 128
+# the blocks the rule (and the autotune spaces) choose among: multiples of
+# 128 that nest, so a length one of them divides is divided by the smaller
+BLOCK_CHOICES = (128, 256, 512, 1024)
+
+
+def _tile_bytes(rows, cols, itemsize):
+    """VMEM bytes of one (rows, cols) buffer: the lane dim pads to 128."""
+    return rows * round_up(cols, _LANES) * itemsize
+
+
+def block_vmem_bytes(direction: str, block_q: int, block_k: int, d: int,
+                     itemsize: int) -> int:
+    """What one grid step holds in VMEM at these blocks, ``fwd`` or ``bwd``
+    (the larger of the dq and the dk/dv kernel, which share one pair):
+    double-buffered operand and result tiles, the per-row statistics
+    (lane- or sublane-padded), float32 accumulators, and the float32
+    (block_q, block_k) temporaries of the score tile."""
+    q_tile = _tile_bytes(block_q, d, itemsize)
+    k_tile = _tile_bytes(block_k, d, itemsize)
+    col = _tile_bytes(block_q, 1, 4)
+    scores = block_q * block_k * 4
+    if direction == "fwd":    # q, k, v -> out, lse; m, l, acc; s, p, p~
+        return (2 * (2 * q_tile + 2 * k_tile + col) + 2 * col
+                + _tile_bytes(block_q, d, 4) + 3 * scores)
+    # dq: q, g, k, v, lse, delta -> dq; acc.  dk/dv: the same operands
+    # (statistics as rows) -> dk, dv; two acc.  Both: s/p, dp, ds, ds~
+    dq = (2 * (3 * q_tile + 2 * k_tile + 2 * col)
+          + _tile_bytes(block_q, d, 4))
+    dkv = (2 * (2 * q_tile + 4 * k_tile + 2 * _tile_bytes(8, block_q, 4))
+           + 2 * _tile_bytes(block_k, d, 4))
+    return max(dq, dkv) + 4 * scores
+
+
+def default_blocks(direction: str, sq: int, skv: int, d: int,
+                   itemsize: int) -> Dict[str, int]:
+    """The block rule: the largest ``(block_q, block_k)`` of
+    ``BLOCK_CHOICES``, none longer than the 128-padded sequence, whose
+    :func:`block_vmem_bytes` fits ``_VMEM_BLOCK_BUDGET``.  Largest by area
+    (fewest grid steps, least re-reading of K/V per query row); between
+    equal areas the squarer pair, then the longer ``block_k`` (the
+    contraction the accumulator is rescaled over)."""
+    best = None
+    for bq in BLOCK_CHOICES:
+        for bk in BLOCK_CHOICES:
+            if (bq > round_up(sq, _LANES) or bk > round_up(skv, _LANES)
+                    or block_vmem_bytes(direction, bq, bk, d, itemsize)
+                    > _VMEM_BLOCK_BUDGET):
+                continue
+            rank = (bq * bk, -abs(bq - bk), bk)
+            if best is None or rank > best[0]:
+                best = (rank, bq, bk)
+    if best is None:  # a head size no 128 x 128 step fits: let Mosaic say so
+        return {"block_q": _LANES, "block_k": _LANES}
+    return {"block_q": best[1], "block_k": best[2]}
+
+
+def _clip_blocks(block_q, block_k, sq, skv):
+    """Blocks no longer than the (8-padded) sequence, and the padded
+    lengths they tile."""
+    bq = min(block_q, round_up(sq, 8))
+    bk = min(block_k, round_up(skv, 8))
+    return bq, bk, round_up(sq, bq), round_up(skv, bk)
+
+
+def tile_share(sq: int, skv: int, block_q: int, block_k: int,
+               causal: bool) -> float:
+    """Tiles the kernels visit / tiles in the (q-blocks x k-blocks)
+    rectangle: 1.0 non-causal, 36/64 at 8 x 8 blocks causal."""
+    bq, bk, sq_p, skv_p = _clip_blocks(block_q, block_k, sq, skv)
+    nq, nk = sq_p // bq, skv_p // bk
+    if not causal:
+        return 1.0
+    visited = sum(min(((i + 1) * bq - 1) // bk, nk - 1) + 1
+                  for i in range(nq))
+    return visited / (nq * nk)
+
+
+def _mxu(a, b, dims=(((1,), (0,)), ((), ()))):
+    """One tile product on the MXU, float32 out.  Operands narrower than
+    float32 are multiplied as they are (a session-wide
+    ``jax_default_matmul_precision`` of "highest" asks nothing more of a
+    bfloat16 tile, and Mosaic refuses it there); float32 operands follow
+    that setting."""
+    precision = None if a.dtype == jnp.float32 else jax.lax.Precision.DEFAULT
+    return jax.lax.dot_general(a, b, dims, precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
+def _pad_seq(x, to):
+    s = x.shape[2]
+    return x if s == to else jnp.pad(
+        x, ((0, 0), (0, 0), (0, to - s), (0, 0)))
+
+
+def _compiler_params():
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=_VMEM_LIMIT_BYTES)
+
+
+def _tile_predicates(qi, kj, *, causal, block_q, block_k, kv_len, skv_p):
+    """(visited, masked) for tile (qi, kj): whether the causal mask leaves
+    anything of it, and whether the diagonal or the key padding crosses it
+    (only then is the element mask built).  Python bools where static."""
+    visited, masked = True, False
+    if causal:
+        visited = kj * block_k < (qi + 1) * block_q
+        masked = (kj + 1) * block_k - 1 > qi * block_q
+    if skv_p != kv_len:
+        pad = (kj + 1) * block_k > kv_len
+        masked = pad if masked is False else jnp.logical_or(masked, pad)
+    return visited, masked
+
+
+def _for_visited_tiles(visit, visited, masked):
+    """Run ``visit(masked=...)`` under the tile's predicates: one
+    specialisation with the element mask, one without (``pl.when`` on a
+    Python bool is a plain ``if``)."""
+    if masked is False:
+        pl.when(visited)(lambda: visit(False))
+        return
+    pl.when(jnp.logical_and(visited, masked))(lambda: visit(True))
+    pl.when(jnp.logical_and(visited, jnp.logical_not(masked)))(
+        lambda: visit(False))
+
+
+def _element_mask(q0, k0, shape, q_axis, *, causal, kv_len):
+    """True where key position < kv_len (and <= query position when
+    causal), for a score tile whose queries run along ``q_axis``."""
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    mask = k_pos < kv_len
+    if causal:
+        q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+        mask = jnp.logical_and(mask, k_pos <= q_pos)
+    return mask
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, sm_scale, causal, block_q, block_k, kv_len):
+                *, sm_scale, causal, block_q, block_k, kv_len, skv_p):
     # q_ref: (1, block_q, d); k_ref/v_ref: (1, block_k, d) — one tile each.
     qi = pl.program_id(1)
     kj = pl.program_id(2)
-    num_kb = pl.num_programs(2)
 
     @pl.when(kj == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # causal: skip k-blocks strictly above this q-block's diagonal band
-    needed = jnp.bool_(True)
-    if causal:
-        needed = kj * block_k < (qi + 1) * block_q
-
-    @pl.when(needed)
-    def _step():
-        q = q_ref[0].astype(jnp.float32) * sm_scale
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-
-        k_pos = kj * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = k_pos < kv_len
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            mask = jnp.logical_and(mask, k_pos <= q_pos)
-        s = jnp.where(mask, s, _NEG_INF)
-
-        m_prev = m_scr[:, 0]
-        l_prev = l_scr[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+    def visit(masked):
+        v = v_ref[0]
+        s = _mxu(q_ref[0], k_ref[0], _NT) * sm_scale
+        if masked:
+            s = jnp.where(_element_mask(
+                qi * block_q, kj * block_k, s.shape, 0, causal=causal,
+                kv_len=kv_len), s, _NEG_INF)
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        m_scr[:, 0] = m_new
-        l_scr[:, 0] = l_prev * alpha + jnp.sum(p, axis=-1)
-        acc_scr[:] = acc_scr[:] * alpha[:, None] + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
+        m_scr[...] = m_new
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + _mxu(p.astype(v.dtype), v)
 
-    @pl.when(kj == num_kb - 1)
+    _for_visited_tiles(visit, *_tile_predicates(
+        qi, kj, causal=causal, block_q=block_q, block_k=block_k,
+        kv_len=kv_len, skv_p=skv_p))
+
+    @pl.when(kj == pl.num_programs(2) - 1)
     def _finish():
-        m = m_scr[:, 0]
-        l = l_scr[:, 0]
+        l = l_scr[...]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[:] / l_safe[:, None]).astype(o_ref.dtype)
-        lse_ref[0, :, 0] = m + jnp.log(l_safe)
+        o_ref[0] = (acc_scr[...] * (1.0 / l_safe)).astype(o_ref.dtype)
+        lse_ref[0] = m_scr[...] + jnp.log(l_safe)
+
+
+def _kv_index_map(causal, bq, bk, nk):
+    """K/V tile of grid step (bh, i, j).  Causal: steps past the last tile
+    q-block ``i`` visits re-name that tile, so nothing is fetched for
+    them."""
+    if not causal:
+        return lambda bh, i, j: (bh, j, 0)
+    return lambda bh, i, j: (
+        bh, jnp.minimum(j, jnp.minimum(((i + 1) * bq - 1) // bk, nk - 1)), 0)
 
 
 def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     b, h, sq, d = q.shape
     skv = k.shape[2]
-    bq = min(block_q, round_up(sq, 8))
-    bk = min(block_k, round_up(skv, 8))
-    sq_p, skv_p = round_up(sq, bq), round_up(skv, bk)
+    bq, bk, sq_p, skv_p = _clip_blocks(block_q, block_k, sq, skv)
+    qp = _pad_seq(q, sq_p).reshape(b * h, sq_p, d)
+    kp = _pad_seq(k, skv_p).reshape(b * h, skv_p, d)
+    vp = _pad_seq(v, skv_p).reshape(b * h, skv_p, d)
 
-    qp = jnp.pad(q, ((0, 0), (0, 0), (0, sq_p - sq), (0, 0)))
-    kp = jnp.pad(k, ((0, 0), (0, 0), (0, skv_p - skv), (0, 0)))
-    vp = jnp.pad(v, ((0, 0), (0, 0), (0, skv_p - skv), (0, 0)))
-    qp = qp.reshape(b * h, sq_p, d)
-    kp = kp.reshape(b * h, skv_p, d)
-    vp = vp.reshape(b * h, skv_p, d)
-
-    grid = (b * h, sq_p // bq, skv_p // bk)
+    nq, nk = sq_p // bq, skv_p // bk
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal, block_q=bq,
-        block_k=bk, kv_len=skv)
+        block_k=bk, kv_len=skv, skv_p=skv_p)
+    q_spec = pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0))
+    kv_spec = pl.BlockSpec((1, bk, d), _kv_index_map(causal, bq, bk, nk))
+    # the row statistics carry a trailing singleton lane dim: a 2-D (1, bq)
+    # block would put bq in the lane slot and 1 in the sublane slot, which
+    # TPU tiling rejects when batch·heads > 1.
+    stat_spec = pl.BlockSpec((1, bq, 1), lambda bh, i, j: (bh, i, 0))
 
     out, lse = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-            # lse carries a trailing singleton lane dim: a 2-D (1, bq) block
-            # would put bq in the lane slot and 1 in the sublane slot, which
-            # TPU tiling rejects when batch·heads > 1.
-            pl.BlockSpec((1, bq, 1), lambda bh, i, j: (bh, i, 0)),
-        ],
+        grid=(b * h, nq, nk),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=[q_spec, stat_spec],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, sq_p, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, sq_p, 1), jnp.float32),
@@ -130,6 +282,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
             pltpu.VMEM((bq, 1), jnp.float32),   # running denom
             pltpu.VMEM((bq, d), jnp.float32),   # output accumulator
         ],
+        compiler_params=_compiler_params(),
         interpret=default_interpret(interpret),
     )(qp, kp, vp)
 
@@ -138,73 +291,171 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     return out, lse  # lse: (b, h, sq)
 
 
-def _blockwise_bwd(q, k, v, out, lse, g, sm_scale, causal, block_k=128):
-    """Memory-efficient flash-attention backward: a ``lax.scan`` over k/v
-    blocks reconstructs one (sq × block_k) score tile at a time from the
-    saved logsumexp — peak memory O(S·block) instead of the O(S²) full
-    score matrix.  Recurrence: p = exp(q·kᵀ·scale − lse);
-    D = rowsum(g ⊙ out); dS = p ⊙ (g·vᵀ − D)·scale."""
-    qf = q.astype(jnp.float32)
-    kf = k.astype(jnp.float32)
-    vf = v.astype(jnp.float32)
-    gf = g.astype(jnp.float32)
-    b, h, sq, d = qf.shape
-    skv = kf.shape[2]
-    bk = min(block_k, round_up(skv, 8))
-    skv_p = round_up(skv, bk)
-    kp = jnp.pad(kf, ((0, 0), (0, 0), (0, skv_p - skv), (0, 0)))
-    vp = jnp.pad(vf, ((0, 0), (0, 0), (0, skv_p - skv), (0, 0)))
-    # (nblocks, b, h, bk, d) scan layout
-    kb = kp.reshape(b, h, skv_p // bk, bk, d).transpose(2, 0, 1, 3, 4)
-    vb = vp.reshape(b, h, skv_p // bk, bk, d).transpose(2, 0, 1, 3, 4)
+def _dq_kernel(q_ref, g_ref, lse_ref, delta_ref, k_ref, v_ref, dq_ref,
+               dq_scr, *, sm_scale, causal, block_q, block_k, kv_len,
+               skv_p):
+    """dq for one q-block: p rebuilt from lse, ds = p ⊙ (g·vᵀ − delta),
+    dq = scale · Σ_k ds·k over the k-blocks at or below the diagonal."""
+    qi = pl.program_id(1)
+    kj = pl.program_id(2)
 
-    delta = jnp.sum(gf * out.astype(jnp.float32), axis=-1)  # (b,h,sq)
-    q_pos = jax.lax.broadcasted_iota(jnp.int32, (sq, bk), 0)
-    k_off = jax.lax.broadcasted_iota(jnp.int32, (sq, bk), 1)
+    @pl.when(kj == 0)
+    def _init():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    def step(dq_acc, inp):
-        j, k_j, v_j = inp
-        s = jnp.einsum("bhqd,bhkd->bhqk", qf, k_j) * sm_scale
-        k_pos = j * bk + k_off
-        mask = k_pos < skv
+    def visit(masked):
+        k = k_ref[0]
+        s = _mxu(q_ref[0], k, _NT) * sm_scale
+        if masked:
+            s = jnp.where(_element_mask(
+                qi * block_q, kj * block_k, s.shape, 0, causal=causal,
+                kv_len=kv_len), s, _NEG_INF)
+        p = jnp.exp(s - lse_ref[0])
+        dp = _mxu(g_ref[0], v_ref[0], _NT)
+        ds = p * (dp - delta_ref[0])
+        dq_scr[...] += _mxu(ds.astype(k.dtype), k)
+
+    _for_visited_tiles(visit, *_tile_predicates(
+        qi, kj, causal=causal, block_q=block_q, block_k=block_k,
+        kv_len=kv_len, skv_p=skv_p))
+
+    @pl.when(kj == pl.num_programs(2) - 1)
+    def _finish():
+        dq_ref[0] = (dq_scr[...] * sm_scale).astype(dq_ref.dtype)
+
+
+def _dkv_kernel(q_ref, g_ref, lse_ref, delta_ref, k_ref, v_ref, dk_ref,
+                dv_ref, dk_scr, dv_scr, *, sm_scale, causal, block_q,
+                block_k, kv_len, skv_p):
+    """dk, dv for one k-block, over the q-blocks at or below the diagonal.
+    The score tile is built TRANSPOSED (keys along rows, k·qᵀ), so both
+    accumulating products are plain (block_k, block_q) @ (block_q, d)
+    matmuls and the per-query lse/delta broadcast along rows: nothing is
+    transposed in the kernel."""
+    kj = pl.program_id(1)
+    qi = pl.program_id(2)
+
+    @pl.when(qi == 0)
+    def _init():
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    def visit(masked):
+        q, g = q_ref[0], g_ref[0]
+        s_t = _mxu(k_ref[0], q, _NT) * sm_scale
+        if masked:
+            s_t = jnp.where(_element_mask(
+                qi * block_q, kj * block_k, s_t.shape, 1, causal=causal,
+                kv_len=kv_len), s_t, _NEG_INF)
+        p_t = jnp.exp(s_t - lse_ref[0, 0])
+        dv_scr[...] += _mxu(p_t.astype(g.dtype), g)
+        dp_t = _mxu(v_ref[0], g, _NT)
+        ds_t = p_t * (dp_t - delta_ref[0, 0])
+        dk_scr[...] += _mxu(ds_t.astype(q.dtype), q)
+
+    _for_visited_tiles(visit, *_tile_predicates(
+        qi, kj, causal=causal, block_q=block_q, block_k=block_k,
+        kv_len=kv_len, skv_p=skv_p))
+
+    @pl.when(qi == pl.num_programs(2) - 1)
+    def _finish():
+        dk_ref[0] = (dk_scr[...] * sm_scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+
+
+def _flash_bwd(q, k, v, out, lse, g, sm_scale, causal, block_q, block_k,
+               interpret):
+    """The Pallas backward pair.  Padding rows and columns carry zero
+    gradient: padded queries have g = 0 and delta = 0, padded keys are
+    masked out of ``p``; both are sliced off the results."""
+    b, h, sq, d = q.shape
+    skv = k.shape[2]
+    bq, bk, sq_p, skv_p = _clip_blocks(block_q, block_k, sq, skv)
+    nq, nk = sq_p // bq, skv_p // bk
+    bh = b * h
+
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), -1)
+    qp = _pad_seq(q, sq_p).reshape(bh, sq_p, d)
+    gp = _pad_seq(g, sq_p).reshape(bh, sq_p, d)
+    kp = _pad_seq(k, skv_p).reshape(bh, skv_p, d)
+    vp = _pad_seq(v, skv_p).reshape(bh, skv_p, d)
+    stats = [jnp.pad(x.reshape(bh, sq), ((0, 0), (0, sq_p - sq)))
+             for x in (lse, delta)]
+
+    static = dict(sm_scale=sm_scale, causal=causal, block_q=bq, block_k=bk,
+                  kv_len=skv, skv_p=skv_p)
+    interpret = default_interpret(interpret)
+
+    # dq: grid (bh, q-blocks, k-blocks); statistics as (rows, 1) columns
+    q_spec = pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0))
+    col_spec = pl.BlockSpec((1, bq, 1), lambda bh, i, j: (bh, i, 0))
+    kv_spec = pl.BlockSpec((1, bk, d), _kv_index_map(causal, bq, bk, nk))
+    dq = pl.pallas_call(
+        functools.partial(_dq_kernel, **static),
+        grid=(bh, nq, nk),
+        in_specs=[q_spec, q_spec, col_spec, col_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((bh, sq_p, d), q.dtype),
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        compiler_params=_compiler_params(),
+        interpret=interpret,
+    )(qp, gp, *(x[:, :, None] for x in stats), kp, vp)
+
+    # dk/dv: grid (bh, k-blocks, q-blocks); statistics as (1, block_q) rows
+    # of a (bh, q-blocks, 1, block_q) view, whose last two block dims span
+    # the array's: legal at any block_q.  Causal: the q-blocks before the
+    # first one k-block j reaches re-name that first tile.
+    def q_block(j, i):
         if causal:
-            mask = jnp.logical_and(mask, k_pos <= q_pos)
-        p = jnp.where(mask, jnp.exp(s - lse[..., None]), 0.0)
-        dv_j = jnp.einsum("bhqk,bhqd->bhkd", p, gf)
-        dp = jnp.einsum("bhqd,bhkd->bhqk", gf, v_j)
-        ds = p * (dp - delta[..., None]) * sm_scale
-        dq_acc = dq_acc + jnp.einsum("bhqk,bhkd->bhqd", ds, k_j)
-        dk_j = jnp.einsum("bhqk,bhqd->bhkd", ds, qf)
-        return dq_acc, (dk_j, dv_j)
+            i = jnp.maximum(i, jnp.minimum((j * bk) // bq, nq - 1))
+        return i
 
-    nb = skv_p // bk
-    dq, (dk_b, dv_b) = jax.lax.scan(
-        step, jnp.zeros_like(qf), (jnp.arange(nb), kb, vb))
-    dk = dk_b.transpose(1, 2, 0, 3, 4).reshape(b, h, skv_p, d)[:, :, :skv]
-    dv = dv_b.transpose(1, 2, 0, 3, 4).reshape(b, h, skv_p, d)[:, :, :skv]
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    qd_spec = pl.BlockSpec((1, bq, d),
+                           lambda bh, j, i: (bh, q_block(j, i), 0))
+    row_spec = pl.BlockSpec(
+        (1, 1, 1, bq), lambda bh, j, i: (bh, q_block(j, i), 0, 0))
+    kd_spec = pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0))
+    dk, dv = pl.pallas_call(
+        functools.partial(_dkv_kernel, **static),
+        grid=(bh, nk, nq),
+        in_specs=[qd_spec, qd_spec, row_spec, row_spec, kd_spec, kd_spec],
+        out_specs=[kd_spec, kd_spec],
+        out_shape=[jax.ShapeDtypeStruct((bh, skv_p, d), k.dtype),
+                   jax.ShapeDtypeStruct((bh, skv_p, d), v.dtype)],
+        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
+                        pltpu.VMEM((bk, d), jnp.float32)],
+        compiler_params=_compiler_params(),
+        interpret=interpret,
+    )(qp, gp, *(x.reshape(bh, nq, 1, bq) for x in stats), kp, vp)
+
+    dq = dq.reshape(b, h, sq_p, d)[:, :, :sq]
+    dk = dk.reshape(b, h, skv_p, d)[:, :, :skv]
+    dv = dv.reshape(b, h, skv_p, d)[:, :, :skv]
+    return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash(q, k, v, sm_scale, causal, block_q, block_k, block_k_bwd,
-           interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, sm_scale, causal, block_q, block_k, block_q_bwd,
+           block_k_bwd, interpret):
     out, _ = _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k,
                         interpret)
     return out
 
 
 def _flash_vjp_fwd(q, k, v, sm_scale, causal, block_q, block_k,
-                   block_k_bwd, interpret):
+                   block_q_bwd, block_k_bwd, interpret):
     out, lse = _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k,
                           interpret)
     return out, (q, k, v, out, lse)
 
 
-def _flash_vjp_bwd(sm_scale, causal, block_q, block_k, block_k_bwd,
-                   interpret, res, g):
+def _flash_vjp_bwd(sm_scale, causal, block_q, block_k, block_q_bwd,
+                   block_k_bwd, interpret, res, g):
     q, k, v, out, lse = res
-    return _blockwise_bwd(q, k, v, out, lse, g, sm_scale, causal,
-                          block_k=block_k_bwd)
+    _book_trace("bwd", q.shape, k.shape[2], q.dtype, causal, block_q_bwd,
+                block_k_bwd)
+    return _flash_bwd(q, k, v, out, lse, g, sm_scale, causal, block_q_bwd,
+                      block_k_bwd, interpret)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -524,6 +775,59 @@ def paged_verify_attention(q, k_pages, v_pages, page_table, positions, *,
     )(*scalars, q, k_pages, v_pages)
 
 
+@functools.lru_cache(maxsize=None)
+def _log_blocks_once(direction, q_shape, skv, dtype, causal, block_q,
+                     block_k):
+    log.info("flash_attention %s q%s kv %d %s causal=%s: blocks %d x %d",
+             direction, q_shape, skv, dtype, causal, block_q, block_k)
+
+
+def _book_trace(direction, q_shape, skv, dtype, causal, block_q, block_k):
+    """Trace-time bookkeeping (nothing of it runs inside the step): that
+    this direction was lowered to the Pallas kernels, on what operand
+    dtype, how much of the tile rectangle it visits, and — once per shape
+    — the blocks chosen."""
+    from bigdl_tpu.optim.metrics import global_metrics
+
+    m = global_metrics()
+    m.inc("kernel.flash.traces", labels={
+        "direction": direction, "impl": "pallas", "dtype": dtype.name})
+    m.gauge("kernel.flash.tile_share",
+            tile_share(q_shape[2], skv, block_q, block_k, causal),
+            labels={"direction": direction})
+    _log_blocks_once(direction, tuple(q_shape), skv, dtype.name, causal,
+                     block_q, block_k)
+
+
+def resolve_blocks(q_shape, skv, dtype, *, block_q=None, block_k=None,
+                   block_k_bwd=None, online_shape=None):
+    """``(forward, backward)`` blocks of one call on operands of ``dtype``:
+    per axis an explicit kwarg, else a cached autotune winner for this
+    device/shape bucket, else :func:`default_blocks`' pick.  Explicit
+    ``block_q``/``block_k`` also pin the backward pair's; ``block_k_bwd``
+    frees its key block again."""
+    from bigdl_tpu.ops import autotune
+
+    dtype = jnp.dtype(dtype)
+    sq, d = q_shape[2], q_shape[3]
+    key = autotune.attention_key(q_shape, skv, dtype)
+    fwd = autotune.resolve(
+        "flash_attention_fwd", key,
+        explicit={"block_q": block_q, "block_k": block_k},
+        online_shape=online_shape,
+        defaults=default_blocks("fwd", sq, skv, d, dtype.itemsize))
+    # the backward: cache/defaults only — no online_shape: a forward-only
+    # eager call must not pay a jax.grad tuning sweep for a backward it
+    # may never run (the offline CLI tunes flash_attention_bwd)
+    bwd = autotune.resolve(
+        "flash_attention_bwd", key,
+        explicit={"block_q": block_q,
+                  "block_k": block_k if block_k_bwd is None
+                  else block_k_bwd},
+        defaults=default_blocks("bwd", sq, skv, d, dtype.itemsize))
+    return fwd, bwd
+
+
 def flash_attention(q, k, v, *, causal: bool = False,
                     sm_scale: Optional[float] = None,
                     block_q: Optional[int] = None,
@@ -532,37 +836,33 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     interpret: Optional[bool] = None):
     """Fused blockwise attention.  q, k, v: (batch, heads, seq, head_dim).
 
-    ``block_*=None`` consults the autotune cache for this device/shape
-    bucket and falls back to the hand-picked 128 defaults
-    (docs/performance.md §Kernel autotuning); explicit kwargs always win.
-    ``block_k_bwd`` tiles the backward k/v scan independently of the
-    forward."""
+    The operands are cast to the policy's compute dtype (bfloat16 on a
+    TPU, float32 elsewhere) as ``dot_product_attention`` casts its own;
+    accumulation and the softmax statistics are float32 and the result
+    comes back in ``q``'s dtype.
+
+    ``block_*=None``: :func:`resolve_blocks` — a cached autotune winner if
+    there is one, else the block rule's pick for this shape
+    (docs/performance.md §Kernel autotuning); explicit kwargs always
+    win."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     from bigdl_tpu.ops import autotune
 
-    key = autotune.attention_key(q.shape, k.shape[2], q.dtype)
+    dtype = q.dtype
+    q, k, v = cast_compute(q, k, v)
+    skv = k.shape[2]
     # online mode tunes on a cache miss, but only on EAGER calls —
     # inside a jit trace the args are tracers and we must not run timing
     # trials mid-trace
     shape = (tuple(q.shape) + (q.dtype.name,)
              if autotune.is_concrete(q, k, v) else None)
-    fwd = autotune.resolve("flash_attention_fwd", key,
-                           explicit={"block_q": block_q,
-                                     "block_k": block_k},
-                           online_shape=shape)
-    if block_k_bwd is None:
-        if block_k is not None:
-            # an explicit forward block_k also pins the backward (the
-            # legacy single-knob contract) — no bwd lookup, no online
-            # tuning run whose winner would be discarded
-            block_k_bwd = block_k
-        else:
-            # cache/defaults only — no online_shape: a forward-only eager
-            # call must not pay a jax.grad tuning sweep for a backward it
-            # may never run (the offline CLI tunes flash_attention_bwd)
-            block_k_bwd = autotune.resolve("flash_attention_bwd",
-                                           key)["block_k"]
-    return _flash(q, k, v, float(sm_scale), bool(causal),
-                  int(fwd["block_q"]), int(fwd["block_k"]),
-                  int(block_k_bwd), interpret)
+    fwd, bwd = resolve_blocks(q.shape, skv, q.dtype, block_q=block_q,
+                              block_k=block_k, block_k_bwd=block_k_bwd,
+                              online_shape=shape)
+    _book_trace("fwd", q.shape, skv, q.dtype, bool(causal),
+                int(fwd["block_q"]), int(fwd["block_k"]))
+    out = _flash(q, k, v, float(sm_scale), bool(causal),
+                 int(fwd["block_q"]), int(fwd["block_k"]),
+                 int(bwd["block_q"]), int(bwd["block_k"]), interpret)
+    return out.astype(dtype)

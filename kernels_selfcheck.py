@@ -42,7 +42,8 @@ from bigdl_tpu.runtime.engine import enable_compile_cache
 # paid on the FIRST run per (kernel, tiles)
 _COMPILE_CACHE_DIR = enable_compile_cache()
 
-# baseline rows must measure the HAND-PICKED defaults: pin them
+# baseline rows must measure the UNTUNED defaults (fixed tiles, or for
+# flash attention the block rule's pick at the row's shape): pin them
 # explicitly so the kernels' call-time autotune-cache resolution — which
 # tuned_timings itself populates — can never leak tuned tiles into the
 # "default tiles" baseline (kernel_ms vs kernel_ms_tuned stays a real
@@ -240,8 +241,10 @@ def main(out_path, interpret_mode=False):
                 lambda i, qq: naive_attn(qq, k, v).astype(q.dtype), q)),
         )
 
-    record_flash_fwd("flash_attention_fwd", **DFLT["flash_attention_fwd"])
     _flash_shape = (B, H, S, D, "bfloat16")
+    for _kern in ("flash_attention_fwd", "flash_attention_bwd"):
+        DFLT[_kern] = _autotune.REGISTRY[_kern].defaults_for(_flash_shape)
+    record_flash_fwd("flash_attention_fwd", **DFLT["flash_attention_fwd"])
     tuned_timings(
         "flash_attention_fwd", "flash_attention_fwd", _flash_shape,
         lambda tiles: jax.jit(lambda: flash_attention(
@@ -250,10 +253,14 @@ def main(out_path, interpret_mode=False):
 
     def flash_loss(args):
         qq, kk, vv = args
+        # explicit forward blocks pin the backward pair's too: the rule
+        # picks one pair per direction, so pin the backward's own
         return flash_attention(
             qq, kk, vv, causal=True, interpret=interpret,
+            block_q=DFLT["flash_attention_bwd"]["block_q"],
+            block_k=DFLT["flash_attention_fwd"]["block_k"],
             block_k_bwd=DFLT["flash_attention_bwd"]["block_k"],
-            **DFLT["flash_attention_fwd"]).astype(jnp.float32).sum()
+            ).astype(jnp.float32).sum()
 
     def naive_loss(args):
         qq, kk, vv = args
@@ -278,6 +285,7 @@ def main(out_path, interpret_mode=False):
             qq, kk, vv = args
             return flash_attention(
                 qq, kk, vv, causal=True, interpret=interpret,
+                block_q=tiles["block_q"],
                 block_k_bwd=tiles["block_k"]).astype(jnp.float32).sum()
 
         return jax.jit(lambda: jax.grad(loss)((q, k, v)))

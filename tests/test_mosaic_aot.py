@@ -77,17 +77,30 @@ def test_paged_verify_attention(v5e, quantized):
              v5e((S, NB), jnp.int32), v5e((S,), jnp.int32), *scales)
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_attention_forward_and_backward(v5e, dtype):
+@pytest.mark.parametrize("shape,dtype", [
+    ((_K["flash_b"], H, _K["flash_s"], D), jnp.float32),   # the smoke's
+    ((_K["flash_b"], H, _K["flash_s"], D), jnp.bfloat16),
+    ((8, 12, 1024, 64), jnp.bfloat16),     # gpt2-small, batch 8 a chip
+    ((8, 12, 1024, 64), jnp.float32),
+    ((2, 20, 4096, 256), jnp.bfloat16),    # glm-4.7-flash.train-packed4k
+    ((2, 20, 4096, 256), jnp.float32),
+    ((2, 4, 200, 64), jnp.bfloat16),       # padded: 200 rows, one block
+    ((1, 4, 1500, 128), jnp.bfloat16),     # padded to 1536, blocks of 512
+])
+def test_flash_attention_forward_and_backward(v5e, shape, dtype):
+    """The three training kernels (forward, dq, dk/dv) with the block
+    rule's picks, operands in ``dtype`` as the policy would hand them: a
+    lowering or VMEM refusal shows here, before chip time is spent."""
     from bigdl_tpu.ops.flash_attention import flash_attention
+    from bigdl_tpu.tensor.policy import compute_dtype
 
-    q = v5e((_K["flash_b"], H, _K["flash_s"], D), dtype)
+    q = v5e(shape, jnp.float32)  # activations are float32; the call casts
 
     def loss(q, k, v):
-        return flash_attention(q, k, v, causal=True,
-                               interpret=False).astype(jnp.float32).sum()
+        return flash_attention(q, k, v, causal=True, interpret=False).sum()
 
-    _compile(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+    with compute_dtype(dtype):
+        _compile(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
 
 
 @pytest.mark.parametrize("block,ok", [((128, 128), True), ((64, 64), False),
@@ -127,18 +140,40 @@ def test_fused_layernorm_and_int8_matmul(v5e):
              v5e((m, k), jnp.int8), v5e((k, n), jnp.int8))
 
 
-def test_latent_attention_head_size_256(v5e):
-    """GLM-4.7-Flash's expanded attention: 20 heads of 256 over 4,096
-    positions, two sequences: a head size the kernel never ran before
-    PR 28."""
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_attention_cross_lengths(v5e, causal):
+    """sq != skv, neither a multiple of its block: the clamped index maps
+    and the key-padding mask lower too."""
     from bigdl_tpu.ops.flash_attention import flash_attention
+    from bigdl_tpu.tensor.policy import compute_dtype
 
-    q = v5e((2, 20, 4096, 256), jnp.float32)
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=causal, block_q=256,
+                               block_k=512, interpret=False).sum()
+
+    with compute_dtype(jnp.bfloat16):
+        _compile(jax.grad(loss, argnums=(0, 1, 2)),
+                 v5e((2, 4, 700, 128), jnp.float32),
+                 v5e((2, 4, 1300, 128), jnp.float32),
+                 v5e((2, 4, 1300, 128), jnp.float32))
+
+
+@pytest.mark.parametrize("sq,skv", [(8, 8), (24, 24), (24, 640), (5, 37)])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_flash_attention_short_lengths(v5e, sq, skv, dtype):
+    """Serving's prompt chunks and buckets: blocks clipped to the 8-padded
+    sequence (one 24-row block of bf16 tiles) still lower, forward and
+    backward."""
+    from bigdl_tpu.ops.flash_attention import flash_attention
+    from bigdl_tpu.tensor.policy import compute_dtype
 
     def loss(q, k, v):
         return flash_attention(q, k, v, causal=True, interpret=False).sum()
 
-    _compile(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+    kv = v5e((2, 4, skv, 64), jnp.float32)
+    with compute_dtype(dtype):
+        _compile(jax.grad(loss, argnums=(0, 1, 2)),
+                 v5e((2, 4, sq, 64), jnp.float32), kv, kv)
 
 
 def test_grouped_expert_product_at_the_cell_sizes(v5e):

@@ -74,6 +74,241 @@ class TestFlashAttention:
         assert out.shape == q.shape
 
 
+def _attention_grads(att, q, k, v):
+    return jax.grad(lambda q, k, v: jnp.sum(att(q, k, v) ** 2),
+                    argnums=(0, 1, 2))(q, k, v)
+
+
+def _rel_err(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.abs(a - b).max() / np.abs(b).max())
+
+
+# (causal, sq, skv, head size, block_q, block_k)
+_BWD_CASES = [
+    (False, 32, 32, 16, 8, 8),      # square, every tile visited
+    (True, 32, 32, 16, 8, 8),       # square, tiles above the diagonal skipped
+    (True, 32, 32, 16, 16, 8),      # block_q != block_k: the clamp's floor
+    (True, 32, 32, 16, 8, 16),
+    (False, 24, 40, 8, 8, 16),      # cross-attention lengths
+    (True, 24, 40, 8, 8, 16),       # sq < skv: trailing key tiles unvisited
+    (True, 40, 24, 8, 16, 8),       # sq > skv
+    (False, 37, 53, 8, 16, 16),     # neither length a multiple of its block
+    (True, 37, 37, 8, 16, 16),
+    (True, 21, 21, 8, 128, 128),    # blocks longer than the sequence
+    (True, 16, 16, 64, 8, 8),       # gpt2-small's head size
+    (True, 16, 16, 256, 8, 8),      # GLM-4.7-Flash's head size
+]
+
+
+class TestFlashBackwardKernels:
+    """The Pallas backward pair (dq; dk/dv) against ``jax.grad`` of
+    ``dot_product_attention``: both policies, padding, cross lengths."""
+
+    @staticmethod
+    def _inputs(sq, skv, d, seed=5):
+        rng = np.random.default_rng(seed)
+        return (_rand(rng, 1, 2, sq, d), _rand(rng, 1, 2, skv, d),
+                _rand(rng, 1, 2, skv, d))
+
+    @staticmethod
+    def _reference(causal, sq, skv):
+        # the kernel's causal mask is top-left aligned: key j <= query i
+        mask = jnp.tril(jnp.ones((sq, skv), bool)) if causal else None
+        return lambda q, k, v: dot_product_attention(q, k, v, mask=mask)
+
+    @pytest.mark.parametrize("policy", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("causal,sq,skv,d,bq,bk", _BWD_CASES)
+    def test_gradients_match_reference(self, causal, sq, skv, d, bq, bk,
+                                       policy):
+        """float32 policy: 1e-4 of the largest reference gradient (the
+        old scan's class; measured 8e-7 at most).  bfloat16 policy: 3e-2
+        against the float32 reference, measured 0.53e-2 to 1.4e-2 over
+        these cases where XLA's own attention under the same policy reads
+        0.51e-2 to 1.1e-2: the operands' rounding sets it, not the kernel.
+        The result is float32 as its input."""
+        from bigdl_tpu.tensor.policy import compute_dtype
+
+        q, k, v = self._inputs(sq, skv, d)
+        want = _attention_grads(self._reference(causal, sq, skv), q, k, v)
+        with compute_dtype(policy):
+            got = _attention_grads(
+                lambda q, k, v: flash_attention(
+                    q, k, v, causal=causal, block_q=bq, block_k=bk,
+                    interpret=True), q, k, v)
+        tol = 1e-4 if policy == "float32" else 3e-2
+        for name, a, b in zip("qkv", got, want):
+            assert a.dtype == jnp.float32 and a.shape == b.shape
+            assert np.isfinite(np.asarray(a)).all()
+            assert _rel_err(a, b) <= tol, (name, _rel_err(a, b))
+
+    def test_padding_rows_and_columns_get_zero_gradient(self):
+        """The pair's own outputs BEFORE the slice: rows past sq of dq and
+        rows past skv of dk, dv are exactly zero (padded queries carry
+        g = 0 and delta = 0; padded keys are masked out of p)."""
+        import importlib
+        fa = importlib.import_module("bigdl_tpu.ops.flash_attention")
+
+        sq, skv, d = 21, 27, 8
+        q, k, v = self._inputs(sq, skv, d)
+        g = _rand(np.random.default_rng(6), 1, 2, sq, d)
+        out, lse = fa._flash_fwd(q, k, v, 0.3, True, 16, 16, True)
+        seen = {}
+        real_call = fa.pl.pallas_call
+
+        def spy(kernel, **kw):
+            call = real_call(kernel, **kw)
+
+            def run(*args):
+                res = call(*args)
+                seen[kernel.func.__name__] = res
+                return res
+            return run
+
+        fa.pl.pallas_call = spy
+        try:
+            fa._flash_bwd(q, k, v, out, lse, g, 0.3, True, 16, 16, True)
+        finally:
+            fa.pl.pallas_call = real_call
+        dq = np.asarray(seen["_dq_kernel"])
+        dk, dv = (np.asarray(x) for x in seen["_dkv_kernel"])
+        assert dq.shape[1] == 32 and dk.shape[1] == 32
+        assert np.abs(dq[:, :sq]).max() > 0 and np.abs(dk[:, :skv]).max() > 0
+        assert (dq[:, sq:] == 0).all()
+        assert (dk[:, skv:] == 0).all() and (dv[:, skv:] == 0).all()
+
+    @pytest.mark.parametrize("direction", ["fwd", "bwd"])
+    @pytest.mark.parametrize("sq,skv,d,itemsize", [
+        (4096, 4096, 256, 2),    # glm-4.7-flash.train-packed4k
+        (4096, 4096, 256, 4),
+        (1024, 1024, 64, 2),     # gpt2-small, (8, 12, 1024, 64)
+        (1024, 1024, 64, 4),
+        (8, 8, 64, 4), (16, 16, 64, 4), (32, 32, 64, 2), (64, 64, 64, 2),
+        (24, 640, 64, 2),        # the decode engine's buckets and chunks
+        (300, 5000, 128, 2),
+        (8192, 8192, 512, 4),    # a head size that leaves little room
+    ])
+    def test_block_rule_returns_legal_blocks(self, direction, sq, skv, d,
+                                             itemsize):
+        import importlib
+        fa = importlib.import_module("bigdl_tpu.ops.flash_attention")
+        from bigdl_tpu.ops.common import round_up
+
+        got = fa.default_blocks(direction, sq, skv, d, itemsize)
+        bq, bk = got["block_q"], got["block_k"]
+        # multiples of 128 (Mosaic's lane tiling), at most 1024, never
+        # longer than the 128-padded length, inside the VMEM budget
+        assert bq % 128 == 0 and bk % 128 == 0
+        assert 128 <= bq <= min(1024, round_up(sq, 128))
+        assert 128 <= bk <= min(1024, round_up(skv, 128))
+        assert (fa.block_vmem_bytes(direction, bq, bk, d, itemsize)
+                <= fa._VMEM_BLOCK_BUDGET < fa._VMEM_LIMIT_BYTES)
+        # as the kernels use them: a multiple of 8, no longer than the
+        # 8-padded sequence, tiling the padded length exactly
+        cq, ck, sq_p, skv_p = fa._clip_blocks(bq, bk, sq, skv)
+        assert cq % 8 == 0 and ck % 8 == 0
+        assert cq <= round_up(sq, 8) and ck <= round_up(skv, 8)
+        assert sq_p % cq == 0 and skv_p % ck == 0
+        assert sq <= sq_p < sq + cq and skv <= skv_p < skv + ck
+        # the registry's default IS the rule
+        from bigdl_tpu.ops import autotune
+        if sq == skv:
+            dtype = "bfloat16" if itemsize == 2 else "float32"
+            spec = autotune.REGISTRY[f"flash_attention_{direction}"]
+            assert spec.defaults_for((1, 1, sq, d, dtype)) == got
+            for axis, v in got.items():
+                assert v in spec.space[axis].grid()
+
+    def test_block_rule_follows_the_shape(self):
+        """Bigger tiles where they fit, smaller where the head size or the
+        itemsize fills the budget; explicit kwargs and a cached winner
+        still win, in that order."""
+        import importlib
+        fa = importlib.import_module("bigdl_tpu.ops.flash_attention")
+
+        area = lambda b: b["block_q"] * b["block_k"]
+        small = fa.default_blocks("bwd", 8192, 8192, 64, 2)
+        big = fa.default_blocks("bwd", 8192, 8192, 512, 4)
+        assert area(big) < area(small)
+        assert area(fa.default_blocks("bwd", 8192, 8192, 256, 2)) <= area(
+            fa.default_blocks("fwd", 8192, 8192, 256, 2))
+
+    @pytest.mark.parametrize("causal,blocks,share", [
+        (True, (8, 8), 36 / 64), (False, (8, 8), 1.0),
+        (True, (16, 8), 20 / 32), (True, (64, 64), 1.0)])
+    def test_trace_counters_read_what_the_call_did(self, causal, blocks,
+                                                   share):
+        from bigdl_tpu.optim.metrics import global_metrics, label_key
+
+        m = global_metrics()
+        q, k, v = self._inputs(64, 64, 8)
+
+        def count(direction):
+            return m.counter(label_key(
+                "kernel.flash.traces", direction=direction, impl="pallas",
+                dtype="float32"))
+
+        def gauge(direction):
+            return m.gauges[label_key("kernel.flash.tile_share",
+                                      direction=direction)]
+
+        before = count("fwd"), count("bwd")
+        att = lambda q, k, v: flash_attention(
+            q, k, v, causal=causal, block_q=blocks[0], block_k=blocks[1],
+            interpret=True)
+        att(q, k, v)
+        assert (count("fwd"), count("bwd")) == (before[0] + 1, before[1])
+        assert gauge("fwd") == pytest.approx(share)
+        _attention_grads(att, q, k, v)
+        assert (count("fwd"), count("bwd")) == (before[0] + 2,
+                                                before[1] + 1)
+        assert gauge("bwd") == pytest.approx(share)
+
+    def test_autotune_winner_and_explicit_blocks_beat_the_rule(
+            self, tmp_path, monkeypatch):
+        import importlib
+        fa = importlib.import_module("bigdl_tpu.ops.flash_attention")
+        from bigdl_tpu.ops import autotune
+
+        monkeypatch.setenv("BIGDL_TPU_AUTOTUNE_CACHE", str(tmp_path))
+        monkeypatch.delenv("BIGDL_TPU_AUTOTUNE", raising=False)
+        autotune.reset_cache()
+        q, k, v = self._inputs(32, 32, 8)
+        seen = []
+        real = fa._flash
+
+        def spy(q, k, v, scale, causal, bq, bk, bq_b, bk_b, interpret):
+            seen.append((bq, bk, bq_b, bk_b))
+            return real(q, k, v, scale, causal, bq, bk, bq_b, bk_b,
+                        interpret)
+
+        monkeypatch.setattr(fa, "_flash", spy)
+        key = autotune.attention_key(q.shape, 32, q.dtype)
+        try:
+            fa.flash_attention(q, k, v, interpret=True)
+            rule_f = fa.default_blocks("fwd", 32, 32, 8, 4)
+            rule_b = fa.default_blocks("bwd", 32, 32, 8, 4)
+            assert seen[-1] == (rule_f["block_q"], rule_f["block_k"],
+                                rule_b["block_q"], rule_b["block_k"])
+            for kern, tiles in (("flash_attention_fwd", (16, 8)),
+                                ("flash_attention_bwd", (8, 16))):
+                autotune.get_cache().put(autotune.full_key(kern, key), {
+                    "tiles": {"block_q": tiles[0], "block_k": tiles[1]},
+                    "best_ms": 1.0, "default_ms": 2.0, "trials": 1,
+                    "winner": "searched"})
+            fa.flash_attention(q, k, v, interpret=True)
+            assert seen[-1] == (16, 8, 8, 16)        # cached winners
+            fa.flash_attention(q, k, v, block_q=32, interpret=True)
+            assert seen[-1] == (32, 8, 32, 16)       # block_q pins both
+            fa.flash_attention(q, k, v, block_k=32, interpret=True)
+            assert seen[-1] == (16, 32, 8, 32)       # block_k pins both
+            fa.flash_attention(q, k, v, block_k=32, block_k_bwd=8,
+                               interpret=True)
+            assert seen[-1] == (16, 32, 8, 8)
+        finally:
+            autotune.reset_cache()
+
+
 class TestInt8Matmul:
     def test_exact_int_arithmetic(self):
         rng = np.random.default_rng(0)
