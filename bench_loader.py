@@ -16,12 +16,10 @@ buffer-ring slots).  ``pipeline_img_per_sec`` vs ``serial_e2e_img_per_sec``
 is the PR's headline; per-stage ``data.*`` counters/gauges land in the
 process-wide registry exactly as a ``/metrics`` scrape would see them.
 
-``loader_img_per_sec`` must exceed the device-resident throughput claim in
-``BENCH_r*.json`` for the headline number to be sustainable host-fed; the
-bench.py TPU worker embeds a short version of this measurement next to its
-throughput fields.  ``--smoke`` runs a seconds-scale geometry and fails
-loudly on any pipeline error — the CI guard against silent loader
-regressions.
+``loader_img_per_sec`` must exceed what the chip consumes (PERF.md §5,
+``resnet50.train-hostfed``) for that rate to be sustainable host-fed.
+``--smoke`` runs a seconds-scale geometry and fails loudly on any pipeline
+error — the CI guard against silent loader regressions.
 """
 
 import json
@@ -279,9 +277,8 @@ def smoke() -> int:
     empty runs, a pipeline that lost to the serial stages, or a dispatch
     double buffer that never overlapped a transfer.  The geometry is
     sized so decode work dominates stage-threading overhead (the old
-    64x64 smoke was too small to gate the ratio on); the per-round
-    full-geometry run (``BENCH_loader_r*.json``) still tracks absolute
-    img/s via the sentinel.  Returns a process exit code."""
+    64x64 smoke was too small to gate the ratio on).  Returns a process
+    exit code."""
     geo = dict(batch=384, n_records=768, epochs=1, src_hw=256, out_hw=224)
     r = measure_pipeline(**geo)
     if r.get("pipeline_img_per_sec", 0) < r.get("serial_e2e_img_per_sec",

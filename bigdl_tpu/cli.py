@@ -17,7 +17,7 @@ identical processes (one per TPU-VM host) that rendezvous through
   multihost job (run once per host, e.g. from ``gcloud compute tpus ssh
   --worker=all``)
 
-plus ``bigdl-tpu bench | dryrun | doctor`` for the repo harnesses.
+plus ``bigdl-tpu dryrun | doctor`` for the repo harnesses.
 """
 
 import argparse
@@ -115,7 +115,6 @@ def main(argv=None) -> int:
     sub.add_parser("doctor", help="environment diagnostic: devices, mesh, "
                    "native lib, rendezvous env; non-zero when the backend "
                    "or the mesh cannot be brought up")
-    sub.add_parser("bench", help="run the repo benchmark (bench.py)")
     sub.add_parser("dryrun", help="8-virtual-device multichip dry run")
 
     serve = sub.add_parser(
@@ -139,14 +138,10 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     if args.cmd == "run":
         return _run(args)
-    repo = os.getcwd()
-    if args.cmd == "bench":
-        return subprocess.call([sys.executable,
-                                os.path.join(repo, "bench.py")])
     if args.cmd == "dryrun":
         return subprocess.call([
             sys.executable, "-c",
-            "import __graft_entry__ as g; g.dryrun_multichip(8)"], cwd=repo)
+            "import __graft_entry__ as g; g.dryrun_multichip(8)"])
     if args.cmd == "doctor":
         return _doctor()
     if args.cmd == "serve":

@@ -263,7 +263,7 @@ def autotune_workers(decode_rate: float = 0.0, target_rate: float = 0.0,
     Replaces the fixed ``min(4|8, cores)`` caps from the 2-core bench era;
     a TPU-VM host has O(100) cores and one chip demands 1500+ img/s.  The
     reserve only bites once the host has cores to spare: a 2-core host
-    still gets 2 workers (the geometry BENCH_loader_r06 won on), never
+    still gets 2 workers, never
     ``cores - reserve = 0``."""
     cores = host_cores if host_cores is not None else host_core_count()
     ceiling = max(1, min(cores, max(2, cores - max(0, reserve))))
@@ -554,7 +554,7 @@ class StreamingPipeline:
         ``*_capacity_batches_per_s`` is count / stage-busy-seconds — what
         the stage COULD do if never blocked (the autotuning signal).  The
         old keys divided counts by busy time alone, which reported
-        102595 batches/s for a 4-batch read window (BENCH_loader_r06) —
+        102595 batches/s for a 4-batch read window —
         a rate over a near-zero interval, not a throughput.  Counts and
         busy seconds ride along so the window is auditable."""
         wall = max(time.perf_counter() - self._t0, 1e-9)
@@ -779,7 +779,7 @@ def dispatch_to_device(batches: Iterable, put: Callable[[Any], Any],
     ``<name>.dispatch.in_flight`` gauge (window depth) and the
     ``<name>.dispatch_overlapped_total`` counter (transfers issued while
     a previous one was still in the window; 0 means the double buffer
-    never engaged — the regression the bench smoke gates on) — and the
+    never engaged — what ``bench_loader.py --smoke`` gates on) — and the
     two halves of every pull, both spent in the PULLING thread:
     ``<name>.batch_wait_s`` (blocked on the upstream iterator) and
     ``<name>.put_s`` (inside ``put``)."""

@@ -21,8 +21,7 @@ the registered ``two_tower_layout`` table — the id-embedding tables are
 vocab-sharded over fsdp x tp, so per-chip table bytes shrink by the
 model-shard factor.  The sparse lookup collectives this implies are
 priced by :func:`~bigdl_tpu.parallel.layout.embedding_lookup_bytes`
-(surfaced through :meth:`RecommendationPipeline.lookup_collective_bytes`
-and the RECSYS bench artifact).
+(surfaced through :meth:`RecommendationPipeline.lookup_collective_bytes`).
 
 Compile discipline: both stages run on CLOSED bucket sets
 (``batch_buckets`` here; candidate count is a static shape), and

@@ -148,16 +148,9 @@ class MultiHeadAttention(Module):
             return self._merge_project(params, x, out)
         flash_ok = mask is None and not dropout_active
         if self.use_flash is None:
-            import os as _os
-
             from bigdl_tpu.ops.common import on_tpu
 
-            # global kill-switch for the auto path: BIGDL_TPU_FLASH=0
-            # routes every auto-selecting layer through XLA attention —
-            # the A/B lever bench_lm uses, and the honest-demotion knob
-            # if the amortized showdown ever finds the kernel slower
-            use_flash = (flash_ok and on_tpu()
-                         and _os.environ.get("BIGDL_TPU_FLASH") != "0")
+            use_flash = flash_ok and on_tpu()
         else:
             use_flash = self.use_flash and flash_ok
 

@@ -17,13 +17,8 @@ The layer every other subsystem reports through:
   sentinel, and cross-host straggler stats
 - :mod:`.cost`   — analytic FLOPs/bytes cost model + device peak table
   (the live ``train.mfu`` gauge)
-- :mod:`.sentinel` — read-only perf-regression gate over the committed
-  bench trajectory (``python -m bigdl_tpu.obs.sentinel``)
 """
 
-# NOTE: obs.sentinel is deliberately NOT imported here — it is the
-# `python -m bigdl_tpu.obs.sentinel` CLI, and an eager package import
-# would trip runpy's double-import warning on every invocation
 from bigdl_tpu.obs import attr, cost, flight, slo, trace
 from bigdl_tpu.obs.attr import (RecompileSentinel, StepAttribution,
                                 expected_compile, recompile_sentinel)

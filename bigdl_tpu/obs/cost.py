@@ -6,15 +6,15 @@ TensorBoard summaries.  Here the cost of a model is derived ONCE per run
 from the model itself — a shape-capturing walk over the ``nn/`` module tree
 under ``jax.eval_shape`` (no compute, no compile) with per-layer FLOP
 formulas — so a *running* job can export a live ``train.mfu`` gauge instead
-of waiting for an offline ``bench.py`` one-shot.
+of waiting for an offline measurement.
 
-Conventions (must stay aligned with ``bench.py`` so live and bench MFU
-agree):
+Conventions (those of ``benchmark/flops.py``, so the live gauge and the
+benchmark's ``train.mfu`` agree; ``tests/test_perf_attr.py`` holds the
+two ResNet-50 counts within 5%):
 
 - forward FLOPs are *model* flops (2 x MACs for matmul-family layers;
-  elementwise layers count one pass over their output) — the
-  ``analytic_3x_fwd`` convention, generalized from bench.py's hardcoded
-  ResNet-50 constant to per-layer counts over arbitrary module trees.
+  elementwise layers count one pass over their output), as per-layer
+  counts over arbitrary module trees.
 - training FLOPs = ``TRAIN_FLOPS_MULTIPLIER`` (3) x forward (fwd +
   input-grad + weight-grad).
 - MFU = achieved FLOP/s per chip / the chip's bf16 peak
@@ -29,15 +29,13 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 # fwd + input-grad + weight-grad — the standard training-FLOPs convention
-# (bench.py's analytic_3x_fwd)
 TRAIN_FLOPS_MULTIPLIER = 3.0
 
 # bf16 matmul peak FLOP/s per chip, keyed by the EXACT jax
 # ``Device.device_kind`` (the spellings jax's own
 # ``_src/pallas/mosaic/tpu_info.py`` matches on).  Values: Google Cloud TPU
 # documentation, system-architecture page of each generation ("TPU v5e":
-# 197 TFLOP/s bf16 per chip).  THE process-wide source of truth: bench.py /
-# bench_lm.py delegate here.
+# 197 TFLOP/s bf16 per chip).
 PEAK_BF16_FLOPS: Dict[str, float] = {
     "TPU v4": 275e12,
     "TPU v5 lite": 197e12,   # v5e as libtpu reports it
@@ -338,49 +336,11 @@ def mfu(flops_per_step: float, step_time_s: float, n_devices: int,
     return achieved / peak
 
 
-def collective_bytes_for_specs(params, specs, mesh,
-                               dtype_bytes: int = 4) -> Dict[str, Any]:
-    """Per-step, per-AXIS collective bytes of a declarative layout — the
-    obs-side reader of ``parallel.layout`` PartitionSpec trees (docs/
-    parallelism.md §Declarative layouts).  Pure layout math, usable
-    before anything compiles: ``data`` carries the gradient allreduce,
-    ``fsdp`` the 2004.13336 param-gather/grad-scatter cycle, ``tp``
-    moves activations (priced separately via
-    ``parallel.layout.tp_activation_bytes``).  Also reports
-    ``param_bytes_per_chip`` — the "fits on one chip?" number fsdp x tp
-    layouts exist to shrink.  ``bench_scaling --layout`` and the
-    MULTICHIP_LAYOUT sentinel family consume exactly this dict.
-
-    NOTE: distinct from the LEGACY ``parallel.gspmd.
-    collective_bytes_for_specs`` (a flat
-    ``dp_allreduce_bytes_per_step``-keyed dict) — this one returns the
-    per-axis ``{"per_axis_bytes_per_step": ..., "param_bytes_per_chip":
-    ...}`` shape of ``parallel.layout.collective_bytes_by_axis``."""
-    from bigdl_tpu.parallel.layout import collective_bytes_by_axis
-
-    return collective_bytes_by_axis(params, specs, mesh,
-                                    dtype_bytes=dtype_bytes)
-
-
-def embedding_lookup_bytes(batch: int, dim: int, sizes,
-                           n_tables: int = 1,
-                           dtype_bytes: int = 4) -> Dict[str, Any]:
-    """Per-axis collective bytes of sparse embedding lookups against a
-    vocab-sharded (fsdp x tp) table — the obs-side reader of the
-    serving-side lookup accounting (docs/recsys.md §Lookup-collective
-    ledger).  The RECSYS sentinel family consumes exactly this dict."""
-    from bigdl_tpu.parallel.layout import embedding_lookup_bytes as _impl
-
-    return _impl(batch, dim, sizes, n_tables=n_tables,
-                 dtype_bytes=dtype_bytes)
-
-
 def collective_ledger(step_engine) -> Dict[str, Any]:
     """Per-step collective-bytes ledger of a
-    :class:`~bigdl_tpu.optim.train_step.ShardedParameterStep` — what
-    MULTICHIP_LARGE measures offline, derived from the parameter layout
-    and sync strategy (ZeRO-1 reduce-scatter + all_gather; hierarchical
-    DCN hop when the mesh is multislice).
+    :class:`~bigdl_tpu.optim.train_step.ShardedParameterStep`, derived
+    from the parameter layout and sync strategy (ZeRO-1 reduce-scatter +
+    all_gather; hierarchical DCN hop when the mesh is multislice).
 
     Bytes are counted in the ACTUAL wire dtype of the configured
     ``grad_comm`` / ``param_comm`` modes — bf16 payloads at 2 B/elem,

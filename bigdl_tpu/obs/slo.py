@@ -36,11 +36,6 @@ serving degradation surface consult (docs/serving.md §Autoscaling).
 No recent data is NO burn: an empty window reads NaN from the histogram
 (the obs.hist contract) and the objective reports burn 0 with
 ``samples=0`` — silence must not page anyone.
-
-CLI — the ``SLO_r*.json`` artifact source (burn-rate alert latency under
-an injected hard violation; gated lower-better by ``obs.sentinel``)::
-
-    python -m bigdl_tpu.obs.slo --bench
 """
 
 import json
@@ -459,111 +454,3 @@ def evaluator_from_env(metrics=None,
                   "disabled", e)
         return None
 
-
-# ---------------------------------------------------------------------------
-# the SLO_r*.json artifact source: burn-rate alert latency under load
-# ---------------------------------------------------------------------------
-
-def bench(window_s: float = 2.0, warm_s: float = 1.0,
-          threshold_s: float = 0.05, rate_hz: float = 200.0,
-          timeout_s: float = 10.0) -> Dict[str, Any]:
-    """Measure how fast the burn-rate alert fires after a hard SLO
-    violation starts — THE number that decides whether an operator pages
-    in seconds or in minutes.  Real wall clock on a compressed geometry
-    (2s windows): feed in-budget latencies for ``warm_s``, then switch
-    every request to 4x the objective bound and count evaluation TICKS
-    until ``burn >= alert`` — the reported latency is ``ticks *
-    interval``, quantized to the evaluation cadence so the committed
-    artifact is stable run-to-run (a sub-tick wall measurement would
-    gate on scheduler phase noise, not detection quality).  Gated
-    lower-better by the sentinel's SLO family; ``slo_burn_peak`` gates
-    higher-better (the detector must keep SEEING a hard violation as a
-    hard burn)."""
-    from bigdl_tpu.optim.metrics import Metrics
-
-    m = Metrics()
-    spec = SLOSpec.from_dict({
-        "tenant": "bench",
-        "objectives": {"predict_p99_s": threshold_s},
-        "window_s": window_s})
-    interval = window_s / 20.0
-    ev = SLOEvaluator([spec], metrics=m, interval_s=interval)
-    lb = {"tenant": "bench"}
-    period = 1.0 / rate_hz
-    t0 = time.time()
-    while time.time() - t0 < warm_s:
-        m.observe("serving.tenant_latency_seconds", threshold_s / 5,
-                  labels=lb)
-        ev.maybe_evaluate()
-        time.sleep(period)
-    warm_burn = max((s.burn for s in ev.statuses()), default=0.0)
-    inject_t = time.time()
-    alert_latency = None
-    burn_peak = 0.0
-    ticks = 0
-    while time.time() - inject_t < timeout_s:
-        # one full evaluation tick: violating traffic, then the verdict
-        tick_end = inject_t + (ticks + 1) * interval
-        while time.time() < tick_end:
-            m.observe("serving.tenant_latency_seconds", threshold_s * 4,
-                      labels=lb)
-            time.sleep(period)
-        ticks += 1
-        burn = max((s.burn for s in ev.evaluate()), default=0.0)
-        burn_peak = max(burn_peak, burn)
-        if alert_latency is None and burn >= ev.alert_burn:
-            alert_latency = ticks * interval
-        if alert_latency is not None \
-                and ticks * interval >= alert_latency + 5 * interval:
-            break  # peak sampled well past the crossing; done
-    row: Dict[str, Any] = {
-        "metric": "slo_alert",
-        "slo_alert_latency_s": alert_latency,
-        "slo_burn_peak": round(burn_peak, 3),
-        "warm_burn": round(warm_burn, 4),
-        "window_s": window_s,
-        "eval_interval_s": interval,
-        "threshold_s": threshold_s,
-        "alert_burn": ev.alert_burn,
-        "evals_after_injection": ticks,
-        "geometry": "inject_hard_violation_w2",
-    }
-    if alert_latency is None:
-        row["error"] = "burn rate never crossed the alert threshold"
-    elif warm_burn >= ev.alert_burn:
-        row["error"] = "alert was already firing before the injection"
-    return row
-
-
-def main(argv: Optional[List[str]] = None) -> int:
-    import argparse
-
-    ap = argparse.ArgumentParser(
-        prog="bigdl_tpu.obs.slo",
-        description="SLO burn-rate alert-latency bench (the SLO_r*.json "
-                    "artifact source; docs/observability.md §SLOs & burn "
-                    "rates)")
-    ap.add_argument("--bench", action="store_true",
-                    help="measure burn-rate alert latency under an "
-                         "injected hard violation")
-    ap.add_argument("--window", type=float, default=2.0)
-    ap.add_argument("--out", default=None,
-                    help="also write the JSON row to this path")
-    args = ap.parse_args(argv)
-    if not args.bench:
-        ap.error("nothing to do (use --bench)")
-    row = bench(window_s=args.window)
-    print(json.dumps(row))
-    if args.out:
-        with open(args.out, "w") as f:
-            json.dump(row, f, indent=1)
-    if "error" in row:
-        return 1
-    # the gate the CI step enforces: detection inside ONE window
-    return 0 if row["slo_alert_latency_s"] <= args.window else 1
-
-
-if __name__ == "__main__":
-    import sys
-
-    sys.exit(main())

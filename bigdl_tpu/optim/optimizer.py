@@ -431,8 +431,7 @@ class Optimizer:
         if os.environ.get("BIGDL_TPU_MEASURE_OVERLAP", "0") in ("1",
                                                                 "true"):
             # opt-in startup audit (two extra compiles): how much of the
-            # gradient-sync collective time hides under compute — the
-            # live counterpart of bench_scaling --grad-comm
+            # gradient-sync collective time hides under compute
             try:
                 ov = step_engine.measure_overlap(
                     step_engine.shard_batch(sample["input"]),

@@ -907,16 +907,15 @@ class ShardedParameterStep:
             exposed_collective_s = max(0, step_s - compute_s)
             overlap_efficiency   = 1 - exposed / collective_s   (in [0,1])
 
-        Builds two extra non-donating XLA programs, so this is a
-        bench/audit call (``bench_scaling --grad-comm``,
-        ``BIGDL_TPU_MEASURE_OVERLAP=1``), not a hot-path one.  Training
-        state is read, never consumed."""
+        Builds two extra non-donating XLA programs, so this is an
+        audit call (``BIGDL_TPU_MEASURE_OVERLAP=1``), not a hot-path one.
+        Training state is read, never consumed."""
         import time as _time
 
         if self.seq_parallel:
             raise NotImplementedError(
-                "overlap audit under seq_parallel: use bench_scaling on "
-                "a data-parallel mesh")
+                "overlap audit under seq_parallel: run it on a "
+                "data-parallel mesh")
         if rng is None:
             rng = jax.random.PRNGKey(0)
         ema_in = self.ema_flat if self.ema_flat is not None \

@@ -2,8 +2,9 @@
 
 The ZeRO-1 shard cycle (``optim/train_step.py``) moves the FULL flat
 gradient through ``psum_scatter`` and the updated params back through
-``all_gather`` every step: MULTICHIP_LARGE_r05 measured ~204 MB ICI +
-51 MB DCN per step for DP ResNet-50, full-precision bytes on every hop.
+``all_gather`` every step: for DP ResNet-50 on a dcn_data=2 x data=4 mesh
+the ledger counts ~204 MB ICI + 51 MB DCN per step, full-precision bytes
+on every hop.
 This module is the bandwidth layer under that cycle:
 
 - **Blockwise int8 reduce-scatter** (EQuARX recipe, PAPERS.md arXiv
@@ -295,8 +296,7 @@ def layout_ledger(n_params: int, ndev: int, dcn: int = 1,
                   param_comm: str = "fp32") -> Dict[str, float]:
     """Pure layout math: the per-step collective-bytes ledger of a ZeRO-1
     cycle over ``n_params`` parameters WITHOUT building a step engine (no
-    devices touched) — what ``bench_scaling --grad-comm`` uses to price
-    the MULTICHIP_LARGE geometry on any host.  Mirrors
+    devices touched), so a geometry can be priced on any host.  Mirrors
     ``ShardedParameterStep``'s properties exactly (same bucket table,
     same estimators).  ``param_comm`` prices the updated-param gather in
     its actual wire dtype — fp32 stays the classic ``n_pad * 4``."""

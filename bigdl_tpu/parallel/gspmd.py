@@ -321,8 +321,7 @@ class GSPMDTrainStep:
 
     def collective_bytes_by_axis(self, dtype_bytes: int = 4
                                  ) -> Dict[str, Any]:
-        """The per-axis ledger of this step's layout (``obs.cost.
-        collective_bytes_for_specs`` serves the same numbers)."""
+        """The per-axis ledger of this step's layout."""
         from bigdl_tpu.parallel.layout import collective_bytes_by_axis
 
         return collective_bytes_by_axis(self.params, self.specs, self.mesh,
@@ -338,8 +337,7 @@ def collective_bytes_for_specs(params, specs, mesh: Mesh,
     data-parallel sync moves ~2x its bytes.  Pure layout math — usable
     before anything compiles.  Data-parallel degree counts every batch
     axis present (data, dcn_data, fsdp).  The per-AXIS breakdown lives in
-    :func:`bigdl_tpu.parallel.layout.collective_bytes_by_axis` (served
-    through ``obs.cost.collective_bytes_for_specs``)."""
+    :func:`bigdl_tpu.parallel.layout.collective_bytes_by_axis`."""
     from bigdl_tpu.parallel.layout import AXIS_FSDP
 
     axes = dict(mesh.shape)

@@ -10,8 +10,7 @@ canonical spec.  ``jax.jit`` + ``NamedSharding`` then does GSPMD end to
 end — the partitioner inserts the collectives, and the same layout object
 drives training (``gspmd.GSPMDTrainStep``), serving
 (``serving.InferenceModel``/``DecodeEngine``) and the analytic per-axis
-collective-bytes ledger (:func:`collective_bytes_by_axis`, read by
-``obs.cost.collective_bytes_for_specs``).
+collective-bytes ledger (:func:`collective_bytes_by_axis`).
 
 Axis semantics (docs/parallelism.md §Declarative layouts):
 
@@ -427,9 +426,7 @@ def _spec_axes(spec) -> Tuple[str, ...]:
 
 def collective_bytes_by_axis(params, specs, mesh: Mesh,
                              dtype_bytes: int = 4) -> Dict[str, Any]:
-    """Analytic per-step, per-axis collective bytes of a GSPMD layout —
-    the ledger ``obs.cost.collective_bytes_for_specs`` serves and
-    ``bench_scaling --layout`` prices (MULTICHIP_LAYOUT artifacts).
+    """Analytic per-step, per-axis collective bytes of a GSPMD layout.
 
     Conventions (per chip, ring collectives, documented in
     docs/parallelism.md §Declarative layouts):

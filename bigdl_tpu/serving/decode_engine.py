@@ -182,8 +182,7 @@ class DecodeConfig:
     # ``max_new_tokens`` horizon before any seat frees — the cost model
     # of the legacy one-``lax.scan`` whole-sequence decode this engine
     # replaces (a fixed-length scan cannot exit early; a finished row
-    # holds its seat to the last step).  The A/B arm bench_serving
-    # --decode measures the continuous engine against.
+    # holds its seat to the last step).
     continuous: bool = True
     queue_capacity: int = 4096
     # None = auto (Pallas kernel on TPU, gathered-jnp path elsewhere).

@@ -2,10 +2,9 @@
 
 The decode engine (docs/serving.md §Autoregressive decode) is
 single-host: ``enqueue_generate`` binds each request to one worker's
-engine, so admission pressure — not step cost — is the wall under load
-(DECODE_r01: TTFT p99 3 s at 24 clients while inter-token p99 sits at
-5.5 ms).  This package scales generation across the multi-worker
-:class:`~bigdl_tpu.serving.pool.ServingPool`:
+engine, so admission pressure can become the wall under load (not
+measured on the chip; PERF.md §7).  This package scales generation across
+the multi-worker :class:`~bigdl_tpu.serving.pool.ServingPool`:
 
 - :class:`~bigdl_tpu.serving.fleet.router.FleetRouter` — KV-aware
   placement of ``/generate`` over the decode-pressure signals workers

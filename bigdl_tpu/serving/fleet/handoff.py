@@ -47,7 +47,7 @@ _REQUIRED = ("tokens", "first_token", "first_logp")
 # hard ceiling on an accepted blob: a misbehaving (or chaos-injected)
 # prefill worker must not be able to make a decode worker materialize an
 # unbounded numpy array.  256 MiB covers every geometry this repo ships
-# (the bench fleet's largest handoff is < 1 MiB) with 2+ orders of
+# (the test fleet's largest handoff is < 1 MiB) with 2+ orders of
 # margin; callers with bigger pools pass max_bytes explicitly.
 MAX_HANDOFF_BYTES = 256 * 1024 * 1024
 

@@ -1,4 +1,5 @@
-"""Compiled-kernel selfcheck — produces a KERNELS_rNN.json.
+"""Compiled-kernel selfcheck — writes one JSON report (``KERNELS_r04.json``
+is the 2026-08-01 one; the default path is under ``chiprun_out/``).
 
 Runs the flagship Pallas kernels on the TPU with Mosaic compilation, at
 realistic shapes, and for each records:
@@ -479,5 +480,7 @@ def main(out_path, interpret_mode=False):
 if __name__ == "__main__":
     args = [a for a in sys.argv[1:] if a != "--interpret"]
     out = args[0] if args else os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), "KERNELS_r05.json")
+        os.path.dirname(os.path.abspath(__file__)), "chiprun_out",
+        "KERNELS.json")
+    os.makedirs(os.path.dirname(os.path.abspath(out)), exist_ok=True)
     sys.exit(main(out, interpret_mode="--interpret" in sys.argv[1:]))

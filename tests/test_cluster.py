@@ -695,27 +695,6 @@ def test_checkpoint_mirror_is_garbage_collected(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# sentinel family (satellite): CLUSTER_r*.json gates like latencies
-
-
-def test_sentinel_gates_cluster_recovery_families(tmp_path):
-    from bigdl_tpu.obs import sentinel
-
-    (tmp_path / "BENCH_r01.json").write_text(json.dumps(
-        {"metric": "resnet_img_per_sec", "value": 100.0}))
-    (tmp_path / "CLUSTER_r01.json").write_text(json.dumps(
-        {"mttr_s": 2.0, "recovery_bytes": 1e6}))
-    history = sentinel.load_history(str(tmp_path))
-    assert "cluster_mttr_s" in history
-    assert history["cluster_mttr_s"][0].direction == sentinel.LOWER
-    # 50% slower recovery regresses; a faster one passes
-    bad = sentinel.check({"mttr_s": 3.0, "recovery_bytes": 1e6}, history)
-    assert any(v.family == "cluster_mttr_s" and v.regressed for v in bad)
-    ok = sentinel.check({"mttr_s": 1.5, "recovery_bytes": 9e5}, history)
-    assert all(not v.regressed for v in ok)
-
-
-# ---------------------------------------------------------------------------
 # true multi-process membership drill (slow: real processes, real clocks)
 
 

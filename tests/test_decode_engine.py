@@ -181,7 +181,7 @@ class TestSlotAndPageInvariants:
             eng.stop()
 
     def test_whole_batch_restart_mode_answers(self, lm):
-        """continuous=False (the bench baseline): gang admission, full
+        """continuous=False (the static baseline): gang admission, full
         scan horizon — same answers, just slower seats."""
         eng = _lm_engine(lm, continuous=False, max_new_tokens=6)
         cont = _lm_engine(lm, max_new_tokens=6)
@@ -636,50 +636,3 @@ class TestServingSurface:
                     "serving_decode_page_utilization"):
             assert fam in text, fam
         assert "# HELP serving_decode_ttft_s" in text
-
-
-# ---------------------------------------------------------------------------
-# sentinel: the DECODE_r* family
-# ---------------------------------------------------------------------------
-
-def test_sentinel_normalizes_and_gates_decode_family():
-    from bigdl_tpu.obs import sentinel
-
-    row = {"engine": "continuous", "geometry": "decode_s8_c24",
-           "tokens_per_s": 3000.0, "tokens_per_s_user": 120.0,
-           "ttft_ms_p50": 10.0, "ttft_ms_p99": 80.0,
-           "inter_token_p99_ms": 5.0, "speedup_vs_static": 2.5}
-    rows = {r.family: r for r in sentinel.normalize(row, "t")}
-    assert rows["decode_tokens_per_s_decode_s8_c24"].direction \
-        == sentinel.HIGHER
-    assert rows["decode_ttft_ms_p99_decode_s8_c24"].direction \
-        == sentinel.LOWER
-    assert rows["decode_inter_token_p99_ms_decode_s8_c24"].direction \
-        == sentinel.LOWER
-    assert rows["decode_speedup_vs_static_decode_s8_c24"].direction \
-        == sentinel.HIGHER
-    history = {f: [r] for f, r in rows.items()}
-    worse = dict(row, tokens_per_s=2000.0, ttft_ms_p99=200.0)
-    verdicts = {v.family: v for v in sentinel.check(worse, history)}
-    assert verdicts["decode_tokens_per_s_decode_s8_c24"].regressed
-    assert verdicts["decode_ttft_ms_p99_decode_s8_c24"].regressed
-    ok = dict(row)
-    assert not any(v.regressed for v in sentinel.check(ok, history))
-
-
-def test_committed_decode_artifact_enters_history():
-    """DECODE_r01.json is committed evidence: the sentinel must load it
-    into the gating trajectory (and it must show the >= 2x speedup the
-    acceptance demands)."""
-    import os
-
-    from bigdl_tpu.obs import sentinel
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    if not os.path.exists(os.path.join(root, "DECODE_r01.json")):
-        pytest.skip("DECODE_r01.json not committed yet")
-    history = sentinel.load_history(root)
-    fams = [f for f in history if f.startswith("decode_tokens_per_s")]
-    assert fams, "DECODE family missing from sentinel history"
-    speed = [f for f in history if f.startswith("decode_speedup")]
-    assert speed and history[speed[0]][0].value >= 2.0

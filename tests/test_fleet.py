@@ -20,7 +20,6 @@ import numpy as np
 import pytest
 
 from bigdl_tpu.nn.attention import Transformer
-from bigdl_tpu.obs import sentinel
 from bigdl_tpu.serving.decode_engine import (DecodeConfig, DecodeEngine,
                                              DecodeRequest, LMAdapter)
 from bigdl_tpu.serving.fleet import (FleetRouter, PrefixCache,
@@ -571,45 +570,6 @@ def test_split_streaming_parity(lm):
         fe_d.stop()
         srv_p.stop()
         srv_d.stop()
-
-
-# ---------------------------------------------------------------------------
-# sentinel: the DECODE_POOL_r* family
-
-
-def test_sentinel_normalizes_decode_pool_rows():
-    row = {"engine": "decode_pool", "geometry": "decode_pool_w2_c24",
-           "workers": 2, "concurrent_clients": 24,
-           "tokens_per_s": 5000.0, "tokens_per_s_user": 40.0,
-           "ttft_ms_p50": 300.0, "ttft_ms_p99": 900.0,
-           "inter_token_p99_ms": 6.0}
-    fams = {r.family: r for r in sentinel.normalize(row, "t")}
-    assert fams["decode_tokens_per_s_decode_pool_w2_c24"].direction \
-        == sentinel.HIGHER
-    assert fams["decode_ttft_ms_p99_decode_pool_w2_c24"].direction \
-        == sentinel.LOWER
-    assert fams["decode_inter_token_p99_ms_decode_pool_w2_c24"].direction \
-        == sentinel.LOWER
-    assert "DECODE_POOL_r[0-9]*.json" in sentinel._ARTIFACT_GLOBS
-
-
-def test_sentinel_gates_committed_decode_pool_artifact():
-    """DECODE_POOL_r01.json is committed evidence: the sentinel must load
-    it into per-geometry families and flag a regression against it."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    if not os.path.exists(os.path.join(root, "DECODE_POOL_r01.json")):
-        pytest.skip("DECODE_POOL_r01.json not committed yet")
-    history = sentinel.load_history(root)
-    fams = [f for f in history if f.endswith("decode_pool_w2_c24")]
-    assert any(f.startswith("decode_tokens_per_s") for f in fams)
-    assert any(f.startswith("decode_ttft_ms_p99") for f in fams)
-    base = sentinel.baseline_for("decode_ttft_ms_p99_decode_pool_w2_c24",
-                                 history)
-    bad = {"geometry": "decode_pool_w2_c24",
-           "tokens_per_s": 1.0, "ttft_ms_p99": base.value * 2,
-           "inter_token_p99_ms": 50.0}
-    verdicts = {v.family: v for v in sentinel.check(bad, history)}
-    assert verdicts["decode_ttft_ms_p99_decode_pool_w2_c24"].regressed
 
 
 # ---------------------------------------------------------------------------

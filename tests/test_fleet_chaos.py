@@ -24,7 +24,6 @@ import numpy as np
 import pytest
 
 from bigdl_tpu.nn.attention import Transformer
-from bigdl_tpu.obs import sentinel
 from bigdl_tpu.resilience import faults
 from bigdl_tpu.serving.decode_engine import (DecodeConfig, DecodeEngine,
                                              DecodeRequest, LMAdapter)
@@ -722,24 +721,6 @@ def test_client_disconnect_frees_slot_mid_stream(lm, drain_pair):
         assert srv.decode_pressure().get("free_slots") == eng.cfg.slots
     finally:
         eng._test_sleep_s = 0.03
-
-
-# ---------------------------------------------------------------------------
-# sentinel: the DECODE_CHAOS_r* family
-
-
-def test_sentinel_normalizes_decode_chaos_rows():
-    row = {"bench": "decode_chaos", "geometry": "decode_chaos_w2_c24",
-           "workers": 2, "recovery_ms_p99": 812.5,
-           "chaos_tokens_per_s": 950.0, "failovers": 3}
-    fams = {r.family: r for r in sentinel.normalize(row, "t")}
-    assert fams["chaos_recovery_ms_p99_decode_chaos_w2_c24"].direction \
-        == sentinel.LOWER
-    assert fams["chaos_tokens_per_s_decode_chaos_w2_c24"].direction \
-        == sentinel.HIGHER
-    # the chaos row must NOT leak into the decode-bench families
-    assert not any(f.startswith("decode_tokens_per_s") for f in fams)
-    assert "DECODE_CHAOS_r[0-9]*.json" in sentinel._ARTIFACT_GLOBS
 
 
 # ---------------------------------------------------------------------------

@@ -352,7 +352,7 @@ def test_dispatch_inflight_one_is_the_serial_window(rec):
 
 def test_autotune_workers_policy():
     # no rates: the whole ceiling (cores minus reserve), floor of 2 so a
-    # 2-core host keeps the geometry BENCH_loader_r06 won on
+    # 2-core host keeps two workers
     assert autotune_workers(host_cores=24) == 22
     assert autotune_workers(host_cores=2) == 2
     assert autotune_workers(host_cores=1) == 1
@@ -368,7 +368,7 @@ def test_autotune_workers_policy():
 def test_stage_rates_measured_window(rec):
     """stage_rates reports counts, busy seconds, and rates over the
     MEASURED window — not a count divided by a near-zero busy interval
-    (the bogus 102595.69 batches/s of BENCH_loader_r06)."""
+    (which once reported 102595.69 batches/s)."""
     p, _, _ = rec
     ds = RecordDataSet(p)
     sp = ds.stream_batches(10, shuffle=False, workers=2)

@@ -8,8 +8,8 @@ the replicated-params audit gauge + flight line, the ACCEPTANCE pair —
 fsdp x tp training of the 12L transformer matches the dp loss trajectory
 from one seed, and the same checkpoint serves model-sharded through
 ``InferenceModel``/``DecodeEngine`` with zero unexpected recompiles —
-plus the Estimator/keras ``parallelism=`` surfaces, the per-axis
-collective-bytes ledger math, and the MULTICHIP_LAYOUT sentinel family.
+plus the Estimator/keras ``parallelism=`` surfaces and the per-axis
+collective-bytes ledger math.
 """
 
 import os
@@ -525,7 +525,7 @@ class TestEstimatorSurface:
 
 
 # ---------------------------------------------------------------------------
-# the per-axis ledger + sentinel family
+# the per-axis ledger
 # ---------------------------------------------------------------------------
 
 class TestLedger:
@@ -544,15 +544,23 @@ class TestLedger:
         assert led["param_bytes_per_chip"] == pytest.approx(
             (8 / 4 + 2) * 4)
 
-    def test_obs_cost_reads_the_layout(self):
-        from bigdl_tpu.obs.cost import collective_bytes_for_specs
-
-        r = mesh_and_layout("fsdp:2,tp:2")
-        params = {"w": np.zeros((4, 2), np.float32)}
-        specs = {"w": P("fsdp", "tp")}
-        a = collective_bytes_for_specs(params, specs, r.mesh)
-        b = collective_bytes_by_axis(params, specs, r.mesh)
-        assert a == b
+    def test_fsdp_tp_shrinks_per_chip_params_4x_at_published_width(self):
+        """A count from shapes: the 12-layer d=768 transformer under
+        ``"fsdp:2,tp:4"`` holds >= 4x fewer parameter bytes per chip
+        than under ``"dp"``, and no parameter replicates by default."""
+        model = Transformer(32768, hidden_size=768, num_heads=12,
+                            ffn_size=3072, num_layers=12, dropout=0.0,
+                            mode="lm")
+        shapes = _param_shapes(model, np.zeros((1, 1024), np.int32))
+        per_chip = {}
+        for spec in ("dp", "fsdp:2,tp:4"):
+            r = mesh_and_layout(spec)
+            table = r.table_for(model)
+            assert table.audit(shapes).fallback_replicated == []
+            per_chip[spec] = collective_bytes_by_axis(
+                shapes, table.param_specs(shapes),
+                r.mesh)["param_bytes_per_chip"]
+        assert per_chip["dp"] / per_chip["fsdp:2,tp:4"] >= 4.0
 
     def test_tp_activation_estimate(self):
         # 2*(tp-1)/tp * B*S*D * 4 bytes, x3 (fwd + bwd), x n collectives
@@ -567,38 +575,3 @@ class TestLedger:
         params = {"w": np.zeros((8,), np.float32)}
         rep = collective_bytes_for_specs(params, {"w": P()}, r.mesh)
         assert rep["n_data_replicas"] == 4.0
-
-    def test_sentinel_layout_family(self):
-        from bigdl_tpu.obs import sentinel as obs_sentinel
-
-        row = {"metric": "multichip_layout_param_bytes_reduction",
-               "value": 7.9,
-               "layout_modes": {
-                   "dp": {"per_axis_bytes_per_step": {"data": 100.0},
-                          "param_bytes_per_chip": 400.0},
-                   "fsdp_tp": {
-                       "per_axis_bytes_per_step": {"fsdp": 60.0},
-                       "tp_activation_bytes_per_step": 30.0,
-                       "param_bytes_per_chip": 50.0}}}
-        rows = {r.family: r for r in obs_sentinel.normalize(
-            row, "MULTICHIP_LAYOUT_r99.json")}
-        assert rows["multichip_layout_param_bytes_reduction"].direction \
-            == obs_sentinel.HIGHER
-        assert rows["multichip_layout_dp_param_bytes_per_chip"].direction \
-            == obs_sentinel.LOWER
-        assert rows["multichip_layout_fsdp_tp_fsdp_bytes_per_step"].value \
-            == 60.0
-        assert ("multichip_layout_fsdp_tp_tp_activation_bytes_per_step"
-                in rows)
-
-    def test_committed_layout_artifact_gates(self):
-        from bigdl_tpu.obs import sentinel as obs_sentinel
-
-        history = obs_sentinel.load_history(REPO)
-        fam = "multichip_layout_fsdp_tp_param_bytes_per_chip"
-        assert fam in history, (
-            "MULTICHIP_LAYOUT_r*.json must stay committed so the "
-            "sentinel gates the layout ledger")
-        assert "multichip_layout_param_bytes_reduction" in history
-        base = obs_sentinel.baseline_for(fam, history)
-        assert base is not None and base.value > 0

@@ -1,18 +1,14 @@
 """Performance-attribution layer specs (docs/observability.md §Step-time
-attribution, docs/performance.md §Regression sentinel).
+attribution).
 
-Tier-1 coverage for the tentpole: per-step wall-time decomposition summing
-back to the measured wall, the analytic cost model agreeing with bench.py's
-ResNet-50 convention within 5%, the live train.mfu / collective-bytes
-gauges on a real Optimizer run, the recompilation sentinel (counting,
-expected-compile suppression, flight events), straggler stats, and the
-perf-regression sentinel flagging a synthetic 20% throughput drop against
-the committed trajectory."""
+Tier-1 coverage: per-step wall-time decomposition summing back to the
+measured wall, the analytic cost model agreeing with the benchmark's
+ResNet-50 count within 5%, the live train.mfu / collective-bytes gauges
+on a real Optimizer run, the recompilation sentinel (counting,
+expected-compile suppression, flight events) and straggler stats."""
 
 import json
 import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -20,7 +16,6 @@ import pytest
 from bigdl_tpu.obs import attr as obs_attr
 from bigdl_tpu.obs import cost as obs_cost
 from bigdl_tpu.obs import flight
-from bigdl_tpu.obs import sentinel as obs_sentinel
 from bigdl_tpu.optim.metrics import Metrics, global_metrics
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -135,13 +130,15 @@ def test_cost_model_linear_mlp_exact():
     assert y.shape == (16, 8)
 
 
-def test_cost_model_resnet50_matches_bench_analytic_within_5pct():
-    """Acceptance: the per-layer analytic count on the bench geometry
-    (ResNet-50 @224) agrees with bench.py's hardcoded analytic_3x_fwd
-    convention (4.09 GMACs forward) within 5% — so the live train.mfu
-    gauge and bench.py's analytic MFU agree whenever step time and peak
-    agree (they share both other factors by construction)."""
+def test_cost_model_resnet50_matches_benchmark_flops_within_5pct():
+    """The program's per-layer count for ResNet-50 @224 agrees within 5%
+    with the benchmark's own (``benchmark/flops.py``: 8.18 GFLOP forward,
+    x3 a training sample), so the in-program ``train.mfu`` gauge and the
+    benchmark's ``train.mfu`` are held to one yardstick: they differ only
+    where step time or peak differ."""
     import jax
+
+    from benchmark import flops as benchmark_flops
 
     from bigdl_tpu.models.resnet import resnet50
 
@@ -153,13 +150,12 @@ def test_cost_model_resnet50_matches_bench_analytic_within_5pct():
     # the cost trace itself is jax.eval_shape — no FLOP executes at 224
     rep = obs_cost.forward_costs(
         model, variables, np.zeros((1, 224, 224, 3), np.float32))
-    bench_fwd_flops = 2 * 4.09e9  # bench.py: ~4.09 GMACs fwd per image
-    assert rep.flops == pytest.approx(bench_fwd_flops, rel=0.05)
-    # and the training convention matches bench's 3x multiplier exactly
-    import bench
-
+    fwd = benchmark_flops.resnet50_forward_flops(224, 1000)
+    assert fwd == pytest.approx(8.18e9, rel=0.01)
+    assert rep.flops == pytest.approx(fwd, rel=0.05)
+    # and the training convention is the same 3x multiplier
     assert rep.train_flops() == pytest.approx(
-        bench._RESNET50_TRAIN_FLOPS_PER_IMAGE, rel=0.05)
+        benchmark_flops.TRAIN_OVER_FORWARD * fwd, rel=0.05)
 
 
 def test_cost_model_attention_counts_projections_and_scores():
@@ -239,7 +235,7 @@ def test_optimizer_exports_attribution_and_live_mfu(monkeypatch):
     # matmuls plus the elementwise ReLU (8 out) and LogSoftMax (2 out)
     fwd1 = 2 * (4 * 8 + 8 * 2) + 2 * 8 + 2 * 2
     assert g["train.flops_per_step"] == pytest.approx(3 * fwd1 * 16)
-    # live MFU is the same arithmetic the bench does: achieved/peak
+    # live MFU is achieved/peak, the benchmark's own arithmetic
     assert 0 < g["train.mfu"] < 1
     import jax
 
@@ -316,98 +312,7 @@ def test_recompile_sentinel_counts_and_flags():
 
 
 # ---------------------------------------------------------------------------
-# perf-regression sentinel
-# ---------------------------------------------------------------------------
-
-def test_sentinel_history_covers_committed_trajectory():
-    history = obs_sentinel.load_history(REPO)
-    assert "resnet50_train_throughput" in history
-    assert "train_dispatch_overhead_reduction" in history
-    assert "loader_pipeline_img_per_sec" in history
-    assert "serving_throughput_rps" in history
-    assert "serving_p99_ms" in history
-    base = obs_sentinel.baseline_for("resnet50_train_throughput", history)
-    assert base.value > 0 and base.source.startswith("BENCH_r")
-    p99 = obs_sentinel.baseline_for("serving_p99_ms", history)
-    assert p99.direction == obs_sentinel.LOWER
-    # lower-better baseline is the BEST (smallest) committed latency
-    assert p99.value == min(r.value for r in history["serving_p99_ms"])
-
-
-def test_sentinel_flags_synthetic_20pct_throughput_drop():
-    """Acceptance: a synthetic 20% throughput regression against the
-    committed trajectory is flagged; a 5% wiggle (inside the 10%
-    threshold) passes; a lower-better latency regression is flagged in
-    the other direction."""
-    history = obs_sentinel.load_history(REPO)
-    base = obs_sentinel.baseline_for("resnet50_train_throughput", history)
-    verdicts = obs_sentinel.check(
-        {"metric": "resnet50_train_throughput", "value": base.value * 0.8},
-        history)
-    assert len(verdicts) == 1 and verdicts[0].regressed
-    assert verdicts[0].ratio == pytest.approx(0.8, abs=0.001)
-    ok = obs_sentinel.check(
-        {"metric": "resnet50_train_throughput", "value": base.value * 0.95},
-        history)
-    assert not ok[0].regressed
-    p99 = obs_sentinel.baseline_for("serving_p99_ms", history)
-    worse = obs_sentinel.check(
-        {"requests": 1, "throughput_rps": 1e9, "p50_ms": 0.01,
-         "p99_ms": p99.value * 1.25}, history)
-    by_family = {v.family: v for v in worse}
-    assert by_family["serving_p99_ms"].regressed
-    assert not by_family["serving_throughput_rps"].regressed
-
-
-def test_sentinel_ignores_bad_rows_and_unknown_families():
-    history = obs_sentinel.load_history(REPO)
-    # an errored/suspect fresh row yields no verdicts (never a false gate)
-    assert obs_sentinel.check(
-        {"metric": "resnet50_train_throughput", "value": 1.0,
-         "error": "tpu unavailable"}, history) == []
-    assert obs_sentinel.check(
-        {"metric": "resnet50_train_throughput", "value": 1.0,
-         "suspect": True}, history) == []
-    # unknown family: nothing to regress from
-    assert obs_sentinel.check(
-        {"metric": "a_brand_new_metric", "value": 1.0}, history) == []
-    # wrapped {parsed} round artifacts unwrap
-    rows = obs_sentinel.normalize(
-        {"n": 5, "rc": 0,
-         "parsed": {"metric": "resnet50_train_throughput", "value": 42.0}},
-        "wrapped")
-    assert rows and rows[0].value == 42.0
-
-
-def test_sentinel_smoke_cli_gate():
-    """The CI step: --smoke proves the gate flags a synthetic regression
-    (and passes an on-trajectory row) using only committed artifacts."""
-    proc = subprocess.run(
-        [sys.executable, "-m", "bigdl_tpu.obs.sentinel", "--smoke",
-         "--root", REPO],
-        capture_output=True, text=True, timeout=120, cwd=REPO)
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    verdict = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert verdict["smoke"] == "ok" and verdict["families"] >= 4
-
-
-def test_sentinel_cli_fails_on_regressed_fresh_file(tmp_path):
-    history = obs_sentinel.load_history(REPO)
-    base = obs_sentinel.baseline_for("resnet50_train_throughput", history)
-    fresh = tmp_path / "fresh.json"
-    fresh.write_text(json.dumps(
-        {"metric": "resnet50_train_throughput", "value": base.value * 0.5}))
-    proc = subprocess.run(
-        [sys.executable, "-m", "bigdl_tpu.obs.sentinel", str(fresh),
-         "--root", REPO],
-        capture_output=True, text=True, timeout=120, cwd=REPO)
-    assert proc.returncode == 1, proc.stdout + proc.stderr
-    out = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert out["regressed"] is True
-
-
-# ---------------------------------------------------------------------------
-# block-sparse cost model + KERNELS sentinel family (ISSUE 10 satellites)
+# block-sparse cost model
 # ---------------------------------------------------------------------------
 
 def test_cost_model_block_sparse_dense_vs_effective():
@@ -435,43 +340,6 @@ def test_cost_model_block_sparse_dense_vs_effective():
     # training effective = fwd(eff) + dx(eff) + dw(DENSE — the weight
     # grad is a dense matmul masked on the way out): 2·0.5 + 1 = 2.0
     assert detail["effective"] == pytest.approx(dense * 2.0)
-
-
-def test_sentinel_kernels_family_normalize_and_gate():
-    """KERNELS_r*.json rows gate: per-kernel speedup (higher-better),
-    parity_ok rows only, probe_ rows never."""
-    doc = {"device_kind": "TPU v5 lite", "all_ok": True, "kernels": {
-        "flash_attention_fwd": {"parity_ok": True, "speedup": 1.2,
-                                "speedup_amortized": 1.5},
-        "fused_layernorm_fwd": {"parity_ok": True, "speedup": 1.0},
-        "broken_kernel": {"parity_ok": False, "speedup": 9.9},
-        "probe_flash_bq256": {"parity_ok": True, "speedup": 3.0},
-    }}
-    rows = {r.family: r for r in obs_sentinel.normalize(doc, "t.json")}
-    assert rows["kernel_speedup_flash_attention_fwd"].value == 1.5  # amortized preferred
-    assert rows["kernel_speedup_fused_layernorm_fwd"].value == 1.0
-    assert "kernel_speedup_broken_kernel" not in rows
-    assert not any("probe" in f for f in rows)
-    assert all(r.direction == obs_sentinel.HIGHER for r in rows.values())
-
-
-def test_sentinel_kernels_family_in_committed_history_and_gates():
-    """The committed KERNELS_r04 rows are in the history, and a 20%
-    kernel-speedup regression fails like every other family (the
-    `make bench-watch` contract)."""
-    history = obs_sentinel.load_history(REPO)
-    fam = "kernel_speedup_flash_attention_fwd"
-    assert fam in history
-    base = obs_sentinel.baseline_for(fam, history)
-    assert base.source.startswith("KERNELS_r")
-    fresh = {"kernels": {"flash_attention_fwd": {
-        "parity_ok": True, "speedup": base.value * 0.8}}}
-    verdicts = obs_sentinel.check(fresh, history)
-    by_family = {v.family: v for v in verdicts}
-    assert by_family[fam].regressed
-    ok = obs_sentinel.check({"kernels": {"flash_attention_fwd": {
-        "parity_ok": True, "speedup": base.value}}}, history)
-    assert not ok[0].regressed
 
 
 def test_export_help_covers_new_gauges():

@@ -8,7 +8,7 @@ slow batches, full queues, and shutdown.  Fault injection uses the
 ``serving_worker_kill`` / ``serving_slow_batch``.
 
 In-process specs run under tier-1; the multi-worker pool chaos tests are
-``slow`` (subprocess spawns) and run via ``make test-serving``.
+``slow`` (subprocess spawns).
 """
 
 import json

@@ -9,7 +9,7 @@ degradation isolation, the queue_wait/occupancy exports, the
 zero-recompile mixed-size sweep, the pure autoscaling policy, and the
 proxy's keep-alive connection pool.  Pool integration (subprocess
 workers: autoscale up/down, conn reuse counters, two models behind one
-pool) runs as ``slow`` via ``make test-serving``.
+pool) runs as ``slow``.
 """
 
 import json

@@ -4,9 +4,8 @@ by concurrent clients over real sockets.
 Reference analog (unverified — mount empty): ``scala/serving/`` decouples
 the serving engine from clients via Flink/Redis processes; these specs
 prove the TPU-native stack holds up across a process boundary — dynamic
-batching under concurrency, bounded-queue backpressure (non-blocking
-shed + client retry, never an unbounded block), and recorded p50/p99
-latency (VERDICT r3 #9).
+batching under concurrency and bounded-queue backpressure (non-blocking
+shed + client retry, never an unbounded block).
 """
 
 import json
@@ -74,29 +73,24 @@ def test_serving_subprocess_concurrent_clients(tmp_path):
 
         rs = np.random.RandomState(0)
         n_clients, n_requests = 8, 20
-        latencies = [[] for _ in range(n_clients)]
         errors = []
 
-        def client(ci):
+        def client():
             try:
                 for _ in range(n_requests):
                     x = rs.rand(2, 8).astype(np.float32)
-                    t0 = time.perf_counter()
                     out = _post(url, {"instances": x.tolist()})
-                    latencies[ci].append(time.perf_counter() - t0)
                     preds = np.asarray(out["predictions"])
                     assert preds.shape == (2, 4), preds.shape
             except Exception as e:  # noqa: BLE001
                 errors.append(e)
 
-        threads = [threading.Thread(target=client, args=(i,))
-                   for i in range(n_clients)]
-        t0 = time.time()
+        threads = [threading.Thread(target=client)
+                   for _ in range(n_clients)]
         for t in threads:
             t.start()
         for t in threads:
             t.join(timeout=120)
-        wall = time.time() - t0
         assert not errors, errors
 
         # health endpoint reports engine stats across the process boundary
@@ -107,22 +101,6 @@ def test_serving_subprocess_concurrent_clients(tmp_path):
         assert health["requests"] == total, health
         # concurrency => dynamic batching actually coalesced requests
         assert health["batches"] < total, health
-
-        lat = np.sort(np.concatenate(latencies))
-        artifact = {
-            "requests": total,
-            "concurrent_clients": n_clients,
-            "batches": int(health["batches"]),
-            "avg_batch_size": round(total / health["batches"], 2),
-            "wall_s": round(wall, 2),
-            "throughput_rps": round(total / wall, 1),
-            "p50_ms": round(float(lat[int(0.50 * (len(lat) - 1))]) * 1e3, 2),
-            "p99_ms": round(float(lat[int(0.99 * (len(lat) - 1))]) * 1e3, 2),
-        }
-        print("SERVING_LATENCY " + json.dumps(artifact))
-        if os.environ.get("BIGDL_TPU_WRITE_ARTIFACTS"):
-            with open(os.path.join(repo_root, "SERVING_r05.json"), "w") as f:
-                json.dump(artifact, f, indent=1)
     finally:
         if proc.poll() is None:
             proc.stdin.close()

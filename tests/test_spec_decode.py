@@ -10,8 +10,8 @@ inside warmup()'s closed bucket set, the spec x ``kv_dtype="int8"``
 token-parity budget, draft-side pages freed together with target pages
 on cancel/disconnect (the page-leak regression spec), ``decode_pressure``
 honesty under draft pages, the multi-query verify kernel's parity with
-the gathered-jnp reference, and the ``serving.decode.spec_*`` metric +
-sentinel surface.
+the gathered-jnp reference, and the ``serving.decode.spec_*`` metric
+surface.
 """
 
 import time
@@ -442,23 +442,3 @@ def test_paged_verify_attention_int8_matches_dequantized():
                                rtol=1e-5, atol=1e-5)
     with pytest.raises(ValueError, match="k_scales"):
         paged_verify_attention(q, kq, vq, pt, pos, interpret=True)
-
-
-# ---------------------------------------------------------------------------
-# sentinel: the DECODE_SPEC_r* family
-# ---------------------------------------------------------------------------
-
-def test_sentinel_normalizes_decode_spec_rows():
-    from bigdl_tpu.obs import sentinel
-
-    row = {"bench": "decode_spec", "geometry": "decode_s8_c24",
-           "spec_tokens_per_s_user": 140.0, "accept_rate": 0.74,
-           "speedup_vs_off": 1.9, "token_parity": 1.0}
-    fams = {r.family: r for r in sentinel.normalize(row, "t")}
-    assert fams["decode_spec_tokens_per_s_user_decode_s8_c24"].direction \
-        == sentinel.HIGHER
-    assert fams["decode_spec_accept_rate_decode_s8_c24"].direction \
-        == sentinel.HIGHER
-    # the spec row must NOT leak into the plain decode-bench families
-    assert not any(f.startswith("decode_tokens_per_s") for f in fams)
-    assert "DECODE_SPEC_r[0-9]*.json" in sentinel._ARTIFACT_GLOBS
