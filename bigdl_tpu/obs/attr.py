@@ -109,7 +109,7 @@ class StepAttribution:
         self.steps = 0
         self.wall_s = 0.0
         self.totals: Dict[str, float] = {c: 0.0 for c in COMPONENTS}
-        self.windows = 0  # log points seen (one sync each)
+        self.windows = 0  # loss fetches seen: log points and flushes
         self._t_iter: Optional[float] = None  # open iteration's start
         self._booked = 0.0  # phase seconds booked since then
 

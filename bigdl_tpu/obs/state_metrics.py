@@ -15,16 +15,18 @@ Every leaf is a cumulative ``uint32`` that wraps around: a counter adds its
 events; a mean adds its value in 16.16 fixed point and ``n`` counts the
 additions.  The train step sums each replica's additions over the data axes
 (``optim/train_step.py``: unsigned leaves are event counters), so the
-totals are the job's.  On the host a :class:`StateMetricsBooker` rides the
-one ``device_get`` the driver's log point makes anyway, takes the
-difference from the last fetch modulo 2**32 (exact as long as fewer than
+totals are the job's, and each bundle of steps hands a copy of the subtrees
+back beside its losses (:func:`subtrees`: the state itself is donated to
+the next bundle).  On the host a :class:`StateMetricsBooker` rides the one
+``device_get`` the driver's log point makes anyway, takes the difference
+from the last fetch modulo 2**32 (exact as long as fewer than
 4.29e9 events, or a summed mean under 65,536, fall between two log points)
 and books it: ``inc(name, delta)`` for a counter, one ``observe(name,
 delta_sum / delta_n)`` per subtree for a mean.  The one convention: a state
 dict with the key ``"metrics"`` built by :func:`new_state_metrics`.
 """
 
-from typing import Dict, Iterable, List, Tuple
+from typing import Dict, Iterable
 
 KEY = "metrics"
 _FIXED = 65536.0
@@ -55,21 +57,23 @@ def bump_state_metrics(tree, counters: Dict[str, object],
         "n": tree["n"] + jnp.uint32(1)}
 
 
-def _subtrees(state, path=()) -> List[Tuple[tuple, dict]]:
+def subtrees(state, path=()) -> Dict[tuple, dict]:
+    """The ``"metrics"`` subtrees of a model state by their path in it
+    (``{}``, and nothing to fetch, for a model that keeps none)."""
     if not isinstance(state, dict):
-        return []
-    found = []
+        return {}
+    found = {}
     for k, v in state.items():
         if k == KEY and isinstance(v, dict) and "counters" in v:
-            found.append((path, v))
+            found[path] = v
         else:
-            found.extend(_subtrees(v, path + (k,)))
+            found.update(subtrees(v, path + (k,)))
     return found
 
 
 class StateMetricsBooker:
-    """Host side: finds the ``"metrics"`` subtrees of a model state, hands
-    their leaves to the caller's fetch and books the differences."""
+    """Host side: books the differences between one fetch of a model
+    state's ``"metrics"`` subtrees and the next."""
 
     def __init__(self, model_state, metrics):
         self.metrics = metrics
@@ -85,12 +89,7 @@ class StateMetricsBooker:
         state a resume restored)."""
         import jax
 
-        self._last = jax.device_get(dict(_subtrees(model_state)))
-
-    def leaves(self, model_state):
-        """What to add to the log point's ``device_get`` (nothing, and no
-        walk, for a model that keeps no such subtree)."""
-        return dict(_subtrees(model_state)) if self._last else {}
+        self._last = jax.device_get(subtrees(model_state))
 
     def book(self, fetched) -> None:
         delta = lambda new, old: (int(new) - int(old)) % 2 ** 32

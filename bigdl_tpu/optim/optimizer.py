@@ -9,13 +9,15 @@ failure (bounded by ``bigdl.failure.retryTimes``).
 
 TPU-native: one iteration is one XLA program (no Spark stages); the loop below
 only shards host batches, dispatches the jitted step, and evaluates triggers.
-Loss stays on-device between logs so iterations pipeline.
+Loss stays on-device between logs so iterations pipeline: a log point
+fetches every dispatched bundle but the newest, so the device always holds
+the next program while the host does its work for the one after.
 """
 
 import os
 import time
 import warnings
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence
 
 import jax
 import numpy as np
@@ -41,6 +43,23 @@ from bigdl_tpu.runtime.engine import Engine
 from bigdl_tpu.utils.log import get_logger
 
 log = get_logger("bigdl_tpu.optim")
+
+
+class _Dispatched(NamedTuple):
+    """One dispatched bundle's results, on the device until a log point
+    fetches them."""
+
+    it0: int     # iterations done before its first step
+    steps: int
+    epoch: int
+    losses: Any  # length-``steps`` device vectors
+    gnorms: Any
+    counted: Any  # the model state's "metrics" subtrees after its last step
+
+    @property
+    def end(self) -> int:
+        """Iterations done after its last step."""
+        return self.it0 + self.steps
 
 
 class TrainedModel:
@@ -221,9 +240,9 @@ class Optimizer:
         self._bundle_k = 1
         self._bundle_auto = False
         self._bundle_picked = False
-        self._pending_losses: List = []  # [(first_step, loss_vec, gnorm_vec)]
+        self._pending_losses: List[_Dispatched] = []  # not yet fetched
+        self._log_due = False  # a log point waits for the next dispatch
         self._last_dispatch_end: Optional[float] = None
-        self._inflight = 0
         # perf attribution (docs/observability.md §Step-time attribution):
         # the driver thread's time by phase + live MFU/collective-bytes
         # accounting, resolved per optimize() run
@@ -454,8 +473,8 @@ class Optimizer:
         self._bundle_k = 1 if self._bundle_auto else max(1, int(spc))
         self._bundle_picked = False
         self._pending_losses = []
+        self._log_due = False
         self._last_dispatch_end = None
-        self._inflight = 0
 
         state: Dict[str, Any] = {
             "epoch": 1, "iteration": 0, "epoch_batch": 0,
@@ -470,6 +489,7 @@ class Optimizer:
         # are booked at the log points, counted from here on
         self._state_metrics = StateMetricsBooker(step_engine.model_state,
                                                  self.metrics)
+        self.metrics.inc("train.fetch_overlapped", 0)  # exists from the start
 
         # preemption-aware save: flag-based — the handler must not touch jax
         # from signal context, so the loop checkpoints at the next iteration
@@ -604,17 +624,13 @@ class Optimizer:
                     ran_any = True
                     prev_it = state["iteration"]
                     self._one_bundle(step_engine, state, mbs)
-                    if self._should_log(prev_it, state["iteration"]):
-                        self._log_progress(step_engine, state)
-                    # attribution: trigger work is the "overhead" phase
-                    with attribution.phase("overhead") as trig:
-                        self._fire_triggers(step_engine, state)
-                    # trigger work (validation/checkpoint/histograms) is not
-                    # step time: shift the log window start past it
-                    if getattr(self, "_last_log", None) is not None:
-                        self._last_log = (
-                            self._last_log[0] + trig.seconds,
-                            self._last_log[1])
+                    # a log point's fetch comes one bundle late, once the
+                    # next bundle is queued behind the one it reads
+                    if self._log_due:
+                        self._log_progress(state)
+                    self._log_due = self._should_log(prev_it,
+                                                     state["iteration"])
+                    self._fire_triggers(step_engine, state)
                     if self.cluster is not None \
                             and self.cluster.preempt_pending \
                             and not self._preempted:
@@ -638,6 +654,8 @@ class Optimizer:
                         self._save_checkpoint_once(step_engine, state)
                         break
                     done = self._end_reached(state)
+                    if done:
+                        self._log_progress(state, flush=True)
                     attribution.end_iteration()
                     if done:
                         break
@@ -651,8 +669,7 @@ class Optimizer:
                     # would double-feed plateau schedules.
                     if ran_any or skip == 0 or reshard is not None:
                         state["epoch_finished"] = True
-                        with attribution.phase("overhead"):
-                            self._fire_triggers(step_engine, state)
+                        self._fire_triggers(step_engine, state)
                     state["epoch"] += 1
                     # a resharded epoch's plan dies with the epoch: later
                     # epochs use the normal (seed, epoch, process_count)
@@ -672,7 +689,7 @@ class Optimizer:
                 # rolled-back step chain; drop them so the next log window
                 # never feeds pre-failure losses to the watchdog
                 self._pending_losses = []
-                self._inflight = 0
+                self._log_due = False
                 self._last_dispatch_end = None
                 cause = classify(e)
                 policy = self.failure_policy \
@@ -725,6 +742,9 @@ class Optimizer:
                 self.metrics.reset()
                 attribution.begin()
 
+        # whatever way the loop left (an epoch-count end_when is seen only
+        # at its top), the model handed back has had every loss looked at
+        self._log_progress(state, flush=True)
         attribution.end_iteration()  # the tail: the last end_when call
         if self._recompile is not None:
             # the step loop is over: run-tail work (final checkpoint,
@@ -940,12 +960,14 @@ class Optimizer:
                         # starting, stopping and reading back a trace is
                         # driver time: booked, not left to "other"
                         with self.attribution.phase("overhead"):
-                            self._profiler.step(it0 + j)
+                            self._profiler.step(
+                                it0 + j, settle=lambda: jax.block_until_ready(
+                                    state["loss"]))
             xs = [mb[0] for mb in mbs]
             ys = [mb[1] for mb in mbs]
             with self.attribution.phase("dispatch", steps=k, step=it0,
                                         size=k) as disp:
-                losses, gnorms = step_engine.train_bundle_device(
+                losses, gnorms, counted = step_engine.train_bundle_device(
                     it0, xs, ys)
                 last_loss = losses[-1]  # a device op of its own
             # per-step normalized so the mean stays comparable across
@@ -962,60 +984,87 @@ class Optimizer:
         if self._dcn_bytes_step:
             self.metrics.inc("train.collective_dcn_bytes_total",
                              self._dcn_bytes_step * k)
-        self._pending_losses.append((it0, losses, gnorms))
-        self._inflight += k
-        self.metrics.gauge("train.steps_in_flight", self._inflight)
+        self._pending_losses.append(_Dispatched(
+            it0, k, state["epoch"], losses, gnorms, counted))
+        self._gauge_in_flight()
         self.metrics.gauge("train.bundle_size", k)
-        state["loss"] = last_loss  # device scalar; float() when read
+        # the NEWEST step's loss, as a device scalar: a trigger that reads
+        # it (min_loss, a plateau on "loss") waits for this bundle, as before
+        # the log point stopped doing so; one that does not, does not wait
+        state["loss"] = last_loss
         state["iteration"] = it0 + k
         state["epoch_batch"] = state.get("epoch_batch", 0) + k
+
+    def _gauge_in_flight(self) -> None:
+        self.metrics.gauge("train.steps_in_flight",
+                           sum(b.steps for b in self._pending_losses))
 
     def _should_log(self, prev_it: int, it: int) -> bool:
         # a log point is any multiple of log_every inside (prev_it, it] —
         # bundles quantize the cadence up to their edges
         return it // self.log_every > prev_it // self.log_every
 
-    def _log_progress(self, step_engine, state):
-        it = state["iteration"]
-        # fetching the loss VALUES blocks until the step chain has actually
-        # executed (they are data-dependent on every dispatched bundle), so
-        # the wall-clock window between log points measures real step
+    def _log_progress(self, state, flush: bool = False):
+        """A log point's fetch, made once the NEXT bundle is dispatched: it
+        takes the results of every dispatched bundle but that newest one
+        and logs them.  The newest stays in flight, so while the device
+        runs it the host fires the triggers, pulls the next batch and
+        queues the next bundle: the device never waits for the host's
+        per-step work, and a NaN or a hang is seen one bundle later than
+        it was, no more.  ``flush`` fetches the newest too: where its
+        value is needed (before a validation, a checkpoint or a parameter
+        histogram) and where the loop leaves."""
+        n = len(self._pending_losses) - (0 if flush else 1)
+        if flush:
+            self._log_due = False
+        if n <= 0:
+            return
+        pending = self._pending_losses[:n]
+        del self._pending_losses[:n]
+        # fetching the loss VALUES blocks until these bundles have actually
+        # executed (they are data-dependent on every bundle before them), so
+        # the wall-clock window between two fetches measures real step
         # time — not async dispatch time, which flatters when the in-flight
-        # queue hides device latency.
-        with self.attribution.phase("sync", step=it):
-            pending, self._pending_losses = self._pending_losses, []
+        # queue hides device latency.  The state's counters come as the
+        # bundle's own copy: the state they were in has been donated since.
+        with self.attribution.phase("sync", step=pending[-1].end):
             fetched, counted = jax.device_get((
-                [(lv, gv) for _, lv, gv in pending],
-                self._state_metrics.leaves(step_engine.model_state)))
-            loss = float(state["loss"])
+                [(b.losses, b.gnorms) for b in pending],
+                pending[-1].counted))
+        if self._pending_losses:
+            self.metrics.inc("train.fetch_overlapped")
         with self.attribution.phase("overhead"):
             self._state_metrics.book(counted)
-            self._record_progress(state, it, loss, pending, fetched)
+            self._record_progress(state, pending, fetched)
 
-    def _record_progress(self, state, it, loss, pending, fetched):
+    def _record_progress(self, state, pending, fetched):
         """The log point after the fetch: curves, watchdog, step time,
-        gauges, the log line."""
-        state["loss"] = loss
-        self._inflight = 0
-        self.metrics.gauge("train.steps_in_flight", 0)
+        gauges, the log line, all under the newest FETCHED step's
+        iteration number."""
+        it = pending[-1].end
+        loss = float(np.ravel(fetched[-1][0])[-1])
+        if not self._pending_losses:
+            # nothing newer in flight: this is the state's device scalar
+            state["loss"] = loss
+        self._gauge_in_flight()
         # per-step granularity survives bundling: every bundle returned a
         # length-K loss/grad-norm vector — record the full curves first,
         # then feed the NaN watchdog (which may raise PoisonedStepError
         # into the retry loop after nan_patience bad observations; the
         # fetch above already forced the sync, so none of this costs an
         # extra transfer)
-        for (it0, _, _), (lv, gv) in zip(pending, fetched):
+        for b, (lv, gv) in zip(pending, fetched):
             lv, gv = np.ravel(lv), np.ravel(gv)
             for j in range(len(lv)):
                 self.metrics.observe("train.grad_norm", float(gv[j]))
                 if self._train_summary:
                     self._train_summary.add_scalar(
-                        "loss", float(lv[j]), it0 + j + 1)
+                        "loss", float(lv[j]), b.it0 + j + 1)
         if self.watchdog is not None:
-            for (it0, _, _), (lv, _) in zip(pending, fetched):
+            for b, (lv, _) in zip(pending, fetched):
                 lv = np.ravel(lv)
                 for j in range(len(lv)):
-                    self.watchdog.observe_loss(it0 + j, float(lv[j]))
+                    self.watchdog.observe_loss(b.it0 + j, float(lv[j]))
         now = time.perf_counter()
         last = getattr(self, "_last_log", None)
         dt_is_wall = last is not None and it > last[1]
@@ -1039,7 +1088,7 @@ class Optimizer:
         throughput = self.batch_size / max(dt, 1e-9)
         log.info(
             "Epoch %d Iteration %d: loss %.4f, lr %.5g, ~%.0f records/s",
-            state["epoch"], it, loss, lr, throughput)
+            pending[-1].epoch, it, loss, lr, throughput)
         if self._train_summary:
             self._train_summary.add_scalar("lr", lr, it)
             self._train_summary.add_scalar("throughput", throughput, it)
@@ -1108,31 +1157,48 @@ class Optimizer:
         self._bundle_k = k
 
     def _fire_triggers(self, step_engine, state):
-        # each concern fires at most once per iteration (an iteration-count
-        # trigger would otherwise re-fire at the epoch-boundary call)
+        """Validation, checkpoint and parameter histograms, each where its
+        trigger says so and at most once per iteration (an iteration-count
+        trigger would otherwise re-fire at the epoch-boundary call).  One
+        that fires first has the bundle in flight fetched: its work reads
+        the newest state, and the watchdog must have seen every loss
+        before that state is written anywhere."""
         it = state["iteration"]
-        if (self._val_trigger and self._val_trigger(state)
-                and self._last_val_iter != it):
-            self._last_val_iter = it
-            self._run_validation(step_engine, state)
-        if (self._ckpt_trigger and self._ckpt_trigger(state)
-                and self._ckpt_path and self._last_ckpt_iter != it):
-            self._last_ckpt_iter = it
-            self._save_checkpoint(step_engine, state)
-        hist_trigger = self._summary_triggers.get("Parameters")
-        if (hist_trigger and self._train_summary and hist_trigger(state)
-                and self._last_hist_iter != it):
-            self._last_hist_iter = it
-            variables = step_engine.get_variables()
-            # ONE batched device→host fetch of the whole params tree — a
-            # per-leaf np.asarray would block on a separate transfer per
-            # parameter (hundreds of round-trips on a real model)
-            host_params = jax.device_get(variables["params"])
-            for path, leaf in jax.tree_util.tree_flatten_with_path(
-                    host_params)[0]:
-                tag = "Parameters/" + "/".join(
-                    str(getattr(k, "key", k)) for k in path)
-                self._train_summary.add_histogram(tag, leaf, it)
+        phase = self.attribution.phase
+        for trigger, wanted, last, work in (
+                (self._val_trigger, True, "_last_val_iter",
+                 self._run_validation),
+                (self._ckpt_trigger, self._ckpt_path, "_last_ckpt_iter",
+                 self._save_checkpoint),
+                (self._summary_triggers.get("Parameters"),
+                 self._train_summary, "_last_hist_iter",
+                 self._write_histograms)):
+            with phase("overhead"):
+                due = (trigger and trigger(state) and wanted
+                       and getattr(self, last) != it)
+            if not due:
+                continue
+            setattr(self, last, it)
+            self._log_progress(state, flush=True)
+            with phase("overhead") as spent:
+                work(step_engine, state)
+            # trigger work is not step time: shift the log window's start
+            # past it
+            if getattr(self, "_last_log", None) is not None:
+                self._last_log = (self._last_log[0] + spent.seconds,
+                                  self._last_log[1])
+
+    def _write_histograms(self, step_engine, state):
+        variables = step_engine.get_variables()
+        # ONE batched device→host fetch of the whole params tree — a
+        # per-leaf np.asarray would block on a separate transfer per
+        # parameter (hundreds of round-trips on a real model)
+        host_params = jax.device_get(variables["params"])
+        for path, leaf in jax.tree_util.tree_flatten_with_path(
+                host_params)[0]:
+            tag = "Parameters/" + "/".join(
+                str(getattr(k, "key", k)) for k in path)
+            self._train_summary.add_histogram(tag, leaf, state["iteration"])
 
     def _save_checkpoint_once(self, step_engine, state):
         """Checkpoint unless this iteration was already checkpointed (the
@@ -1145,6 +1211,7 @@ class Optimizer:
             return
         if self._last_ckpt_iter != state["iteration"]:
             self._last_ckpt_iter = state["iteration"]
+            self._log_progress(state, flush=True)
             self._save_checkpoint(step_engine, state)
 
     def _save_checkpoint(self, step_engine, state):

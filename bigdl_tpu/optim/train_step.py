@@ -38,6 +38,7 @@ import numpy as np
 from jax.flatten_util import ravel_pytree
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from bigdl_tpu.obs import state_metrics
 from bigdl_tpu.obs.attr import expected_compile
 from bigdl_tpu.optim.validation import StatsAccumulator
 from bigdl_tpu.parallel import collectives
@@ -710,7 +711,10 @@ class ShardedParameterStep:
         schedule evaluates on device inside each update, so the host does
         zero per-step work between bundle edges.  Returns length-K loss and
         grad-norm vectors so per-step granularity (NaN-streak detection,
-        loss curves) survives bundling.
+        loss curves) survives bundling, and a copy of the model state's
+        ``"metrics"`` subtrees (obs/state_metrics.py): the state itself is
+        donated to the next bundle, so the driver, which fetches a bundle's
+        results while the next one runs, could not read them there.
 
         The K input batches arrive as a K-tuple of ordinary per-batch
         device arrays (each sharded exactly like the single-step program's
@@ -738,7 +742,8 @@ class ShardedParameterStep:
                 jax.lax.scan(body,
                              (flat_p, ema, opt_state, mstate, step0),
                              (x_stack, y_stack))
-            return flat_p, ema, opt_state, mstate, losses, gnorms
+            return (flat_p, ema, opt_state, mstate, losses, gnorms,
+                    state_metrics.subtrees(mstate))
 
         opt_spec, x_spec, y_spec = self._train_specs(x_ex, y_ex)
         xs_spec = (tuple(x_spec for _ in range(n_steps))
@@ -749,7 +754,7 @@ class ShardedParameterStep:
             bundle_shard, mesh=self.mesh,
             in_specs=(P(), P(), opt_spec, P(), P(), P(), xs_spec, ys_spec,
                       P()),
-            out_specs=(P(), P(), opt_spec, P(), P(), P()),
+            out_specs=(P(), P(), opt_spec, P(), P(), P(), P()),
         )
         return jax.jit(mapped, donate_argnums=(0, 1, 2, 3))
 
@@ -999,8 +1004,10 @@ class ShardedParameterStep:
     def train_bundle_device(self, step0: int, xs, ys, base_key=None):
         """Run ``len(xs)`` consecutive training steps as ONE dispatched XLA
         program over already-sharded device batches.  Returns
-        ``(losses, grad_norms)`` — length-K device vectors, one entry per
-        step, fetched lazily by the caller.
+        ``(losses, grad_norms, state_metrics)`` — two length-K device
+        vectors, one entry per step, and the model state's ``"metrics"``
+        subtrees after the last step (``{}`` for a model that keeps none),
+        all fetched lazily by the caller.
 
         Numerics are identical for every bundle size: the scan body is the
         same per-step HLO, per-step PRNG is ``fold_in(base_key, step)`` of
@@ -1035,7 +1042,7 @@ class ShardedParameterStep:
         # recompilation sentinel only flags true cache misses
         with expected_compile() if new_program else nullcontext():
             (self.flat_params, new_ema, self.opt_state, self.model_state,
-             losses, gnorms) = fn(
+             losses, gnorms, counted) = fn(
                 self.flat_params, ema_in, self.opt_state, self.model_state,
                 jnp.asarray(step0, jnp.int32), base_key,
                 tuple(xs), tuple(ys), mask_in)
@@ -1043,7 +1050,7 @@ class ShardedParameterStep:
             self.ema_flat = new_ema
         else:
             self._ema_dummy = new_ema
-        return losses, gnorms
+        return losses, gnorms, counted
 
     def evaluate(self, methods, batches) -> list:
         # cache key must be the method *instances* (two Loss() objects with

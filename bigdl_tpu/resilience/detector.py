@@ -233,7 +233,11 @@ class StepWatchdog:
         ``nan_patience`` consecutive non-finite values.  The driver loop
         calls this at log points — loss observation already forces a
         device sync there, so the check adds no extra transfer."""
-        self._started = None  # the step chain up to here completed
+        # the step chain up to here completed.  The driver observes a
+        # bundle while the next one runs: a loss from before the newest
+        # started step restarts that step's clock (the device has only now
+        # reached it) instead of stopping it
+        self._started = None if step >= self._step else self._clock()
         if math.isfinite(loss):
             self._nan_streak = 0
             return

@@ -87,13 +87,21 @@ class IterationProfiler:
             table = f"could not be read back ({type(e).__name__}: {e})"
         log.info("device idle time by driver phase: %s", table)
 
-    def step(self, iteration: int) -> None:
-        """Call once per training iteration (before the step dispatch)."""
+    def step(self, iteration: int, settle=None) -> None:
+        """Call once per training iteration (before the step dispatch).
+        ``settle`` is called before the trace starts and before it stops:
+        a driver that keeps a step in flight waits for it there, so that
+        the trace holds whole steps and as many step programs as
+        ``train/dispatch`` spans."""
         if self.done:
             return
-        if not self._active and iteration >= self.start_iter:
+        starting = not self._active and iteration >= self.start_iter
+        stopping = self._active and iteration >= self.stop_iter
+        if settle is not None and (starting or stopping):
+            settle()
+        if starting:
             self._start()
-        elif self._active and iteration >= self.stop_iter:
+        elif stopping:
             self._stop(f"iters {self.start_iter}-{self.stop_iter - 1}")
 
     def close(self) -> None:

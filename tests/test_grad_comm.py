@@ -214,9 +214,9 @@ def test_int8_bucketed_bundle_with_clip_and_ema():
     a.set_step_seed(3)
     b.set_step_seed(3)
     xd, yd = a.shard_batch(x), a.shard_batch(y)
-    la1, g1 = a.train_bundle_device(0, [xd], [yd])
-    la2, _ = a.train_bundle_device(1, [xd, xd], [yd, yd])
-    lb, gb = b.train_bundle_device(0, [xd, xd, xd], [yd, yd, yd])
+    la1, g1, _ = a.train_bundle_device(0, [xd], [yd])
+    la2, _, _ = a.train_bundle_device(1, [xd, xd], [yd, yd])
+    lb, gb, _ = b.train_bundle_device(0, [xd, xd, xd], [yd, yd, yd])
     traj = np.concatenate([np.ravel(la1), np.ravel(la2)])
     np.testing.assert_array_equal(traj.astype(np.float32),
                                   np.ravel(lb).astype(np.float32))

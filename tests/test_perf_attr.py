@@ -404,9 +404,12 @@ def test_sync_is_observed_per_log_point(monkeypatch):
 
     monkeypatch.setattr(optim.Optimizer, "__init__", init)
     opt = _train(monkeypatch, iterations=12)
+    # the log points at 4 and 8 are fetched once step 5 and step 9 are
+    # queued; the one at 12 by the flush where the loop leaves
     assert _hist(opt, "train.attr.sync_s")[0] == 3
     assert _hist(opt, "train.attr.dispatch_s")[0] == 12
     assert opt.attribution.report()["windows"] == 3
+    assert opt.metrics.counter("train.fetch_overlapped") == 2
 
 
 def test_cold_compile_is_booked_as_compile_not_dispatch():
