@@ -13,6 +13,7 @@ all-to-all attention (``bigdl_tpu.parallel``) — capabilities the
 reference lacks (SURVEY.md §6.7).
 """
 
+import functools
 import math
 from typing import Optional
 
@@ -36,14 +37,15 @@ def _axis_bound(name: str) -> bool:
 
 
 def dot_product_attention(q, k, v, mask=None, dropout_p=0.0, rng=None,
-                          training=False):
-    """q,k,v: (b, heads, len, dim).  mask: broadcastable to (b, h, lq, lk),
-    True = attend."""
+                          training=False, scale=None):
+    """q,k,v: (b, heads, len, dim); v's dim may differ.  mask: broadcastable
+    to (b, h, lq, lk), True = attend.  ``scale``: what the scores are
+    multiplied by (default ``dim ** -0.5``)."""
     d = q.shape[-1]
     qc, kc = cast_compute(q, k)
     logits = jnp.einsum("bhqd,bhkd->bhqk", qc, kc,
                         preferred_element_type=jnp.float32)
-    logits = logits / math.sqrt(d)
+    logits = logits / math.sqrt(d) if scale is None else logits * scale
     if mask is not None:
         logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
     weights = jax.nn.softmax(logits, axis=-1)
@@ -179,19 +181,61 @@ class MultiHeadAttention(Module):
         return y, EMPTY
 
 
-def rope(x, theta: float = 10000.0, offset=0):
-    """Rotary position embedding over the last axis of ``x`` (..., len,
-    dim), in float32, rotate-half pairing: dim ``i`` turns with dim
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature, ``0.1 * mscale * ln(factor) + 1``
+    (1 where nothing is stretched)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def _yarn_frequencies(freq, dim: int, theta: float, scaling: dict):
+    """(inv_freq, mscale) from the plain frequencies ``freq`` (dim/2,): YaRN
+    (Peng et al., arXiv:2309.00071) as DeepSeek-V2 publishes it.  Pairs
+    that turn more than ``beta_fast``
+    times over the original context keep their frequency, pairs that turn
+    fewer than ``beta_slow`` times are slowed by ``factor``, and a linear
+    ramp over the pair index blends the two in between; cos and sin are
+    scaled by ``yarn_mscale(factor, mscale) / yarn_mscale(factor,
+    mscale_all_dim)``."""
+    if scaling.get("type", scaling.get("rope_type")) != "yarn":
+        raise ValueError(f"rope scaling {scaling!r}: only 'yarn' is known")
+    factor = float(scaling["factor"])
+    original = scaling["original_max_position_embeddings"]
+
+    def pair_turning(turns):      # the pair that turns ``turns`` times
+        return (dim * math.log(original / (2 * math.pi * turns))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(pair_turning(scaling.get("beta_fast", 32))), 0)
+    high = min(math.ceil(pair_turning(scaling.get("beta_slow", 1))), dim - 1)
+    i = jnp.arange(dim // 2, dtype=jnp.float32)
+    ramp = jnp.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    inv_freq = freq / factor * ramp + freq * (1.0 - ramp)
+    mscale = (yarn_mscale(factor, scaling.get("mscale", 1.0))
+              / yarn_mscale(factor, scaling.get("mscale_all_dim", 0.0)))
+    return inv_freq, mscale
+
+
+def rope(x, theta: float = 10000.0, offset=0, scaling=None):
+    """Rotary position embedding over the last axis of ``x`` (..., length,
+    dim), positions ``offset .. offset + length - 1`` along axis -2, in
+    float32.  Rotate-half pairing: feature ``i`` is rotated with feature
     ``i + dim/2`` by the angle ``pos * theta ** (-2i / dim)``.  (The
     interleaved pairing some checkpoints use is a fixed permutation of the
-    projection's columns: same shapes, same work.)"""
+    projection's columns: same shapes, same work.)  ``scaling``: a
+    config's ``rope_scaling`` dict of type "yarn" (the frequencies blended
+    by wavelength, :func:`_yarn_frequencies`), or None."""
     length, dim = x.shape[-2], x.shape[-1]
     half = dim // 2
     pos = (jnp.arange(length) + offset).astype(jnp.float32)[:, None]
     freq = jnp.power(float(theta),
                      -jnp.arange(half, dtype=jnp.float32) * 2.0 / dim)
+    mscale = 1
+    if scaling is not None:
+        freq, mscale = _yarn_frequencies(freq, dim, theta, scaling)
     angle = pos * freq[None, :]
     cos, sin = jnp.cos(angle), jnp.sin(angle)
+    if mscale != 1:
+        cos, sin = cos * mscale, sin * mscale
     x32 = x.astype(jnp.float32)
     x1, x2 = x32[..., :half], x32[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
@@ -215,7 +259,10 @@ class LatentAttention(Module):
     heads * (nope + v_dim)) to per-head no-position keys and values; the
     last ``rope`` columns are ONE rotary key shared by every head.  Only the
     rotary slices of q and k carry positions.  Scores are scaled by
-    ``(nope + rope) ** -0.5``.  No biases.
+    ``(nope + rope) ** -0.5``, times ``yarn_mscale(factor,
+    mscale_all_dim) ** 2`` under a YaRN ``rope_scaling``.  No biases.
+    ``v_dim`` need not be ``nope + rope``: the flash kernels take keys and
+    values of different widths.
 
     The latent (``kv_rank + rope`` per token) is what a decode cache would
     hold; this module does not cache — serving through the paged engine
@@ -223,13 +270,19 @@ class LatentAttention(Module):
 
     def __init__(self, hidden_size: int, num_heads: int, *, q_rank: int,
                  kv_rank: int, nope_dim: int, rope_dim: int, v_dim: int,
-                 rope_theta: float = 10000.0, eps: float = 1e-6,
-                 use_flash=None, name=None):
+                 rope_theta: float = 10000.0, rope_scaling=None,
+                 eps: float = 1e-6, use_flash=None, name=None):
         super().__init__(name)
         self.hidden_size, self.num_heads = hidden_size, num_heads
         self.q_rank, self.kv_rank = q_rank, kv_rank
         self.nope_dim, self.rope_dim, self.v_dim = nope_dim, rope_dim, v_dim
         self.rope_theta, self.eps = rope_theta, eps
+        self.rope_scaling = dict(rope_scaling) if rope_scaling else None
+        self.sm_scale = (nope_dim + rope_dim) ** -0.5
+        if self.rope_scaling:
+            self.sm_scale *= yarn_mscale(
+                self.rope_scaling["factor"],
+                self.rope_scaling.get("mscale_all_dim", 0.0)) ** 2
         # None = the Pallas flash kernel on a TPU, XLA attention elsewhere
         self.use_flash = use_flash
 
@@ -262,11 +315,12 @@ class LatentAttention(Module):
             kv = _project(x, params["wkv_a"])
             ckv = rms_norm(kv[..., :self.kv_rank], params["kv_norm"],
                            self.eps)
-            k_pe = rope(kv[..., None, :, self.kv_rank:], self.rope_theta)
+            turn = functools.partial(rope, theta=self.rope_theta,
+                                     scaling=self.rope_scaling)
+            k_pe = turn(kv[..., None, :, self.kv_rank:])
             kv = _project(ckv, params["wkv_b"]).reshape(
                 b, t, h, nope + vd).transpose(0, 2, 1, 3)
-            q = jnp.concatenate(
-                [q[..., :nope], rope(q[..., nope:], self.rope_theta)], -1)
+            q = jnp.concatenate([q[..., :nope], turn(q[..., nope:])], -1)
             k = jnp.concatenate(
                 [kv[..., :nope], jnp.broadcast_to(k_pe, (b, h, t, rp))], -1)
             v = kv[..., nope:]
@@ -276,13 +330,17 @@ class LatentAttention(Module):
                 from bigdl_tpu.ops.common import on_tpu
 
                 use_flash = on_tpu()
-            if use_flash and vd == nope + rp:
+            if use_flash:
                 from bigdl_tpu.ops.flash_attention import flash_attention
 
-                out = flash_attention(q, k, v, causal=True)
+                out = flash_attention(q, k, v, causal=True,
+                                      sm_scale=self.sm_scale)
             else:
+                # no scaling: the default's division, bit for bit as before
                 out = dot_product_attention(
-                    q, k, v, mask=jnp.tril(jnp.ones((t, t), bool)))
+                    q, k, v, mask=jnp.tril(jnp.ones((t, t), bool)),
+                    scale=None if self.rope_scaling is None
+                    else self.sm_scale)
         with jax.named_scope("mla/proj"):
             out = out.transpose(0, 2, 1, 3).reshape(b, t, h * vd)
             return _project(out, params["wo"]), EMPTY

@@ -472,10 +472,12 @@ def _dtype_name(dtype) -> str:
     return np.dtype(dtype).name if not hasattr(dtype, "name") else dtype.name
 
 
-def attention_key(q_shape, kv_len: int, dtype) -> str:
+def attention_key(q_shape, kv_len: int, dtype, d_v=None) -> str:
+    """``d_v``: the width of v where it is not ``d`` (q's and k's)."""
     b, h, s, d = q_shape
+    width = f"d{d}" if d_v in (None, d) else f"d{d}v{d_v}"
     return (f"bh{_pow2_bucket(b * h)}_q{_pow2_bucket(s)}"
-            f"_k{_pow2_bucket(kv_len)}_d{d}_{_dtype_name(dtype)}")
+            f"_k{_pow2_bucket(kv_len)}_{width}_{_dtype_name(dtype)}")
 
 
 def decode_attention_key(slots: int, heads: int, page: int, hd: int,

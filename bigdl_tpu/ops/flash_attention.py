@@ -34,7 +34,8 @@ Cross-chip sequence parallelism lives in
 ``bigdl_tpu/parallel/ring_attention.py``; the paged decode/verify kernels
 below are the serving-side siblings.
 
-Shapes: q, k, v are (batch, heads, seq, head_dim); output matches q.
+Shapes: q, k are (batch, heads, seq, head_dim), v is (batch, heads, seq,
+v_dim); the output has q's length and v's width.
 """
 
 import functools
@@ -74,30 +75,35 @@ def _tile_bytes(rows, cols, itemsize):
 
 
 def block_vmem_bytes(direction: str, block_q: int, block_k: int, d: int,
-                     itemsize: int) -> int:
+                     itemsize: int, d_v: Optional[int] = None) -> int:
     """What one grid step holds in VMEM at these blocks, ``fwd`` or ``bwd``
     (the larger of the dq and the dk/dv kernel, which share one pair):
     double-buffered operand and result tiles, the per-row statistics
     (lane- or sublane-padded), float32 accumulators, and the float32
-    (block_q, block_k) temporaries of the score tile."""
+    (block_q, block_k) temporaries of the score tile.  ``d`` is the width
+    of q and k, ``d_v`` that of v, out and their gradients (default ``d``)."""
+    d_v = d if d_v is None else d_v
     q_tile = _tile_bytes(block_q, d, itemsize)
     k_tile = _tile_bytes(block_k, d, itemsize)
+    o_tile = _tile_bytes(block_q, d_v, itemsize)
+    v_tile = _tile_bytes(block_k, d_v, itemsize)
     col = _tile_bytes(block_q, 1, 4)
     scores = block_q * block_k * 4
     if direction == "fwd":    # q, k, v -> out, lse; m, l, acc; s, p, p~
-        return (2 * (2 * q_tile + 2 * k_tile + col) + 2 * col
-                + _tile_bytes(block_q, d, 4) + 3 * scores)
+        return (2 * (q_tile + o_tile + k_tile + v_tile + col) + 2 * col
+                + _tile_bytes(block_q, d_v, 4) + 3 * scores)
     # dq: q, g, k, v, lse, delta -> dq; acc.  dk/dv: the same operands
     # (statistics as rows) -> dk, dv; two acc.  Both: s/p, dp, ds, ds~
-    dq = (2 * (3 * q_tile + 2 * k_tile + 2 * col)
+    dq = (2 * (2 * q_tile + o_tile + k_tile + v_tile + 2 * col)
           + _tile_bytes(block_q, d, 4))
-    dkv = (2 * (2 * q_tile + 4 * k_tile + 2 * _tile_bytes(8, block_q, 4))
-           + 2 * _tile_bytes(block_k, d, 4))
+    dkv = (2 * (q_tile + o_tile + 2 * k_tile + 2 * v_tile
+                + 2 * _tile_bytes(8, block_q, 4))
+           + _tile_bytes(block_k, d, 4) + _tile_bytes(block_k, d_v, 4))
     return max(dq, dkv) + 4 * scores
 
 
 def default_blocks(direction: str, sq: int, skv: int, d: int,
-                   itemsize: int) -> Dict[str, int]:
+                   itemsize: int, d_v: Optional[int] = None) -> Dict[str, int]:
     """The block rule: the largest ``(block_q, block_k)`` of
     ``BLOCK_CHOICES``, none longer than the 128-padded sequence, whose
     :func:`block_vmem_bytes` fits ``_VMEM_BLOCK_BUDGET``.  Largest by area
@@ -108,7 +114,7 @@ def default_blocks(direction: str, sq: int, skv: int, d: int,
     for bq in BLOCK_CHOICES:
         for bk in BLOCK_CHOICES:
             if (bq > round_up(sq, _LANES) or bk > round_up(skv, _LANES)
-                    or block_vmem_bytes(direction, bq, bk, d, itemsize)
+                    or block_vmem_bytes(direction, bq, bk, d, itemsize, d_v)
                     > _VMEM_BLOCK_BUDGET):
                 continue
             rank = (bq * bk, -abs(bq - bk), bk)
@@ -202,7 +208,8 @@ def _element_mask(q0, k0, shape, q_axis, *, causal, kv_len):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                 *, sm_scale, causal, block_q, block_k, kv_len, skv_p):
-    # q_ref: (1, block_q, d); k_ref/v_ref: (1, block_k, d) — one tile each.
+    # q_ref: (1, block_q, d); k_ref: (1, block_k, d); v_ref: (1, block_k,
+    # d_v); o_ref: (1, block_q, d_v) — one tile each.
     qi = pl.program_id(1)
     kj = pl.program_id(2)
 
@@ -251,18 +258,19 @@ def _kv_index_map(causal, bq, bk, nk):
 
 def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     b, h, sq, d = q.shape
-    skv = k.shape[2]
+    skv, d_v = k.shape[2], v.shape[3]
     bq, bk, sq_p, skv_p = _clip_blocks(block_q, block_k, sq, skv)
     qp = _pad_seq(q, sq_p).reshape(b * h, sq_p, d)
     kp = _pad_seq(k, skv_p).reshape(b * h, skv_p, d)
-    vp = _pad_seq(v, skv_p).reshape(b * h, skv_p, d)
+    vp = _pad_seq(v, skv_p).reshape(b * h, skv_p, d_v)
 
     nq, nk = sq_p // bq, skv_p // bk
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal, block_q=bq,
         block_k=bk, kv_len=skv, skv_p=skv_p)
     q_spec = pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0))
-    kv_spec = pl.BlockSpec((1, bk, d), _kv_index_map(causal, bq, bk, nk))
+    o_spec = pl.BlockSpec((1, bq, d_v), lambda bh, i, j: (bh, i, 0))
+    kv_map = _kv_index_map(causal, bq, bk, nk)
     # the row statistics carry a trailing singleton lane dim: a 2-D (1, bq)
     # block would put bq in the lane slot and 1 in the sublane slot, which
     # TPU tiling rejects when batch·heads > 1.
@@ -271,22 +279,23 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     out, lse = pl.pallas_call(
         kernel,
         grid=(b * h, nq, nk),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=[q_spec, stat_spec],
+        in_specs=[q_spec, pl.BlockSpec((1, bk, d), kv_map),
+                  pl.BlockSpec((1, bk, d_v), kv_map)],
+        out_specs=[o_spec, stat_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, sq_p, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, sq_p, d_v), q.dtype),
             jax.ShapeDtypeStruct((b * h, sq_p, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),   # running max
             pltpu.VMEM((bq, 1), jnp.float32),   # running denom
-            pltpu.VMEM((bq, d), jnp.float32),   # output accumulator
+            pltpu.VMEM((bq, d_v), jnp.float32),  # output accumulator
         ],
         compiler_params=_compiler_params(),
         interpret=default_interpret(interpret),
     )(qp, kp, vp)
 
-    out = out.reshape(b, h, sq_p, d)[:, :, :sq]
+    out = out.reshape(b, h, sq_p, d_v)[:, :, :sq]
     lse = lse.reshape(b, h, sq_p)[:, :, :sq]
     return out, lse  # lse: (b, h, sq)
 
@@ -369,16 +378,16 @@ def _flash_bwd(q, k, v, out, lse, g, sm_scale, causal, block_q, block_k,
     gradient: padded queries have g = 0 and delta = 0, padded keys are
     masked out of ``p``; both are sliced off the results."""
     b, h, sq, d = q.shape
-    skv = k.shape[2]
+    skv, d_v = k.shape[2], v.shape[3]
     bq, bk, sq_p, skv_p = _clip_blocks(block_q, block_k, sq, skv)
     nq, nk = sq_p // bq, skv_p // bk
     bh = b * h
 
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), -1)
     qp = _pad_seq(q, sq_p).reshape(bh, sq_p, d)
-    gp = _pad_seq(g, sq_p).reshape(bh, sq_p, d)
+    gp = _pad_seq(g, sq_p).reshape(bh, sq_p, d_v)
     kp = _pad_seq(k, skv_p).reshape(bh, skv_p, d)
-    vp = _pad_seq(v, skv_p).reshape(bh, skv_p, d)
+    vp = _pad_seq(v, skv_p).reshape(bh, skv_p, d_v)
     stats = [jnp.pad(x.reshape(bh, sq), ((0, 0), (0, sq_p - sq)))
              for x in (lse, delta)]
 
@@ -388,12 +397,15 @@ def _flash_bwd(q, k, v, out, lse, g, sm_scale, causal, block_q, block_k,
 
     # dq: grid (bh, q-blocks, k-blocks); statistics as (rows, 1) columns
     q_spec = pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0))
+    g_spec = pl.BlockSpec((1, bq, d_v), lambda bh, i, j: (bh, i, 0))
     col_spec = pl.BlockSpec((1, bq, 1), lambda bh, i, j: (bh, i, 0))
-    kv_spec = pl.BlockSpec((1, bk, d), _kv_index_map(causal, bq, bk, nk))
+    kv_map = _kv_index_map(causal, bq, bk, nk)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, **static),
         grid=(bh, nq, nk),
-        in_specs=[q_spec, q_spec, col_spec, col_spec, kv_spec, kv_spec],
+        in_specs=[q_spec, g_spec, col_spec, col_spec,
+                  pl.BlockSpec((1, bk, d), kv_map),
+                  pl.BlockSpec((1, bk, d_v), kv_map)],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((bh, sq_p, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
@@ -410,27 +422,31 @@ def _flash_bwd(q, k, v, out, lse, g, sm_scale, causal, block_q, block_k,
             i = jnp.maximum(i, jnp.minimum((j * bk) // bq, nq - 1))
         return i
 
-    qd_spec = pl.BlockSpec((1, bq, d),
-                           lambda bh, j, i: (bh, q_block(j, i), 0))
+    def qd_spec(width):
+        return pl.BlockSpec((1, bq, width),
+                            lambda bh, j, i: (bh, q_block(j, i), 0))
+
     row_spec = pl.BlockSpec(
         (1, 1, 1, bq), lambda bh, j, i: (bh, q_block(j, i), 0, 0))
     kd_spec = pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0))
+    vd_spec = pl.BlockSpec((1, bk, d_v), lambda bh, j, i: (bh, j, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, **static),
         grid=(bh, nk, nq),
-        in_specs=[qd_spec, qd_spec, row_spec, row_spec, kd_spec, kd_spec],
-        out_specs=[kd_spec, kd_spec],
+        in_specs=[qd_spec(d), qd_spec(d_v), row_spec, row_spec, kd_spec,
+                  vd_spec],
+        out_specs=[kd_spec, vd_spec],
         out_shape=[jax.ShapeDtypeStruct((bh, skv_p, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, skv_p, d), v.dtype)],
+                   jax.ShapeDtypeStruct((bh, skv_p, d_v), v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)],
+                        pltpu.VMEM((bk, d_v), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=interpret,
     )(qp, gp, *(x.reshape(bh, nq, 1, bq) for x in stats), kp, vp)
 
     dq = dq.reshape(b, h, sq_p, d)[:, :, :sq]
     dk = dk.reshape(b, h, skv_p, d)[:, :, :skv]
-    dv = dv.reshape(b, h, skv_p, d)[:, :, :skv]
+    dv = dv.reshape(b, h, skv_p, d_v)[:, :, :skv]
     return dq, dk, dv
 
 
@@ -799,23 +815,24 @@ def _book_trace(direction, q_shape, skv, dtype, causal, block_q, block_k):
                      block_q, block_k)
 
 
-def resolve_blocks(q_shape, skv, dtype, *, block_q=None, block_k=None,
-                   block_k_bwd=None, online_shape=None):
+def resolve_blocks(q_shape, skv, dtype, *, d_v=None, block_q=None,
+                   block_k=None, block_k_bwd=None, online_shape=None):
     """``(forward, backward)`` blocks of one call on operands of ``dtype``:
     per axis an explicit kwarg, else a cached autotune winner for this
     device/shape bucket, else :func:`default_blocks`' pick.  Explicit
     ``block_q``/``block_k`` also pin the backward pair's; ``block_k_bwd``
-    frees its key block again."""
+    frees its key block again.  ``d_v``: the width of v where it is not
+    q's."""
     from bigdl_tpu.ops import autotune
 
     dtype = jnp.dtype(dtype)
     sq, d = q_shape[2], q_shape[3]
-    key = autotune.attention_key(q_shape, skv, dtype)
+    key = autotune.attention_key(q_shape, skv, dtype, d_v)
     fwd = autotune.resolve(
         "flash_attention_fwd", key,
         explicit={"block_q": block_q, "block_k": block_k},
         online_shape=online_shape,
-        defaults=default_blocks("fwd", sq, skv, d, dtype.itemsize))
+        defaults=default_blocks("fwd", sq, skv, d, dtype.itemsize, d_v))
     # the backward: cache/defaults only — no online_shape: a forward-only
     # eager call must not pay a jax.grad tuning sweep for a backward it
     # may never run (the offline CLI tunes flash_attention_bwd)
@@ -824,7 +841,7 @@ def resolve_blocks(q_shape, skv, dtype, *, block_q=None, block_k=None,
         explicit={"block_q": block_q,
                   "block_k": block_k if block_k_bwd is None
                   else block_k_bwd},
-        defaults=default_blocks("bwd", sq, skv, d, dtype.itemsize))
+        defaults=default_blocks("bwd", sq, skv, d, dtype.itemsize, d_v))
     return fwd, bwd
 
 
@@ -834,7 +851,10 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     block_k: Optional[int] = None,
                     block_k_bwd: Optional[int] = None,
                     interpret: Optional[bool] = None):
-    """Fused blockwise attention.  q, k, v: (batch, heads, seq, head_dim).
+    """Fused blockwise attention.  q, k: (batch, heads, seq, head_dim); v:
+    (batch, heads, seq, v_dim), where ``v_dim`` need not be ``head_dim``
+    (latent attention publishes 192-wide keys against 128-wide values); the
+    result has v's width.  ``sm_scale`` defaults to ``head_dim ** -0.5``.
 
     The operands are cast to the policy's compute dtype (bfloat16 on a
     TPU, float32 elsewhere) as ``dot_product_attention`` casts its own;
@@ -855,11 +875,13 @@ def flash_attention(q, k, v, *, causal: bool = False,
     # online mode tunes on a cache miss, but only on EAGER calls —
     # inside a jit trace the args are tracers and we must not run timing
     # trials mid-trace
+    d_v = None if v.shape[-1] == q.shape[-1] else v.shape[-1]
+    # the online tuner's bench shape is (b, h, s, d): equal widths only
     shape = (tuple(q.shape) + (q.dtype.name,)
-             if autotune.is_concrete(q, k, v) else None)
-    fwd, bwd = resolve_blocks(q.shape, skv, q.dtype, block_q=block_q,
-                              block_k=block_k, block_k_bwd=block_k_bwd,
-                              online_shape=shape)
+             if d_v is None and autotune.is_concrete(q, k, v) else None)
+    fwd, bwd = resolve_blocks(q.shape, skv, q.dtype, d_v=d_v,
+                              block_q=block_q, block_k=block_k,
+                              block_k_bwd=block_k_bwd, online_shape=shape)
     _book_trace("fwd", q.shape, skv, q.dtype, bool(causal),
                 int(fwd["block_q"]), int(fwd["block_k"]))
     out = _flash(q, k, v, float(sm_scale), bool(causal),

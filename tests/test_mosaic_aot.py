@@ -84,6 +84,8 @@ def test_paged_verify_attention(v5e, quantized):
     ((8, 12, 1024, 64), jnp.float32),
     ((2, 20, 4096, 256), jnp.bfloat16),    # glm-4.7-flash.train-packed4k
     ((2, 20, 4096, 256), jnp.float32),
+    ((2, 4, 4096, 192, 128), jnp.bfloat16),    # xing4.0-29b-a4b: 192-wide
+    ((2, 4, 4096, 192, 128), jnp.float32),     # keys, 128-wide values
     ((2, 4, 200, 64), jnp.bfloat16),       # padded: 200 rows, one block
     ((1, 4, 1500, 128), jnp.bfloat16),     # padded to 1536, blocks of 512
 ])
@@ -94,13 +96,14 @@ def test_flash_attention_forward_and_backward(v5e, shape, dtype):
     from bigdl_tpu.ops.flash_attention import flash_attention
     from bigdl_tpu.tensor.policy import compute_dtype
 
-    q = v5e(shape, jnp.float32)  # activations are float32; the call casts
+    q = v5e(shape[:4], jnp.float32)  # activations are float32; the call casts
+    v = v5e(shape[:3] + shape[4:], jnp.float32) if len(shape) == 5 else q
 
     def loss(q, k, v):
         return flash_attention(q, k, v, causal=True, interpret=False).sum()
 
     with compute_dtype(dtype):
-        _compile(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+        _compile(jax.grad(loss, argnums=(0, 1, 2)), q, q, v)
 
 
 @pytest.mark.parametrize("block,ok", [((128, 128), True), ((64, 64), False),

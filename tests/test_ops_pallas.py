@@ -21,28 +21,46 @@ def _rand(rng, *shape):
 
 
 class TestFlashAttention:
+    # d_v: the values' width; 24 / 16 is latent attention's 192 / 128
+    @pytest.mark.parametrize("d,d_v", [(16, 16), (24, 16), (8, 24)])
     @pytest.mark.parametrize("causal", [False, True])
-    def test_matches_reference(self, causal):
+    def test_matches_reference(self, causal, d, d_v):
         rng = np.random.default_rng(0)
-        q = _rand(rng, 2, 3, 40, 16)
-        k = _rand(rng, 2, 3, 40, 16)
-        v = _rand(rng, 2, 3, 40, 16)
+        q = _rand(rng, 2, 3, 40, d)
+        k = _rand(rng, 2, 3, 40, d)
+        v = _rand(rng, 2, 3, 40, d_v)
         out = flash_attention(q, k, v, causal=causal, block_q=16, block_k=16,
                               interpret=True)
+        assert out.shape == (2, 3, 40, d_v)
         mask = jnp.tril(jnp.ones((40, 40), bool)) if causal else None
         ref = dot_product_attention(q, k, v, mask=mask)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-4, atol=2e-4)
 
-    def test_unaligned_and_cross_lengths(self):
+    @pytest.mark.parametrize("d,d_v", [(8, 8), (24, 16)])
+    def test_unaligned_and_cross_lengths(self, d, d_v):
         rng = np.random.default_rng(1)
-        q = _rand(rng, 1, 2, 37, 8)
-        k = _rand(rng, 1, 2, 53, 8)
-        v = _rand(rng, 1, 2, 53, 8)
+        q = _rand(rng, 1, 2, 37, d)
+        k = _rand(rng, 1, 2, 53, d)
+        v = _rand(rng, 1, 2, 53, d_v)
         out = flash_attention(q, k, v, block_q=16, block_k=16, interpret=True)
         ref = dot_product_attention(q, k, v)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-4, atol=2e-4)
+
+    def test_the_caller_passes_the_softmax_scale(self):
+        """``sm_scale`` is the caller's (YaRN's mscale ** 2 rides it)."""
+        rng = np.random.default_rng(4)
+        q, k, v = (_rand(rng, 1, 2, 24, 24), _rand(rng, 1, 2, 24, 24),
+                   _rand(rng, 1, 2, 24, 16))
+        mask = jnp.tril(jnp.ones((24, 24), bool))
+        out = flash_attention(q, k, v, causal=True, sm_scale=0.37,
+                              block_q=8, block_k=8, interpret=True)
+        ref = dot_product_attention(q, k, v, mask=mask, scale=0.37)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=2e-4, atol=2e-4)
+        assert float(jnp.abs(ref - dot_product_attention(
+            q, k, v, mask=mask)).max()) > 1e-2
 
     @pytest.mark.parametrize("causal", [False, True])
     def test_gradients_match(self, causal):
@@ -84,7 +102,7 @@ def _rel_err(a, b):
     return float(np.abs(a - b).max() / np.abs(b).max())
 
 
-# (causal, sq, skv, head size, block_q, block_k)
+# (causal, sq, skv, head size or (q/k width, v width), block_q, block_k)
 _BWD_CASES = [
     (False, 32, 32, 16, 8, 8),      # square, every tile visited
     (True, 32, 32, 16, 8, 8),       # square, tiles above the diagonal skipped
@@ -98,6 +116,11 @@ _BWD_CASES = [
     (True, 21, 21, 8, 128, 128),    # blocks longer than the sequence
     (True, 16, 16, 64, 8, 8),       # gpt2-small's head size
     (True, 16, 16, 256, 8, 8),      # GLM-4.7-Flash's head size
+    (True, 16, 16, (192, 128), 8, 8),   # Xing4.0's: keys wider than values
+    (False, 32, 32, (24, 16), 8, 8),
+    (True, 32, 32, (24, 16), 16, 8),
+    (True, 37, 53, (24, 16), 16, 16),   # lengths the blocks do not divide
+    (True, 40, 24, (8, 24), 16, 8),     # values wider than keys
 ]
 
 
@@ -107,9 +130,10 @@ class TestFlashBackwardKernels:
 
     @staticmethod
     def _inputs(sq, skv, d, seed=5):
+        d, d_v = d if isinstance(d, tuple) else (d, d)
         rng = np.random.default_rng(seed)
         return (_rand(rng, 1, 2, sq, d), _rand(rng, 1, 2, skv, d),
-                _rand(rng, 1, 2, skv, d))
+                _rand(rng, 1, 2, skv, d_v))
 
     @staticmethod
     def _reference(causal, sq, skv):
@@ -187,6 +211,9 @@ class TestFlashBackwardKernels:
         (24, 640, 64, 2),        # the decode engine's buckets and chunks
         (300, 5000, 128, 2),
         (8192, 8192, 512, 4),    # a head size that leaves little room
+        (4096, 4096, (192, 128), 2),   # xing4.0-29b-a4b.train-tp8-packed4k
+        (4096, 4096, (192, 128), 4),
+        (8192, 8192, (512, 64), 4),
     ])
     def test_block_rule_returns_legal_blocks(self, direction, sq, skv, d,
                                              itemsize):
@@ -194,14 +221,18 @@ class TestFlashBackwardKernels:
         fa = importlib.import_module("bigdl_tpu.ops.flash_attention")
         from bigdl_tpu.ops.common import round_up
 
-        got = fa.default_blocks(direction, sq, skv, d, itemsize)
+        d, d_v = d if isinstance(d, tuple) else (d, None)
+        got = fa.default_blocks(direction, sq, skv, d, itemsize, d_v)
+        if d_v is None:     # the values' width left out is the keys'
+            assert got == fa.default_blocks(direction, sq, skv, d, itemsize,
+                                            d)
         bq, bk = got["block_q"], got["block_k"]
         # multiples of 128 (Mosaic's lane tiling), at most 1024, never
         # longer than the 128-padded length, inside the VMEM budget
         assert bq % 128 == 0 and bk % 128 == 0
         assert 128 <= bq <= min(1024, round_up(sq, 128))
         assert 128 <= bk <= min(1024, round_up(skv, 128))
-        assert (fa.block_vmem_bytes(direction, bq, bk, d, itemsize)
+        assert (fa.block_vmem_bytes(direction, bq, bk, d, itemsize, d_v)
                 <= fa._VMEM_BLOCK_BUDGET < fa._VMEM_LIMIT_BYTES)
         # as the kernels use them: a multiple of 8, no longer than the
         # 8-padded sequence, tiling the padded length exactly
@@ -212,7 +243,7 @@ class TestFlashBackwardKernels:
         assert sq <= sq_p < sq + cq and skv <= skv_p < skv + ck
         # the registry's default IS the rule
         from bigdl_tpu.ops import autotune
-        if sq == skv:
+        if sq == skv and d_v is None:
             dtype = "bfloat16" if itemsize == 2 else "float32"
             spec = autotune.REGISTRY[f"flash_attention_{direction}"]
             assert spec.defaults_for((1, 1, sq, d, dtype)) == got
@@ -227,6 +258,15 @@ class TestFlashBackwardKernels:
         fa = importlib.import_module("bigdl_tpu.ops.flash_attention")
 
         area = lambda b: b["block_q"] * b["block_k"]
+        # the benchmark's cells: 1024 x 1024 both ways, at GLM's equal and
+        # at Xing's unequal widths; narrower values never shrink a block
+        for widths in ((256,), (192, 128)):
+            for direction in ("fwd", "bwd"):
+                assert fa.default_blocks(direction, 4096, 4096, widths[0], 2,
+                                         *widths[1:]) == {
+                    "block_q": 1024, "block_k": 1024}
+        assert area(fa.default_blocks("bwd", 8192, 8192, 512, 4, 64)) >= area(
+            fa.default_blocks("bwd", 8192, 8192, 512, 4))
         small = fa.default_blocks("bwd", 8192, 8192, 64, 2)
         big = fa.default_blocks("bwd", 8192, 8192, 512, 4)
         assert area(big) < area(small)
