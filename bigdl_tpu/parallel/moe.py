@@ -23,12 +23,13 @@ stands the **held-share, no-drop layer** a 2026 expert model trains with:
 correction bias, weights from the scores alone), ``held_experts_apply`` (the
 part of the result the experts ``held=(first, count)`` give, for every
 token routed to them, whatever the imbalance: sort the (token, choice)
-pairs by expert, one grouped matrix product per projection, un-sort) and
-the module ``HeldMoE`` (router + held experts + shared expert).  A chip of
-an expert-parallel job holds ``count`` of the experts and computes its own
-part; what the absent experts add arrives by the exchange between chips,
-which this file does not have yet — on one chip the layer runs without it
-and nothing stands in for it (docs/parallelism.md §Held-share expert layer).
+pairs by expert, one grouped matrix product per projection over the held
+pairs' rows, sum them into their tokens) and the module ``HeldMoE`` (router
++ held experts + shared expert).  A chip of an expert-parallel job holds
+``count`` of the experts and computes its own part; what the absent experts
+add arrives by the exchange between chips, which this file does not have
+yet — on one chip the layer runs without it and nothing stands in for it
+(docs/parallelism.md §Held-share expert layer).
 """
 
 from typing import Callable, NamedTuple, Optional, Tuple
@@ -227,22 +228,32 @@ def route_sigmoid_topk(x, w_router, bias, k: int, scale: float = 1.0,
 
 
 @jax.custom_vjp
-def _permute(x, perm, inv):
-    """``x[perm]`` for a permutation whose inverse is at hand: the backward
-    is the gather ``g[inv]``, where autodiff would emit a scatter-add."""
-    return x[perm]
+def _rows_of(x, tok, pos, valid):
+    """``x[tok]``: the rows of ``x`` (T, d) a buffer of C sorted rows works
+    on.  ``pos`` (T, k) says where in the buffer each of a token's pairs
+    sits and ``valid`` (T, k) which of them are held: with them the
+    backward is :func:`_tokens_sum` of the cotangent, k gathers a token,
+    where autodiff would emit a scatter-add of C rows."""
+    return x[tok]
 
 
-def _permute_fwd(x, perm, inv):
-    return x[perm], (perm, inv)
+@jax.custom_vjp
+def _tokens_sum(buf, tok, pos, valid):
+    """The transpose of :func:`_rows_of`: ``buf`` (C, d) summed into its
+    tokens (T, d), a token's at most k rows added in float32.  Rows no
+    valid pair points at are never read into the sum.  The backward is
+    ``g[tok]``."""
+    picked = jnp.where(valid[..., None], buf[pos], 0)
+    return jnp.sum(picked, axis=1, dtype=jnp.float32).astype(buf.dtype)
 
 
-def _permute_bwd(res, g):
-    perm, inv = res
-    return g[inv], None, None
-
-
-_permute.defvjp(_permute_fwd, _permute_bwd)
+_rows_of.defvjp(
+    lambda x, tok, pos, valid: (x[tok], (tok, pos, valid)),
+    lambda res, g: (_tokens_sum(g, *res), None, None, None))
+_tokens_sum.defvjp(
+    lambda buf, tok, pos, valid: (_tokens_sum(buf, tok, pos, valid),
+                                  (tok, pos, valid)),
+    lambda res, g: (_rows_of(g, *res), None, None, None))
 
 
 def swiglu_init(rng, d: int, hidden: int):
@@ -267,46 +278,56 @@ def swiglu(x, p):
                       preferred_element_type=jnp.float32).astype(x.dtype)
 
 
-def held_experts_apply(params, x, idx, weights, held: Tuple[int, int]):
-    """The held experts' part of a routed layer, dropping nothing.
+# rows of a grouped product's buffer come in multiples of this: the MXU's
+# side on every TPU so far, and a whole number of sublane tiles at 4 bytes (8
+# rows) and at 2 (16), so a (C, d) buffer is laid out without padding
+_ROW_TILE = 128
 
-    x: (T, d); idx, weights: (T, k) from the router, over ALL experts;
-    ``held=(first, count)``: this shard holds experts ``first .. first +
-    count - 1`` as ``params`` {w_gate (count, d, h), w_up (count, d, h),
-    w_down (count, h, d)}.  Returns ``(y, rows, dropped)``: ``y`` (T, d) =
-    sum over a token's chosen AND held experts of weight * Expert(x);
-    ``rows`` (count,) int32, the rows each held expert was given;
-    ``dropped`` int32, the held pairs whose row came back from the grouped
-    products unserved (all zero, or not finite): read off the result, not
-    off the sizes, so a product that skips rows shows here.  (A token whose
-    input row is exactly zero would read as unserved too; a normed stream
-    has none.)
 
-    The T*k (token, choice) pairs are sorted by held expert (pairs of
-    absent experts last), the tokens' rows gathered in that order, and each
-    projection is ONE grouped matrix product (``jax.lax.ragged_dot``) whose
-    group sizes are ``rows``: an expert computes exactly the rows routed to
-    it — all T of them if every token chooses it — and the tail of absent
-    pairs is never multiplied.  Buffers hold all T*k pairs, the worst case,
-    so no imbalance overflows them."""
+def held_capacity(pairs: int, count: int, num_experts: int) -> int:
+    """Rows of the buffers the held experts' part is computed in: twice
+    what uniform routing sends to ``count`` of ``num_experts`` experts out
+    of ``pairs`` (token, choice) pairs, rounded up to the row tile, and
+    never more than ``pairs``, which a shard that holds every expert gets.
+    A rule on the shapes: nothing sets it."""
+    uniform2 = -(-2 * pairs * count // num_experts)
+    return min(pairs, -(-uniform2 // _ROW_TILE) * _ROW_TILE)
+
+
+def _sort_pairs(idx, held: Tuple[int, int]):
+    """The T*k (token, choice) pairs by held expert, pairs of absent
+    experts last.  Returns ``(order, inv, rows)``: ``order`` (T*k,) int32,
+    the pair at each sorted place; ``inv`` its inverse, the place of each
+    pair; ``rows`` (count,) int32, the pairs of each held expert."""
     first, count = held
-    t, k = idx.shape
     local = (idx >= first) & (idx < first + count)
-    slot = jnp.where(local, idx - first, count).reshape(-1)        # (T*k,)
+    slot = jnp.where(local, idx - first, count).reshape(-1)
     order = jnp.argsort(slot, stable=True).astype(jnp.int32)
     inv = jnp.zeros_like(order).at[order].set(
-        jnp.arange(t * k, dtype=jnp.int32))
+        jnp.arange(idx.size, dtype=jnp.int32))
     rows = jnp.sum(slot[:, None] == jnp.arange(count)[None, :], axis=0,
                    dtype=jnp.int32)
-    pairs = jnp.broadcast_to(cast_compute(x)[:, None, :],
-                             (t, k, x.shape[-1])).reshape(t * k, -1)
+    return order, inv, rows
+
+
+def _held_rows_apply(params, x, weights, order, inv, rows, cap: int):
+    """``held_experts_apply`` after the sort, in buffers of ``cap`` rows:
+    right whenever the held pairs (``sum(rows)``) are at most ``cap``.
+    Returns ``(y, dropped)``."""
+    t, k = weights.shape
+    n_local = jnp.sum(rows)
+    head = order[:cap]                      # the pairs the buffer's rows are
+    tok = head // k                         # and their tokens
+    pos = jnp.minimum(inv, cap - 1).reshape(t, k)
+    valid = (inv < n_local).reshape(t, k)
     # rows past the held pairs belong to no group.  The grouped product
     # never writes them, forward or backward (on the TPU they hold whatever
     # the buffer held), so they are cut off on both sides: ``ys`` before it
     # reaches the sum, and ``xs`` so that its cotangent's tail is dropped
-    # before it is un-sorted into the tokens' gradient
-    held_row = (jnp.arange(t * k) < jnp.sum(rows))[:, None]
-    xs = jnp.where(held_row, _permute(pairs, order, inv), 0)
+    # before it is summed into the tokens' gradient
+    held_row = (jnp.arange(cap) < n_local)[:, None]
+    xs = jnp.where(held_row,
+                   _rows_of(cast_compute(x), tok, pos, valid), 0)
 
     def grouped(a, w):
         return jax.lax.ragged_dot(a, cast_compute(w), rows,
@@ -314,13 +335,65 @@ def held_experts_apply(params, x, idx, weights, held: Tuple[int, int]):
 
     hid = jax.nn.silu(grouped(xs, params["w_gate"])) \
         * grouped(xs, params["w_up"])
-    ys = grouped(cast_compute(hid), params["w_down"])    # (T*k, d) float32
+    ys = grouped(cast_compute(hid), params["w_down"])      # (cap, d) float32
     ys = jnp.where(held_row, ys, 0.0)
-    y = _permute(ys, inv, order).reshape(t, k, -1)
-    served = jnp.all(jnp.isfinite(y), -1) & jnp.any(y != 0, -1)
-    dropped = jnp.sum(local & ~served, dtype=jnp.int32)
-    y = jnp.sum(y * jnp.where(local, weights, 0.0)[..., None], axis=1)
-    return y.astype(x.dtype), rows, dropped
+    served = jnp.all(jnp.isfinite(ys), -1) & jnp.any(ys != 0, -1)
+    dropped = n_local - jnp.sum(served, dtype=jnp.int32)
+    w = _rows_of(weights.reshape(-1, 1), head, pos.reshape(-1, 1),
+                 valid.reshape(-1, 1))
+    y = _tokens_sum(ys * w, tok, pos, valid)
+    return y.astype(x.dtype), dropped
+
+
+def held_experts_apply(params, x, idx, weights, held: Tuple[int, int],
+                       num_experts: int):
+    """The held experts' part of a routed layer, dropping nothing.
+
+    x: (T, d); idx, weights: (T, k) from the router, over ALL
+    ``num_experts`` experts; ``held=(first, count)``: this shard holds
+    experts ``first .. first + count - 1`` as ``params`` {w_gate (count, d,
+    h), w_up (count, d, h), w_down (count, h, d)}.  Returns ``(y, rows,
+    dropped, short)``: ``y`` (T, d) = sum over a token's chosen AND held
+    experts of weight * Expert(x); ``rows`` (count,) int32, the rows each
+    held expert was given; ``dropped`` int32, the held pairs whose row came
+    back from the grouped products unserved (all zero, or not finite): read
+    off the result, not off the sizes, so a product that skips rows shows
+    here (a token whose input row is exactly zero would read as unserved
+    too; a normed stream has none); ``short`` bool, whether the held pairs
+    fitted the short buffers.
+
+    The T*k (token, choice) pairs are sorted by held expert (pairs of
+    absent experts last); the first C of them are the rows of the buffers,
+    each row its token's input; each projection is ONE grouped matrix
+    product (``jax.lax.ragged_dot``) whose group sizes are ``rows``: an
+    expert computes exactly the rows routed to it and the tail of absent
+    pairs is never multiplied; the rows, times their router weights, are
+    summed into their tokens.  C is :func:`held_capacity`: twice the held
+    experts' share under uniform routing.  A step that routes more than C
+    pairs here (all T*k of them, if every token chooses held experts
+    only) runs the same computation in buffers of T*k rows instead, behind
+    a ``jax.lax.cond``, so no imbalance overflows anything; a shard that
+    holds every expert has C = T*k and no conditional."""
+    order, inv, rows = _sort_pairs(idx, held)
+    cap = held_capacity(idx.size, held[1], num_experts)
+    short = jnp.sum(rows) <= cap
+
+    def path(size):
+        return lambda p, x, w: _held_rows_apply(p, x, w, order, inv, rows,
+                                                size)
+
+    if cap == idx.size:
+        y, dropped = path(cap)(params, x, weights)
+    else:
+        # each path keeps nothing for its backward but its inputs and
+        # computes itself again there.  What a branch of a differentiated
+        # ``cond`` keeps, the other has to hand over too, filled with zeros,
+        # beside a copy of the weights: 2.2 GB of temporaries for 0.64 at
+        # the Xing cell's shape, and a slower step (PERF.md §6, PR 33)
+        y, dropped = jax.lax.cond(short, jax.checkpoint(path(cap)),
+                                  jax.checkpoint(path(idx.size)),
+                                  params, x, weights)
+    return y, rows, dropped, short
 
 
 class HeldMoE(Module):
@@ -340,11 +413,14 @@ class HeldMoE(Module):
     step and are booked into the metric registry at the driver's log point:
     counters ``moe.routed_pairs`` (tokens x k), ``moe.local_pairs`` (those
     whose expert is held), ``moe.dropped_pairs`` (held pairs whose expert
-    output came back all zero or not finite: must stay 0), and the
-    histogram ``moe.load_imbalance`` (rows of the busiest held expert over
-    the mean rows of a held expert)."""
+    output came back all zero or not finite: must stay 0),
+    ``moe.applies`` (one a forward pass) and ``moe.short_applies`` (those
+    whose held pairs fitted the buffers of :func:`held_capacity` rows), and
+    the histogram ``moe.load_imbalance`` (rows of the busiest held expert
+    over the mean rows of a held expert)."""
 
-    COUNTERS = ("moe.routed_pairs", "moe.local_pairs", "moe.dropped_pairs")
+    COUNTERS = ("moe.routed_pairs", "moe.local_pairs", "moe.dropped_pairs",
+                "moe.applies", "moe.short_applies")
     MEANS = ("moe.load_imbalance",)
 
     def __init__(self, num_experts: int, hidden: int, k: int, *,
@@ -387,8 +463,9 @@ class HeldMoE(Module):
                 flat, params["w_router"], state["router_bias"], self.k,
                 self.scale, self.norm_topk)
         with jax.named_scope("moe/experts"):
-            y, rows, dropped = held_experts_apply(
-                params["experts"], flat, idx, w, self.held)
+            y, rows, dropped, short = held_experts_apply(
+                params["experts"], flat, idx, w, self.held,
+                self.num_experts)
         if self.shared_hidden:
             with jax.named_scope("moe/shared"):
                 y = y + swiglu(flat, params["shared"])
@@ -398,7 +475,8 @@ class HeldMoE(Module):
             metrics = bump_state_metrics(
                 state["metrics"],
                 {"moe.routed_pairs": idx.size, "moe.local_pairs": n_local,
-                 "moe.dropped_pairs": dropped},
+                 "moe.dropped_pairs": dropped, "moe.applies": 1,
+                 "moe.short_applies": short},
                 {"moe.load_imbalance": jnp.max(rows) / mean_rows})
         return y.reshape(shape), {"router_bias": state["router_bias"],
                                   "metrics": metrics}

@@ -8,6 +8,12 @@ One process drives every visible chip through the entry points a user calls:
 
 - ``kernels``        every Pallas kernel a TPU selector can reach, compiled by
                      Mosaic at the LM's width and matched to its jnp reference
+- ``expert_layer``   ``HeldMoE`` at the Xing cell's shape (4,096 tokens, d 3,584,
+                     8 of 64 experts held, 4 a token) on both of its paths:
+                     the short buffers its routing fits, and the whole-size
+                     ones a routing collapsed onto this shard (forced by a
+                     bias) overflows into; forward and every gradient against
+                     the plain float32 loop over the held experts
 - ``train_resnet50`` ``Optimizer(...).optimize()`` on ResNet-50 (s2d stem), 224x224
 - ``train_lm``       the same path on the 12-layer d768 ``Transformer(mode="lm")``
                      at seq 1024 (flash-attention forward + Pallas backward
@@ -68,6 +74,7 @@ FULL = dict(
     kern=dict(slots=8, heads=12, hd=64, page=16, nb=64, chunk=5,
               flash_b=2, flash_s=1024, ffn=(768, 3072), bs_block=(128, 128),
               ln_rows=4096, mm=(256, 768, 3072)),
+    moe=dict(tokens=4096, d=3584, hidden=1024, experts=64, held=(8, 8), k=4),
 )
 TINY = dict(
     resnet=dict(hw=32, classes=10, batch_per_chip=8, steps=8),
@@ -78,6 +85,7 @@ TINY = dict(
     kern=dict(slots=2, heads=2, hd=16, page=4, nb=2, chunk=3,
               flash_b=1, flash_s=32, ffn=(32, 64), bs_block=(16, 16),
               ln_rows=16, mm=(32, 128, 128)),
+    moe=dict(tokens=256, d=32, hidden=16, experts=16, held=(4, 2), k=2),
 )
 
 
@@ -112,6 +120,17 @@ class Smoke:
             print(detail, flush=True)
         gc.collect()
 
+    def close(self, phase, what, got, want, tol) -> bool:
+        """max |got - want| / max |want| within ``tol``, and said so."""
+        got = np.asarray(got, np.float32)
+        want = np.asarray(want, np.float32)
+        err = float(np.max(np.abs(got - want))
+                    / max(float(np.max(np.abs(want))), 1e-6))
+        fine = bool(np.isfinite(got).all()) and err <= tol
+        self.say(phase, f"{what} rel_err={err:.2e} tol={tol} "
+                        f"{'ok' if fine else 'MISMATCH'}")
+        return fine
+
     # -- phase: kernels ------------------------------------------------------
     def kernels(self):
         import jax
@@ -141,14 +160,7 @@ class Smoke:
         bad = []
 
         def check(name, got, want, tol):
-            got = np.asarray(got, np.float32)
-            want = np.asarray(want, np.float32)
-            err = float(np.max(np.abs(got - want))
-                        / max(float(np.max(np.abs(want))), 1e-6))
-            fine = bool(np.isfinite(got).all()) and err <= tol
-            self.say("kernels", f"kernel={name} rel_err={err:.2e} tol={tol} "
-                                f"{'ok' if fine else 'MISMATCH'}")
-            if not fine:
+            if not self.close("kernels", f"kernel={name}", got, want, tol):
                 bad.append(name)
 
         def ref(fn, *a):
@@ -276,6 +288,69 @@ class Smoke:
                             f"tiles={json.dumps(tiles)}")
         if bad:
             raise AssertionError(f"kernels off their reference: {bad}")
+
+    # -- phase: expert layer -------------------------------------------------
+    def expert_layer(self):
+        """The grouped product leaves the rows past its groups unwritten on
+        the TPU, forward and backward (PERF.md, PR 28), and which rows those
+        are differs between the short buffers and the whole-size ones: both
+        are held to the plain loop here, where only the chip can show it."""
+        import jax
+        import jax.numpy as jnp
+
+        from bigdl_tpu.parallel.moe import (HeldMoE, held_capacity,
+                                            route_sigmoid_topk)
+
+        z = self.sz["moe"]
+        t, d, k, (first, count) = z["tokens"], z["d"], z["k"], z["held"]
+        moe = HeldMoE(z["experts"], z["hidden"], k, held=z["held"], scale=2.0)
+        x = jax.random.normal(jax.random.PRNGKey(0), (1, t, d))
+        cot = jax.random.normal(jax.random.PRNGKey(1), (t, d))
+        v = moe.init(jax.random.PRNGKey(2), x)
+        cap = held_capacity(t * k, count, z["experts"])
+        assert cap < t * k, "a shape with one path shows nothing here"
+
+        def ours(p, x, bias):
+            y, st = moe.forward(p, dict(v["state"], router_bias=bias), x)
+            return jnp.sum(y[0] * cot), (y[0], st["metrics"]["counters"])
+
+        def plain(p, x, bias):
+            idx, w = route_sigmoid_topk(x[0], p["w_router"], bias, k, 2.0)
+            y = jnp.zeros_like(x[0])
+            for e in range(count):
+                w_e = jnp.sum(jnp.where(idx == first + e, w, 0.0), -1)
+                g, u, dn = (p["experts"][n][e]
+                            for n in ("w_gate", "w_up", "w_down"))
+                y = y + w_e[:, None] * ((jax.nn.silu(x[0] @ g) * (x[0] @ u))
+                                        @ dn)
+            return jnp.sum(y * cot), (y, None)
+
+        grad = lambda f: jax.jit(jax.grad(f, (0, 1), has_aux=True))
+        bad = []
+        # every token's k choices on held experts: T*k pairs, over any C
+        collapsed = jnp.zeros((z["experts"],)).at[
+            first:first + count].set(10.0)
+        for path, bias in (("short", jnp.zeros((z["experts"],))),
+                           ("whole", collapsed)):
+            got, (y, m) = grad(ours)(v["params"], x, bias)
+            with jax.default_matmul_precision("highest"):
+                want, (y_ref, _) = grad(plain)(v["params"], x, bias)
+            m = {n: int(c) for n, c in m.items()}
+            took = "short" if m["moe.short_applies"] else "whole"
+            self.say("expert_layer", f"path={path} took={took} rows={cap} "
+                                     f"of {t * k} counters={json.dumps(m)}")
+            if took != path or m["moe.dropped_pairs"] or m["moe.applies"] != 1:
+                bad.append(f"{path}: counters")
+            named = [("y", y, y_ref, TOL_MXU)] + [
+                (jax.tree_util.keystr(kp), a, b, TOL_MXU_BWD)
+                for (kp, a), b in zip(
+                    jax.tree_util.tree_flatten_with_path(got)[0],
+                    jax.tree_util.tree_leaves(want))]
+            bad += [f"{path}: {name}" for name, a, b, tol in named
+                    if not self.close("expert_layer",
+                                      f"path={path} leaf={name}", a, b, tol)]
+        if bad:
+            raise AssertionError(f"expert layer off its reference: {bad}")
 
     # -- phases: train -------------------------------------------------------
     def _train(self, phase, model, x, y, method, steps, batch):
@@ -553,7 +628,8 @@ def main(argv=None) -> int:
     smoke.say("setup", f"jax={jax.__version__} compile_cache={cache_dir} "
                        f"native_lib_in_use={native.available()}")
     t0 = time.perf_counter()
-    for phase in ("kernels", "train_resnet50", "train_lm", "serve_lm"):
+    for phase in ("kernels", "expert_layer", "train_resnet50", "train_lm",
+                  "serve_lm"):
         smoke.run(phase, getattr(smoke, phase))
     m = global_metrics()
     compile_hist = m.hists.get("train.compile_time_s")

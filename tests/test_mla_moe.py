@@ -12,7 +12,8 @@ import jax.numpy as jnp
 from benchmark import harness
 from bigdl_tpu.models.mla_moe_lm import MLAMoEConfig, MLAMoELM
 from bigdl_tpu.nn.attention import LatentAttention, rope
-from bigdl_tpu.parallel.moe import (HeldMoE, held_experts_apply,
+from bigdl_tpu.parallel.moe import (HeldMoE, _held_rows_apply, _sort_pairs,
+                                    held_capacity, held_experts_apply,
                                     route_sigmoid_topk)
 
 fam = harness.load_module("families", "mla_moe_lm")
@@ -151,14 +152,30 @@ def _ref_moe(c, p, x):
     return y
 
 
-@pytest.mark.parametrize("held", [(0, 8), (2, 4)])
-def test_expert_layer_forward_and_gradients(held):
-    c = config(held_experts=held)
+# a share whose short buffers are shorter than T*k: 2 of 16 experts, 2 a
+# token, 128 tokens: 256 pairs, 32 of them held under uniform routing, C 128
+SHARE = dict(n_routed_experts=16, held_experts=(4, 2))
+SHARE_T = 64
+
+
+def _counters(state):
+    return {k: int(v) for k, v in state["metrics"]["counters"].items()}
+
+
+@pytest.mark.parametrize("experts,held,length",
+                         [(8, (0, 8), T), (8, (2, 4), T),
+                          (16, (4, 2), SHARE_T)])
+def test_expert_layer_forward_and_gradients(experts, held, length):
+    c = config(n_routed_experts=experts, held_experts=held)
     moe = _moe(c, held)
-    x = jax.random.normal(jax.random.PRNGKey(5), (2, T, c.hidden_size))
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, length, c.hidden_size))
     v = moe.init(jax.random.PRNGKey(6), x)
     p, st = v["params"], v["state"]
     flat = x.reshape(-1, c.hidden_size)
+    # the third case is held to the reference in buffers a quarter the size
+    pairs = flat.shape[0] * c.num_experts_per_tok
+    assert (held_capacity(pairs, held[1], experts) < pairs) == (experts == 16)
+    assert _counters(moe.forward(p, st, x)[1])["moe.short_applies"] == 1
 
     def ours(p, x):
         return moe.forward(p, st, x)[0].reshape(flat.shape)
@@ -171,6 +188,118 @@ def test_expert_layer_forward_and_gradients(held):
     for a, b in zip(jax.tree_util.tree_leaves(g_ours),
                     jax.tree_util.tree_leaves(g_ref)):
         close(a, b)
+
+
+@pytest.mark.parametrize("pairs,count,experts,want", [
+    (4096 * 4, 8, 64, 4096),         # the Xing cell: a quarter of 16,384
+    (8192 * 4, 8, 64, 8192),         # the GLM cell: a quarter of 32,768
+    (64, 8, 8, 64), (64, 4, 8, 64),  # every expert, or half: all the pairs
+    (256, 2, 16, 128),               # 64 rounded up to the row tile
+    (2048, 3, 64, 256),              # 192 rounded up
+    (100, 1, 64, 100),               # never more than the pairs
+])
+def test_capacity_is_twice_the_uniform_share(pairs, count, experts, want):
+    assert held_capacity(pairs, count, experts) == want
+
+
+def _dense_rebuild(params, x, idx, w, held):
+    """Every held expert on every token, weighted where the token chose
+    it."""
+    want = jnp.zeros_like(x)
+    for e in range(held[0], held[0] + held[1]):
+        w_e = jnp.sum(jnp.where(idx == e, w, 0.0), -1)
+        want = want + w_e[:, None] * fam._swiglu(x, params, False,
+                                                 e - held[0])
+    return want
+
+
+def test_a_step_that_overflows_the_short_buffers_takes_the_whole_path():
+    """Every token's first choice is one held expert: 192 pairs or more
+    where the short buffers have 128 rows.  All are computed, none is
+    dropped, and the counters say which path ran."""
+    c = config(**SHARE)
+    moe = _moe(c, (4, 2), shared=False)
+    x = jax.random.normal(jax.random.PRNGKey(8), (1, 192, c.hidden_size))
+    v = moe.init(jax.random.PRNGKey(9), x)
+    assert held_capacity(192 * 2, 2, 16) == 128
+    _, st = moe.forward(v["params"], v["state"], x)
+    assert _counters(st)["moe.applies"] == 1
+    assert _counters(st)["moe.short_applies"] == 1
+    bias = jnp.zeros((16,)).at[5].set(10.0)
+    y, st = moe.forward(v["params"], dict(st, router_bias=bias), x)
+    idx, w = route_sigmoid_topk(x[0], v["params"]["w_router"], bias, 2, 1.8)
+    close(y[0], _dense_rebuild(v["params"]["experts"], x[0], idx, w, (4, 2)))
+    m = _counters(st)
+    assert m["moe.applies"] == 2 and m["moe.short_applies"] == 1
+    assert m["moe.local_pairs"] > 192 and m["moe.dropped_pairs"] == 0
+
+
+@pytest.mark.parametrize("n_local", [127, 128, 129, 256])
+def test_the_boundary_between_the_paths(n_local):
+    """128 tokens, 2 choices, 2 of 16 experts held: C = 128.  ``n_local``
+    pairs are routed here: 128 still fit, 129 do not, and either way the
+    result is every pair's."""
+    held, k, tokens = (4, 2), 2, 128
+    c = config(**SHARE)
+    x = jax.random.normal(jax.random.PRNGKey(30), (tokens, c.hidden_size))
+    p = _moe(c, held, shared=False).init(
+        jax.random.PRNGKey(31), x[None])["params"]["experts"]
+    pair = np.arange(tokens * k).reshape(tokens, k)
+    # held pairs alternate between the two held experts, the rest go to
+    # experts 0 and 1
+    idx = jnp.asarray(np.where(pair < n_local, 4 + pair % 2, pair % 2),
+                      jnp.int32)
+    w = jax.random.uniform(jax.random.PRNGKey(32), (tokens, k)) + 0.5
+    y, rows, dropped, short = held_experts_apply(p, x, idx, w, held, 16)
+    assert int(rows.sum()) == n_local and int(dropped) == 0
+    assert bool(short) == (n_local <= 128)
+    close(y, _dense_rebuild(p, x, idx, w, held))
+
+
+def test_both_paths_give_the_same_result_and_gradients():
+    """The two branches of the ``cond``, called directly on one input that
+    fits both."""
+    held, k = (4, 2), 2
+    c = config(**SHARE)
+    x = jax.random.normal(jax.random.PRNGKey(33), (128, c.hidden_size))
+    v = _moe(c, held, shared=False).init(jax.random.PRNGKey(34), x[None])
+    idx, w = route_sigmoid_topk(x, v["params"]["w_router"],
+                                v["state"]["router_bias"], k, 1.8)
+    order, inv, rows = _sort_pairs(idx, held)
+    cap = held_capacity(idx.size, held[1], 16)
+    assert 0 < int(rows.sum()) <= cap < idx.size
+    cot = jax.random.normal(jax.random.PRNGKey(35), x.shape)
+
+    def path(size):
+        def f(p, x, w):
+            y, dropped = _held_rows_apply(p, x, w, order, inv, rows, size)
+            return jnp.sum(y * cot), (y, dropped)
+        return jax.value_and_grad(f, (0, 1, 2), has_aux=True)(
+            v["params"]["experts"], x, w)
+
+    (_, (y_s, dropped_s)), g_s = path(cap)
+    (_, (y_w, dropped_w)), g_w = path(idx.size)
+    assert int(dropped_s) == int(dropped_w) == 0
+    close(y_s, y_w, 1e-6)
+    for a, b in zip(jax.tree_util.tree_leaves(g_s),
+                    jax.tree_util.tree_leaves(g_w)):
+        assert float(jnp.abs(b).max()) > 0
+        close(a, b, 1e-6)
+
+
+@pytest.mark.parametrize("experts,held,conds", [(8, None, 0), (8, (2, 4), 0),
+                                                (16, (4, 2), 1)])
+def test_a_shard_that_holds_every_expert_has_no_conditional(experts, held,
+                                                            conds):
+    """C = T*k wherever twice the held share is all the pairs: one path,
+    and the program has no ``cond`` to choose with."""
+    c = config(n_routed_experts=experts)
+    moe = _moe(c, held)
+    x = jax.random.normal(jax.random.PRNGKey(36), (2, SHARE_T, c.hidden_size))
+    v = moe.init(jax.random.PRNGKey(37), x)
+    jaxpr = str(jax.make_jaxpr(lambda p: moe.forward(p, v["state"], x)[0])(
+        v["params"]))
+    assert jaxpr.count("cond[") == conds
 
 
 def test_no_pair_dropped_when_every_token_chooses_one_expert():
@@ -186,33 +315,48 @@ def test_no_pair_dropped_when_every_token_chooses_one_expert():
     flat = x[0]
     idx, w = route_sigmoid_topk(flat, v["params"]["w_router"], bias, 2, 1.8)
     assert (np.asarray(idx) == 5).sum() == T
-    _, rows, dropped = held_experts_apply(v["params"]["experts"], flat, idx,
-                                          w, (4, 4))
+    _, rows, dropped, _ = held_experts_apply(v["params"]["experts"], flat,
+                                             idx, w, (4, 4), 8)
     assert int(rows[1]) == T and int(dropped) == 0
-    # every token's expert-5 term is in the result: rebuild it densely
-    want = jnp.zeros_like(flat)
-    for e in range(4, 8):
-        w_e = jnp.sum(jnp.where(idx == e, w, 0.0), -1)
-        want = want + w_e[:, None] * fam._swiglu(
-            flat, v["params"]["experts"], False, e - 4)
-    close(y[0], want)
+    # every token's expert-5 term is in the result
+    close(y[0], _dense_rebuild(v["params"]["experts"], flat, idx, w, (4, 4)))
     m = new["metrics"]["counters"]
     assert int(m["moe.routed_pairs"]) == 2 * T
     assert int(m["moe.local_pairs"]) == int(rows.sum()) >= T
     assert int(m["moe.dropped_pairs"]) == 0
 
 
-def test_rows_a_grouped_product_skips_are_counted_as_dropped(monkeypatch):
+# the layer on each of its paths: a share whose buffers hold all pairs (no
+# conditional), one whose routing fits the short buffers, and the same with a
+# bias that makes its last held expert every token's first choice: more than
+# C pairs and fewer than all, so the whole-size buffers have a tail too
+PATHS = {"one_path": (8, (2, 4), T, 0.0), "short": (16, (4, 2), SHARE_T, 0.0),
+         "whole": (16, (4, 2), SHARE_T, 10.0)}
+
+
+def _on_path(path, seed):
+    """(moe, params, state, x) for a case of ``PATHS``."""
+    experts, held, length, push = PATHS[path]
+    c = config(n_routed_experts=experts, held_experts=held)
+    moe = _moe(c, held, shared=False)
+    x = jax.random.normal(jax.random.PRNGKey(seed), (2, length, c.hidden_size))
+    v = moe.init(jax.random.PRNGKey(seed + 1), x)
+    bias = jnp.zeros((experts,)).at[held[0] + held[1] - 1].set(push)
+    st = dict(v["state"], router_bias=bias)
+    m = _counters(moe.forward(v["params"], st, x)[1])
+    assert m["moe.short_applies"] == (path != "whole")
+    assert m["moe.dropped_pairs"] == 0
+    assert m["moe.local_pairs"] < m["moe.routed_pairs"]
+    return moe, v["params"], st, x
+
+
+@pytest.mark.parametrize("path", list(PATHS))
+def test_rows_a_grouped_product_skips_are_counted_as_dropped(monkeypatch, path):
     """``moe.dropped_pairs`` is read off what the grouped products gave
     back: one that leaves the last held expert's rows unserved (as
     ``ragged_dot`` left rows unwritten on the TPU before they were cut off)
     shows as that expert's rows."""
-    c = config(held_experts=(2, 4))
-    moe = _moe(c, (2, 4), shared=False)
-    x = jax.random.normal(jax.random.PRNGKey(20), (1, T, c.hidden_size))
-    v = moe.init(jax.random.PRNGKey(21), x)
-    _, st = moe.forward(v["params"], v["state"], x)
-    assert int(st["metrics"]["counters"]["moe.dropped_pairs"]) == 0
+    moe, p, st, x = _on_path(path, 20)
     real = jax.lax.ragged_dot
 
     def skips_last_group(a, w, sizes, **kw):
@@ -222,11 +366,15 @@ def test_rows_a_grouped_product_skips_are_counted_as_dropped(monkeypatch):
                          real(a, w, sizes, **kw))
 
     monkeypatch.setattr(jax.lax, "ragged_dot", skips_last_group)
-    idx, w = route_sigmoid_topk(x[0], v["params"]["w_router"],
-                                v["state"]["router_bias"], 2, 1.8)
-    _, rows, dropped = held_experts_apply(v["params"]["experts"], x[0], idx,
-                                          w, (2, 4))
+    flat = x.reshape(-1, x.shape[-1])
+    idx, w = route_sigmoid_topk(flat, p["w_router"], st["router_bias"], 2,
+                                1.8)
+    _, rows, dropped, short = held_experts_apply(
+        p["experts"], flat, idx, w, moe.held, moe.num_experts)
+    assert bool(short) == (path != "whole")
     assert int(rows[-1]) > 0 and int(dropped) == int(rows[-1])
+    m = _counters(moe.forward(p, st, x)[1])
+    assert m["moe.dropped_pairs"] == int(rows[-1])
 
 
 def test_shares_add_up_to_the_uncut_layer():
@@ -434,20 +582,18 @@ def test_optimize_first_loss_is_the_reference_and_adam_lowers_it():
     assert delta("moe.routed_pairs") == routed
 
 
-def test_rows_of_no_group_may_hold_anything(monkeypatch):
+@pytest.mark.parametrize("path", list(PATHS))
+def test_rows_of_no_group_may_hold_anything(monkeypatch, path):
     """On the TPU the grouped product leaves the rows past its groups
     unwritten, in the forward pass and in the gradient alike.  Fill them
     with NaN here: neither the result nor any gradient may see it."""
-    c = config(held_experts=(2, 4))
-    moe = _moe(c, (2, 4), shared=False)
-    x = jax.random.normal(jax.random.PRNGKey(5), (2, T, c.hidden_size))
-    v = moe.init(jax.random.PRNGKey(6), x)
+    moe, params, st, x = _on_path(path, 5)
     cot = jax.random.normal(jax.random.PRNGKey(7), x.shape)
 
     def loss(p, x):
-        return jnp.sum(moe.forward(p, v["state"], x)[0] * cot)
+        return jnp.sum(moe.forward(p, st, x)[0] * cot)
 
-    clean = jax.value_and_grad(loss, (0, 1))(v["params"], x)
+    clean = jax.value_and_grad(loss, (0, 1))(params, x)
     real = jax.lax.ragged_dot
 
     def tail_nan(a, sizes):
@@ -473,7 +619,7 @@ def test_rows_of_no_group_may_hold_anything(monkeypatch):
     monkeypatch.setattr(
         jax.lax, "ragged_dot",
         lambda a, w, sizes, preferred_element_type=None: dirty(a, w, sizes))
-    got = jax.value_and_grad(loss, (0, 1))(v["params"], x)
+    got = jax.value_and_grad(loss, (0, 1))(params, x)
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(clean)):
         assert bool(jnp.isfinite(a).all())

@@ -193,3 +193,42 @@ def test_grouped_expert_product_at_the_cell_sizes(v5e):
         _compile(jax.grad(loss, argnums=(0, 1)),
                  v5e((32768, 2048), jnp.bfloat16),
                  v5e((8, 2048, 1536), jnp.bfloat16), v5e((8,), jnp.int32))
+
+
+def test_expert_layer_two_paths_keep_no_more_than_one(v5e):
+    """The held-share expert layer at the Xing cell's shape, gradient under
+    ``jax.checkpoint`` as the model runs it: the program with the ``cond``
+    between buffers of C and of T*k rows needs no more temporaries than the
+    whole-size path alone.  (Differentiated without a ``jax.checkpoint`` on
+    each branch, the ``cond`` hands both branches' residuals and a copy of
+    the weights across: 2.2 GB for 0.66 at this shape.)"""
+    from bigdl_tpu.parallel import moe
+    from bigdl_tpu.tensor.policy import compute_dtype
+
+    z = FULL["moe"]
+    t, d, h, k, held, e = (z["tokens"], z["d"], z["hidden"], z["k"],
+                           z["held"], z["experts"])
+    w_in, w_out = v5e((held[1], d, h), jnp.float32), v5e((held[1], h, d),
+                                                         jnp.float32)
+    args = ({"w_gate": w_in, "w_up": w_in, "w_down": w_out},
+            v5e((t, d), jnp.float32), v5e((t, k), jnp.float32),
+            v5e((t, k), jnp.int32), v5e((t, d), jnp.float32))
+
+    def whole(p, x, idx, w, held, e):
+        order, inv, rows = moe._sort_pairs(idx, held)
+        return moe._held_rows_apply(p, x, w, order, inv, rows, idx.size)
+
+    def temp_bytes(apply):
+        def loss(p, x, w, idx, cot):
+            y = jax.checkpoint(lambda p, x, w: apply(p, x, idx, w, held,
+                                                     e)[0])(p, x, w)
+            return jnp.sum(y * cot)
+        # production's matmul precision, not the test session's "highest"
+        with compute_dtype(jnp.bfloat16), \
+                jax.default_matmul_precision("bfloat16"):
+            compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+                *args).compile()
+        return compiled.memory_analysis().temp_size_in_bytes
+
+    assert moe.held_capacity(t * k, held[1], e) < t * k
+    assert temp_bytes(moe.held_experts_apply) <= 1.1 * temp_bytes(whole)
