@@ -248,6 +248,21 @@ def _project(x, w):
                       preferred_element_type=jnp.float32).astype(x.dtype)
 
 
+def _fan_in_normal(key, fan_in, fan_out):
+    """A (fan_in, fan_out) matrix N(0, 1/fan_in)."""
+    return jax.random.normal(key, (fan_in, fan_out)) * fan_in ** -0.5
+
+
+def _flash_wanted(use_flash):
+    """A module's ``use_flash``: None = the Pallas flash kernels on a TPU,
+    XLA's attention elsewhere."""
+    if use_flash is None:
+        from bigdl_tpu.ops.common import on_tpu
+
+        return on_tpu()
+    return use_flash
+
+
 class LatentAttention(Module):
     """Multi-head latent attention (MLA; DeepSeek-V2, arXiv:2405.04434
     §2.1), causal self-attention in its expanded (training) form.
@@ -290,10 +305,7 @@ class LatentAttention(Module):
         d, h = self.hidden_size, self.num_heads
         qk = self.nope_dim + self.rope_dim
         ks = jax.random.split(rng, 5)
-
-        def w(key, fan_in, fan_out):
-            return jax.random.normal(key, (fan_in, fan_out)) * fan_in ** -0.5
-
+        w = _fan_in_normal
         return {"wq_a": w(ks[0], d, self.q_rank),
                 "q_norm": jnp.ones((self.q_rank,)),
                 "wq_b": w(ks[1], self.q_rank, h * qk),
@@ -325,12 +337,7 @@ class LatentAttention(Module):
                 [kv[..., :nope], jnp.broadcast_to(k_pe, (b, h, t, rp))], -1)
             v = kv[..., nope:]
         with jax.named_scope("mla/attn"):
-            use_flash = self.use_flash
-            if use_flash is None:
-                from bigdl_tpu.ops.common import on_tpu
-
-                use_flash = on_tpu()
-            if use_flash:
+            if _flash_wanted(self.use_flash):
                 from bigdl_tpu.ops.flash_attention import flash_attention
 
                 out = flash_attention(q, k, v, causal=True,
@@ -343,6 +350,78 @@ class LatentAttention(Module):
                     else self.sm_scale)
         with jax.named_scope("mla/proj"):
             out = out.transpose(0, 2, 1, 3).reshape(b, t, h * vd)
+            return _project(out, params["wo"]), EMPTY
+
+
+class GroupedQueryAttention(Module):
+    """Causal self-attention with grouped-query heads, a per-head RMSNorm
+    on queries and keys, and RoPE (the attention layers of a hybrid decoder
+    such as LFM2's ``lfm2_moe``).
+
+    ``q = u W_q`` as ``heads`` heads of ``head_dim``, ``k = u W_k`` and ``v
+    = u W_v`` as ``kv_heads`` heads, no biases; every query head and every
+    key head is normalised over its ``head_dim`` numbers (one weight
+    ``q_norm`` for all query heads, one ``k_norm`` for all key heads), THEN
+    turned by :func:`rope` over all ``head_dim`` dims; query head ``j``
+    attends through key/value head ``j // (heads / kv_heads)``; scores
+    scaled by ``head_dim ** -0.5``; ``W_o`` (heads * head_dim, hidden).
+
+    On a TPU the Pallas flash kernels take the grouped heads as they are (K
+    and V are not repeated); elsewhere K and V are repeated for
+    :func:`dot_product_attention`.  Device scopes ``gqa/proj`` and
+    ``gqa/attn``.  This module does not cache: the paged decode kernels
+    take one K/V head a query head."""
+
+    def __init__(self, hidden_size: int, num_heads: int, kv_heads: int,
+                 head_dim: int, *, rope_theta: float = 10000.0,
+                 qk_norm_eps: float = 1e-6, use_flash=None, name=None):
+        super().__init__(name)
+        if num_heads % kv_heads:
+            raise ValueError(f"{num_heads} query heads on {kv_heads} "
+                             "key/value heads: not a whole group each")
+        self.hidden_size, self.num_heads = hidden_size, num_heads
+        self.kv_heads, self.head_dim = kv_heads, head_dim
+        self.rope_theta, self.qk_norm_eps = rope_theta, qk_norm_eps
+        # None = the Pallas flash kernel on a TPU, XLA attention elsewhere
+        self.use_flash = use_flash
+
+    def build(self, rng, x):
+        d, hd = self.hidden_size, self.head_dim
+        h, h_kv = self.num_heads, self.kv_heads
+        ks = jax.random.split(rng, 4)
+        w = _fan_in_normal
+        return {"wq": w(ks[0], d, h * hd), "wk": w(ks[1], d, h_kv * hd),
+                "wv": w(ks[2], d, h_kv * hd), "q_norm": jnp.ones((hd,)),
+                "k_norm": jnp.ones((hd,)), "wo": w(ks[3], h * hd, d)}, EMPTY
+
+    def forward(self, params, state, x, training=False, rng=None):
+        b, t, _ = x.shape
+        h, h_kv, hd = self.num_heads, self.kv_heads, self.head_dim
+
+        def heads(y, n):
+            return y.reshape(b, t, n, hd).transpose(0, 2, 1, 3)
+
+        with jax.named_scope("gqa/proj"):
+            q = heads(_project(x, params["wq"]), h)
+            k = heads(_project(x, params["wk"]), h_kv)
+            v = heads(_project(x, params["wv"]), h_kv)
+            q = rope(rms_norm(q, params["q_norm"], self.qk_norm_eps),
+                     self.rope_theta)
+            k = rope(rms_norm(k, params["k_norm"], self.qk_norm_eps),
+                     self.rope_theta)
+        with jax.named_scope("gqa/attn"):
+            if _flash_wanted(self.use_flash):
+                from bigdl_tpu.ops.flash_attention import flash_attention
+
+                out = flash_attention(q, k, v, causal=True)
+            else:
+                group = h // h_kv
+                out = dot_product_attention(
+                    q, jnp.repeat(k, group, axis=1),
+                    jnp.repeat(v, group, axis=1),
+                    mask=jnp.tril(jnp.ones((t, t), bool)))
+        with jax.named_scope("gqa/proj"):
+            out = out.transpose(0, 2, 1, 3).reshape(b, t, h * hd)
             return _project(out, params["wo"]), EMPTY
 
 

@@ -110,7 +110,8 @@ DEFAULT_HELP = {
     "ops.autotune_cache_misses": "kernel tile lookups that fell back to "
                                  "the defaults (no cache entry)",
     "kernel.flash.traces": "flash_attention lowerings traced, by direction "
-                           "(fwd, bwd), implementation and operand dtype",
+                           "(fwd, bwd), implementation, operand dtype and "
+                           "query heads to a key/value head (kv_group)",
     "kernel.flash.tile_share": "tiles the last-traced flash_attention "
                                "kernels visit / tiles in the q-block x "
                                "k-block rectangle, by direction (causal "

@@ -34,8 +34,15 @@ Cross-chip sequence parallelism lives in
 ``bigdl_tpu/parallel/ring_attention.py``; the paged decode/verify kernels
 below are the serving-side siblings.
 
-Shapes: q, k are (batch, heads, seq, head_dim), v is (batch, heads, seq,
-v_dim); the output has q's length and v's width.
+Shapes: q is (batch, heads, seq, head_dim), k (batch, kv_heads, seq,
+head_dim), v (batch, kv_heads, seq, v_dim); the output has q's heads and
+length and v's width.  Grouped-query heads: ``kv_heads`` divides ``heads``
+and query head ``j`` reads key/value head ``j // (heads / kv_heads)``.  K
+and V are never repeated in HBM: the forward and the dq kernel send program
+``bh`` to K/V row ``bh // group`` in their index maps, and the dk/dv kernel
+runs over the key/value heads and walks its group's query heads in its
+inner grid axis, accumulating all of them in its scratch.  ``kv_heads =
+heads`` traces the program it traced before there were groups.
 """
 
 import functools
@@ -246,23 +253,26 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         lse_ref[0] = m_scr[...] + jnp.log(l_safe)
 
 
-def _kv_index_map(causal, bq, bk, nk):
-    """K/V tile of grid step (bh, i, j).  Causal: steps past the last tile
-    q-block ``i`` visits re-name that tile, so nothing is fetched for
-    them."""
+def _kv_index_map(causal, bq, bk, nk, group=1):
+    """K/V tile of grid step (bh, i, j): query row ``bh`` of ``(b * h, ..)``
+    reads K/V row ``bh // group`` of ``(b * h_kv, ..)``.  Causal: steps
+    past the last tile q-block ``i`` visits re-name that tile, so nothing is
+    fetched for them."""
+    row = (lambda bh: bh) if group == 1 else (lambda bh: bh // group)
     if not causal:
-        return lambda bh, i, j: (bh, j, 0)
+        return lambda bh, i, j: (row(bh), j, 0)
     return lambda bh, i, j: (
-        bh, jnp.minimum(j, jnp.minimum(((i + 1) * bq - 1) // bk, nk - 1)), 0)
+        row(bh),
+        jnp.minimum(j, jnp.minimum(((i + 1) * bq - 1) // bk, nk - 1)), 0)
 
 
 def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     b, h, sq, d = q.shape
-    skv, d_v = k.shape[2], v.shape[3]
+    h_kv, skv, d_v = k.shape[1], k.shape[2], v.shape[3]
     bq, bk, sq_p, skv_p = _clip_blocks(block_q, block_k, sq, skv)
     qp = _pad_seq(q, sq_p).reshape(b * h, sq_p, d)
-    kp = _pad_seq(k, skv_p).reshape(b * h, skv_p, d)
-    vp = _pad_seq(v, skv_p).reshape(b * h, skv_p, d_v)
+    kp = _pad_seq(k, skv_p).reshape(b * h_kv, skv_p, d)
+    vp = _pad_seq(v, skv_p).reshape(b * h_kv, skv_p, d_v)
 
     nq, nk = sq_p // bq, skv_p // bk
     kernel = functools.partial(
@@ -270,7 +280,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
         block_k=bk, kv_len=skv, skv_p=skv_p)
     q_spec = pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0))
     o_spec = pl.BlockSpec((1, bq, d_v), lambda bh, i, j: (bh, i, 0))
-    kv_map = _kv_index_map(causal, bq, bk, nk)
+    kv_map = _kv_index_map(causal, bq, bk, nk, h // h_kv)
     # the row statistics carry a trailing singleton lane dim: a 2-D (1, bq)
     # block would put bq in the lane slot and 1 in the sublane slot, which
     # TPU tiling rejects when batch·heads > 1.
@@ -335,16 +345,19 @@ def _dq_kernel(q_ref, g_ref, lse_ref, delta_ref, k_ref, v_ref, dq_ref,
 
 def _dkv_kernel(q_ref, g_ref, lse_ref, delta_ref, k_ref, v_ref, dk_ref,
                 dv_ref, dk_scr, dv_scr, *, sm_scale, causal, block_q,
-                block_k, kv_len, skv_p):
+                block_k, kv_len, skv_p, q_blocks=None):
     """dk, dv for one k-block, over the q-blocks at or below the diagonal.
     The score tile is built TRANSPOSED (keys along rows, k·qᵀ), so both
     accumulating products are plain (block_k, block_q) @ (block_q, d)
     matmuls and the per-query lse/delta broadcast along rows: nothing is
-    transposed in the kernel."""
+    transposed in the kernel.  Grouped heads (``q_blocks`` set): the inner
+    axis walks the ``q_blocks`` blocks of each of the group's query heads
+    in turn, and all of them add into the one accumulator."""
     kj = pl.program_id(1)
-    qi = pl.program_id(2)
+    step = pl.program_id(2)
+    qi = step if q_blocks is None else step % q_blocks
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
@@ -366,7 +379,7 @@ def _dkv_kernel(q_ref, g_ref, lse_ref, delta_ref, k_ref, v_ref, dk_ref,
         qi, kj, causal=causal, block_q=block_q, block_k=block_k,
         kv_len=kv_len, skv_p=skv_p))
 
-    @pl.when(qi == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finish():
         dk_ref[0] = (dk_scr[...] * sm_scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
@@ -378,16 +391,16 @@ def _flash_bwd(q, k, v, out, lse, g, sm_scale, causal, block_q, block_k,
     gradient: padded queries have g = 0 and delta = 0, padded keys are
     masked out of ``p``; both are sliced off the results."""
     b, h, sq, d = q.shape
-    skv, d_v = k.shape[2], v.shape[3]
+    h_kv, skv, d_v = k.shape[1], k.shape[2], v.shape[3]
     bq, bk, sq_p, skv_p = _clip_blocks(block_q, block_k, sq, skv)
     nq, nk = sq_p // bq, skv_p // bk
-    bh = b * h
+    bh, bkv, group = b * h, b * h_kv, h // h_kv
 
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), -1)
     qp = _pad_seq(q, sq_p).reshape(bh, sq_p, d)
     gp = _pad_seq(g, sq_p).reshape(bh, sq_p, d_v)
-    kp = _pad_seq(k, skv_p).reshape(bh, skv_p, d)
-    vp = _pad_seq(v, skv_p).reshape(bh, skv_p, d_v)
+    kp = _pad_seq(k, skv_p).reshape(bkv, skv_p, d)
+    vp = _pad_seq(v, skv_p).reshape(bkv, skv_p, d_v)
     stats = [jnp.pad(x.reshape(bh, sq), ((0, 0), (0, sq_p - sq)))
              for x in (lse, delta)]
 
@@ -399,7 +412,7 @@ def _flash_bwd(q, k, v, out, lse, g, sm_scale, causal, block_q, block_k,
     q_spec = pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0))
     g_spec = pl.BlockSpec((1, bq, d_v), lambda bh, i, j: (bh, i, 0))
     col_spec = pl.BlockSpec((1, bq, 1), lambda bh, i, j: (bh, i, 0))
-    kv_map = _kv_index_map(causal, bq, bk, nk)
+    kv_map = _kv_index_map(causal, bq, bk, nk, group)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, **static),
         grid=(bh, nq, nk),
@@ -413,31 +426,39 @@ def _flash_bwd(q, k, v, out, lse, g, sm_scale, causal, block_q, block_k,
         interpret=interpret,
     )(qp, gp, *(x[:, :, None] for x in stats), kp, vp)
 
-    # dk/dv: grid (bh, k-blocks, q-blocks); statistics as (1, block_q) rows
+    # dk/dv: grid (b * h_kv, k-blocks, group * q-blocks): inner step i is
+    # q-block i % nq of the group's query head i // nq (one head, and i
+    # itself, where there are no groups); statistics as (1, block_q) rows
     # of a (bh, q-blocks, 1, block_q) view, whose last two block dims span
     # the array's: legal at any block_q.  Causal: the q-blocks before the
     # first one k-block j reaches re-name that first tile.
+    def q_row(r, i):
+        return r if group == 1 else r * group + i // nq
+
     def q_block(j, i):
+        if group > 1:
+            i = i % nq
         if causal:
             i = jnp.maximum(i, jnp.minimum((j * bk) // bq, nq - 1))
         return i
 
     def qd_spec(width):
         return pl.BlockSpec((1, bq, width),
-                            lambda bh, j, i: (bh, q_block(j, i), 0))
+                            lambda r, j, i: (q_row(r, i), q_block(j, i), 0))
 
     row_spec = pl.BlockSpec(
-        (1, 1, 1, bq), lambda bh, j, i: (bh, q_block(j, i), 0, 0))
-    kd_spec = pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0))
-    vd_spec = pl.BlockSpec((1, bk, d_v), lambda bh, j, i: (bh, j, 0))
+        (1, 1, 1, bq), lambda r, j, i: (q_row(r, i), q_block(j, i), 0, 0))
+    kd_spec = pl.BlockSpec((1, bk, d), lambda r, j, i: (r, j, 0))
+    vd_spec = pl.BlockSpec((1, bk, d_v), lambda r, j, i: (r, j, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, **static),
-        grid=(bh, nk, nq),
+        functools.partial(_dkv_kernel, **static,
+                          q_blocks=None if group == 1 else nq),
+        grid=(bkv, nk, group * nq),
         in_specs=[qd_spec(d), qd_spec(d_v), row_spec, row_spec, kd_spec,
                   vd_spec],
         out_specs=[kd_spec, vd_spec],
-        out_shape=[jax.ShapeDtypeStruct((bh, skv_p, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, skv_p, d_v), v.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((bkv, skv_p, d), k.dtype),
+                   jax.ShapeDtypeStruct((bkv, skv_p, d_v), v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d_v), jnp.float32)],
         compiler_params=_compiler_params(),
@@ -445,8 +466,8 @@ def _flash_bwd(q, k, v, out, lse, g, sm_scale, causal, block_q, block_k,
     )(qp, gp, *(x.reshape(bh, nq, 1, bq) for x in stats), kp, vp)
 
     dq = dq.reshape(b, h, sq_p, d)[:, :, :sq]
-    dk = dk.reshape(b, h, skv_p, d)[:, :, :skv]
-    dv = dv.reshape(b, h, skv_p, d_v)[:, :, :skv]
+    dk = dk.reshape(b, h_kv, skv_p, d)[:, :, :skv]
+    dv = dv.reshape(b, h_kv, skv_p, d_v)[:, :, :skv]
     return dq, dk, dv
 
 
@@ -468,8 +489,8 @@ def _flash_vjp_fwd(q, k, v, sm_scale, causal, block_q, block_k,
 def _flash_vjp_bwd(sm_scale, causal, block_q, block_k, block_q_bwd,
                    block_k_bwd, interpret, res, g):
     q, k, v, out, lse = res
-    _book_trace("bwd", q.shape, k.shape[2], q.dtype, causal, block_q_bwd,
-                block_k_bwd)
+    _book_trace("bwd", q.shape, k.shape[1], k.shape[2], q.dtype, causal,
+                block_q_bwd, block_k_bwd)
     return _flash_bwd(q, k, v, out, lse, g, sm_scale, causal, block_q_bwd,
                       block_k_bwd, interpret)
 
@@ -792,27 +813,30 @@ def paged_verify_attention(q, k_pages, v_pages, page_table, positions, *,
 
 
 @functools.lru_cache(maxsize=None)
-def _log_blocks_once(direction, q_shape, skv, dtype, causal, block_q,
-                     block_k):
-    log.info("flash_attention %s q%s kv %d %s causal=%s: blocks %d x %d",
-             direction, q_shape, skv, dtype, causal, block_q, block_k)
+def _log_blocks_once(direction, q_shape, kv_heads, skv, dtype, causal,
+                     block_q, block_k):
+    log.info("flash_attention %s q%s kv %d x %d %s causal=%s: blocks %d x "
+             "%d", direction, q_shape, kv_heads, skv, dtype, causal, block_q,
+             block_k)
 
 
-def _book_trace(direction, q_shape, skv, dtype, causal, block_q, block_k):
+def _book_trace(direction, q_shape, kv_heads, skv, dtype, causal, block_q,
+                block_k):
     """Trace-time bookkeeping (nothing of it runs inside the step): that
     this direction was lowered to the Pallas kernels, on what operand
-    dtype, how much of the tile rectangle it visits, and — once per shape
-    — the blocks chosen."""
+    dtype, with how many query heads to a key/value head, how much of the
+    tile rectangle it visits, and — once per shape — the blocks chosen."""
     from bigdl_tpu.optim.metrics import global_metrics
 
     m = global_metrics()
     m.inc("kernel.flash.traces", labels={
-        "direction": direction, "impl": "pallas", "dtype": dtype.name})
+        "direction": direction, "impl": "pallas", "dtype": dtype.name,
+        "kv_group": str(q_shape[1] // kv_heads)})
     m.gauge("kernel.flash.tile_share",
             tile_share(q_shape[2], skv, block_q, block_k, causal),
             labels={"direction": direction})
-    _log_blocks_once(direction, tuple(q_shape), skv, dtype.name, causal,
-                     block_q, block_k)
+    _log_blocks_once(direction, tuple(q_shape), kv_heads, skv, dtype.name,
+                     causal, block_q, block_k)
 
 
 def resolve_blocks(q_shape, skv, dtype, *, d_v=None, block_q=None,
@@ -851,10 +875,14 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     block_k: Optional[int] = None,
                     block_k_bwd: Optional[int] = None,
                     interpret: Optional[bool] = None):
-    """Fused blockwise attention.  q, k: (batch, heads, seq, head_dim); v:
-    (batch, heads, seq, v_dim), where ``v_dim`` need not be ``head_dim``
-    (latent attention publishes 192-wide keys against 128-wide values); the
-    result has v's width.  ``sm_scale`` defaults to ``head_dim ** -0.5``.
+    """Fused blockwise attention.  q: (batch, heads, seq, head_dim); k:
+    (batch, kv_heads, seq, head_dim); v: (batch, kv_heads, seq, v_dim),
+    where ``v_dim`` need not be ``head_dim`` (latent attention publishes
+    192-wide keys against 128-wide values) and ``kv_heads`` may be any
+    divisor of ``heads`` (grouped-query attention: query head ``j`` reads
+    key/value head ``j // (heads / kv_heads)``; K and V are not repeated,
+    and ``dk``/``dv`` come back ``kv_heads`` wide).  The result has q's
+    heads and v's width.  ``sm_scale`` defaults to ``head_dim ** -0.5``.
 
     The operands are cast to the policy's compute dtype (bfloat16 on a
     TPU, float32 elsewhere) as ``dot_product_attention`` casts its own;
@@ -867,6 +895,12 @@ def flash_attention(q, k, v, *, causal: bool = False,
     win."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
+    kv_heads = k.shape[1]
+    if v.shape[1] != kv_heads or q.shape[1] % kv_heads:
+        raise ValueError(
+            f"flash_attention: {q.shape[1]} query heads on {kv_heads} key "
+            f"and {v.shape[1]} value heads: k and v share a head count "
+            "that divides q's")
     from bigdl_tpu.ops import autotune
 
     dtype = q.dtype
@@ -876,13 +910,15 @@ def flash_attention(q, k, v, *, causal: bool = False,
     # inside a jit trace the args are tracers and we must not run timing
     # trials mid-trace
     d_v = None if v.shape[-1] == q.shape[-1] else v.shape[-1]
-    # the online tuner's bench shape is (b, h, s, d): equal widths only
+    # the online tuner's bench shape is (b, h, s, d): equal widths and
+    # head counts only
     shape = (tuple(q.shape) + (q.dtype.name,)
-             if d_v is None and autotune.is_concrete(q, k, v) else None)
+             if d_v is None and kv_heads == q.shape[1]
+             and autotune.is_concrete(q, k, v) else None)
     fwd, bwd = resolve_blocks(q.shape, skv, q.dtype, d_v=d_v,
                               block_q=block_q, block_k=block_k,
                               block_k_bwd=block_k_bwd, online_shape=shape)
-    _book_trace("fwd", q.shape, skv, q.dtype, bool(causal),
+    _book_trace("fwd", q.shape, kv_heads, skv, q.dtype, bool(causal),
                 int(fwd["block_q"]), int(fwd["block_k"]))
     out = _flash(q, k, v, float(sm_scale), bool(causal),
                  int(fwd["block_q"]), int(fwd["block_k"]),

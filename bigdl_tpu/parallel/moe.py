@@ -209,21 +209,23 @@ class MoE(Module):
 # ---------------------------------------------------------------------------
 
 def route_sigmoid_topk(x, w_router, bias, k: int, scale: float = 1.0,
-                       norm_topk: bool = True):
+                       norm_topk: bool = True, norm_eps: float = 1e-20):
     """Aux-loss-free routing (DeepSeek-V3 ``noaux_tc`` with one group), all
     in float32.  x: (T, d); w_router: (E, d), one row an expert (the
     layout checkpoints publish); bias: (E,) correction bias.  ``s =
     sigmoid(x W^T)``; the ``k`` largest of ``s + bias`` are chosen; the
     weights are ``s`` of the chosen (the bias moves the choice, never the
-    weight), divided by their sum when ``norm_topk``, times ``scale``.
-    Returns (idx (T, k) int32, weights (T, k) float32)."""
+    weight), divided by their sum plus ``norm_eps`` when ``norm_topk`` (a
+    published forward pass adds 1e-20, or 1e-6: at float32 beside a sum of
+    k sigmoids the first is no number, the second a few ulps), times
+    ``scale``.  Returns (idx (T, k) int32, weights (T, k) float32)."""
     s = jax.nn.sigmoid(jnp.einsum(
         "td,ed->te", x.astype(jnp.float32), w_router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
     _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), k)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if norm_topk:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + norm_eps)
     return idx.astype(jnp.int32), w * scale
 
 
@@ -426,7 +428,8 @@ class HeldMoE(Module):
     def __init__(self, num_experts: int, hidden: int, k: int, *,
                  held: Optional[Tuple[int, int]] = None,
                  shared_hidden: int = 0, scale: float = 1.0,
-                 norm_topk: bool = True, name: Optional[str] = None):
+                 norm_topk: bool = True, norm_eps: float = 1e-20,
+                 name: Optional[str] = None):
         super().__init__(name)
         self.num_experts, self.hidden, self.k = num_experts, hidden, k
         self.held = tuple(held) if held is not None else (0, num_experts)
@@ -437,6 +440,7 @@ class HeldMoE(Module):
                              f"[0, {num_experts})")
         self.shared_hidden = shared_hidden
         self.scale, self.norm_topk = scale, norm_topk
+        self.norm_eps = norm_eps
 
     def build(self, rng, x):
         d, h, n = x.shape[-1], self.hidden, self.held[1]
@@ -461,7 +465,7 @@ class HeldMoE(Module):
         with jax.named_scope("moe/route"):
             idx, w = route_sigmoid_topk(
                 flat, params["w_router"], state["router_bias"], self.k,
-                self.scale, self.norm_topk)
+                self.scale, self.norm_topk, self.norm_eps)
         with jax.named_scope("moe/experts"):
             y, rows, dropped, short = held_experts_apply(
                 params["experts"], flat, idx, w, self.held,

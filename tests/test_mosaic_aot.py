@@ -106,6 +106,28 @@ def test_flash_attention_forward_and_backward(v5e, shape, dtype):
         _compile(jax.grad(loss, argnums=(0, 1, 2)), q, q, v)
 
 
+@pytest.mark.parametrize("shape,kv_heads,dtype", [
+    ((1, 32, 8192, 64), 8, jnp.bfloat16),  # lfm2-24b-a2b.train-ep8-packed8k
+    ((1, 32, 8192, 64), 8, jnp.float32),
+    ((2, 8, 200, 64), 2, jnp.bfloat16),    # padded: 200 rows, one block
+    ((1, 4, 1500, 128), 1, jnp.bfloat16),  # one key/value head for all
+])
+def test_flash_attention_grouped_heads(v5e, shape, kv_heads, dtype):
+    """Grouped-query heads: the K/V index maps that divide the program id
+    and the dk/dv kernel that walks its group's query heads."""
+    from bigdl_tpu.ops.flash_attention import flash_attention
+    from bigdl_tpu.tensor.policy import compute_dtype
+
+    q = v5e(shape, jnp.float32)
+    kv = v5e((shape[0], kv_heads) + shape[2:], jnp.float32)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False).sum()
+
+    with compute_dtype(dtype):
+        _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+
+
 @pytest.mark.parametrize("block,ok", [((128, 128), True), ((64, 64), False),
                                       ((8, 8), False)])
 def test_block_sparse_matmul_needs_128_blocks(v5e, block, ok):

@@ -286,7 +286,7 @@ class TestFlashBackwardKernels:
         def count(direction):
             return m.counter(label_key(
                 "kernel.flash.traces", direction=direction, impl="pallas",
-                dtype="float32"))
+                dtype="float32", kv_group="1"))
 
         def gauge(direction):
             return m.gauges[label_key("kernel.flash.tile_share",
