@@ -49,6 +49,9 @@ import numpy as np
 # stated tolerances (max |kernel - reference| / max |reference|, reference in
 # float32 at matmul precision "highest"):
 TOL_F32 = 2e-3    # VPU/f32 kernels: only exp/rsqrt approximations differ
+TOL_F32_SUM = 1e-5  # float32 multiply-adds and sums in another order, nothing
+#                     approximated (the mixing's one-pass backward: 2e-7 on
+#                     the v5e at (4, 4096, 3584), PR 35)
 TOL_MXU = 2e-2    # kernels whose dots take bf16 MXU passes (KERNELS_r04 used
 #                   0.02 forward / 0.05 backward for the same reason)
 TOL_MXU_BWD = 5e-2
@@ -73,7 +76,7 @@ FULL = dict(
                max_new=12, requests=8, prompt_lens=(24, 640)),
     kern=dict(slots=8, heads=12, hd=64, page=16, nb=64, chunk=5,
               flash_b=2, flash_s=1024, ffn=(768, 3072), bs_block=(128, 128),
-              ln_rows=4096, mm=(256, 768, 3072)),
+              ln_rows=4096, mm=(256, 768, 3072), hc=(4, 4096, 3584)),
     moe=dict(tokens=4096, d=3584, hidden=1024, experts=64, held=(8, 8), k=4),
 )
 TINY = dict(
@@ -84,7 +87,7 @@ TINY = dict(
                max_new=4, requests=4, prompt_lens=(3, 24)),
     kern=dict(slots=2, heads=2, hd=16, page=4, nb=2, chunk=3,
               flash_b=1, flash_s=32, ffn=(32, 64), bs_block=(16, 16),
-              ln_rows=16, mm=(32, 128, 128)),
+              ln_rows=16, mm=(32, 128, 128), hc=(4, 64, 128)),
     moe=dict(tokens=256, d=32, hidden=16, experts=16, held=(4, 2), k=2),
 )
 
@@ -185,6 +188,39 @@ class Smoke:
         g_r = ref(jax.grad(loss_of(plain), argnums=(0, 1, 2)), q, kk, v)
         for n, a, b in zip("qkv", g_k, g_r):
             check(f"flash_attention_bwd_d{n}", a, b, TOL_MXU_BWD)
+
+        # the hyper-connection's stream-wide products at the Xing cell's
+        # shape: the one-pass backward (what HyperConnection takes on a TPU)
+        # against autodiff of the plain expressions.  Only the chip shows
+        # Mosaic's layout of the per-token columns and the accumulators
+        # carried over the chunks of d
+        from bigdl_tpu.nn.hyper_connection import read_out, write_back
+        from bigdl_tpu.ops.hc_mix import mix_backward
+
+        n, tok, wide = k["hc"]
+        kx = jax.random.split(jax.random.PRNGKey(35), 7)
+        xs, gs, through = (jax.random.normal(kk_, (n, tok, wide))
+                           for kk_ in kx[:3])
+        ys, du = (jax.random.normal(kk_, (tok, wide)) for kk_ in kx[3:5])
+        cf = jax.random.uniform(kx[5], (n, n + 1, tok))
+        hp = jax.random.uniform(kx[6], (n, tok))
+
+        def pre_bwd(h, du, x, add):
+            dx, _, dh = mix_backward(h[None], du[None], x, add=add)
+            return dx, dh[0]
+
+        got = jax.jit(mix_backward)(cf, gs, xs, ys)
+        want = ref(lambda c, g, x, y: jax.vjp(
+            write_back, x, y, c[:, :n], c[:, n])[1](g), cf, gs, xs, ys)
+        for name, a, b in zip(("dx", "dy", "dres", "dpost"),
+                              got[:2] + (got[2][:, :n], got[2][:, n]), want):
+            check(f"hc_mix_post_bwd_{name}", a, b, TOL_F32_SUM)
+        got = jax.jit(pre_bwd)(hp, du, xs, through)
+        dx, dh = ref(lambda h, du, x: jax.vjp(read_out, x, h)[1](du),
+                     hp, du, xs)
+        check("hc_mix_pre_bwd_dx", got[0], dx + through, TOL_F32_SUM)
+        check("hc_mix_pre_bwd_dh", got[1], dh, TOL_F32_SUM)
+        del xs, gs, through, got, want, dx
 
         # paged decode / verify attention over a page pool, f32 and int8
         P = S * nb

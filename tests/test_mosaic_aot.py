@@ -254,3 +254,26 @@ def test_expert_layer_two_paths_keep_no_more_than_one(v5e):
 
     assert moe.held_capacity(t * k, held[1], e) < t * k
     assert temp_bytes(moe.held_experts_apply) <= 1.1 * temp_bytes(whole)
+
+
+@pytest.mark.parametrize("n,tokens,d", [
+    FULL["kern"]["hc"],         # xing4.0-29b-a4b.train-tp8-packed4k
+    (4, 8192, 2048),
+    (2, 200, 384),              # tiles of 8 tokens by 384
+])
+def test_hc_mix_backward_both_ways(v5e, n, tokens, d):
+    """The one-pass backward of the hyper-connection's write-back (n + 1
+    primal slabs) and of its read-out with the cotangent it adds, at the
+    tile rule's picks: per-token columns ``(block_t, m * p)`` and the
+    accumulators carried over the chunks of ``d`` are what Mosaic could
+    refuse."""
+    from bigdl_tpu.ops.hc_mix import mix_backward
+
+    f32 = lambda *shape: v5e(shape, jnp.float32)
+    _compile(lambda c, g, x, y: mix_backward(c, g, x, y, interpret=False),
+             f32(n, n + 1, tokens), f32(n, tokens, d), f32(n, tokens, d),
+             f32(tokens, d))
+    _compile(lambda c, g, x, a: mix_backward(c, g, x, add=a,
+                                             interpret=False),
+             f32(1, n, tokens), f32(1, tokens, d), f32(n, tokens, d),
+             f32(n, tokens, d))
