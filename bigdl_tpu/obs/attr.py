@@ -40,9 +40,10 @@ This module also owns two run-health sentinels:
   yields max/min/skew (straggler detection).
 """
 
+import statistics
 import threading
 import time
-from collections import defaultdict
+from collections import defaultdict, deque
 from contextlib import contextmanager
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
@@ -75,6 +76,11 @@ class _Phase:
     def seconds(self) -> float:
         """The whole interval (a dispatch's compile seconds included)."""
         return self._timed.seconds
+
+    @property
+    def end_ns(self) -> int:
+        """Where the interval ended, on ``obs.trace.now_ns``."""
+        return self._timed.end_ns
 
     def __enter__(self) -> "_Phase":
         if self._name == "dispatch":
@@ -110,6 +116,7 @@ class StepAttribution:
         self.wall_s = 0.0
         self.totals: Dict[str, float] = {c: 0.0 for c in COMPONENTS}
         self.windows = 0  # loss fetches seen: log points and flushes
+        self.stalls = StallWatch(metrics)
         self._t_iter: Optional[float] = None  # open iteration's start
         self._booked = 0.0  # phase seconds booked since then
 
@@ -133,7 +140,7 @@ class StepAttribution:
 
     def begin(self, now: Optional[float] = None) -> None:
         """The loop starts (or restarts after a recovery) NOW."""
-        self._t_iter = time.perf_counter() if now is None else now
+        self._t_iter = trace.now_ns() * 1e-9 if now is None else now
         self._booked = 0.0
 
     def end_iteration(self, now: Optional[float] = None) -> None:
@@ -143,7 +150,7 @@ class StepAttribution:
         and cannot be negative."""
         if self._t_iter is None:
             return
-        now = time.perf_counter() if now is None else now
+        now = trace.now_ns() * 1e-9 if now is None else now
         wall = now - self._t_iter
         self.book("other", wall - self._booked)
         self.wall_s += wall
@@ -180,6 +187,132 @@ class StepAttribution:
                 f"  {name:<10} {c['total_s']:>10.3f} "
                 f"{c['per_step_s'] * 1e3:>12.3f} {c['fraction']:>8.1%}")
         return "\n".join(lines)
+
+
+# ---------------------------------------------------------------------------
+# stalls: the step intervals judged by the run's own recent ones
+# ---------------------------------------------------------------------------
+
+STALL_COUNTERS = ("train.stalls", "train.stall_s", "train.stall_host_s",
+                  "train.stall_device_s")
+
+
+class StallWatch:
+    """The program's one per-step interval and the stall rule on it
+    (docs/observability.md §Stalls).
+
+    **The interval**: between two consecutive returns of the loss fetch
+    (the ``sync`` phase's own end stamps) over the steps the later fetch
+    covered, into ``train.step_time_s``.  One that holds a compilation of
+    the driver thread's, a trigger's work or a recovery
+    (:meth:`exclude`), and the first, is no sample: booked elsewhere.
+
+    **The rule**: baseline = the median of the last 32 samples (stalls and
+    run-ahead witnesses left out), judged once 8 exist.  A fetch of *k*
+    steps whose interval exceeds *k* baselines by more than max(0.25 s,
+    one baseline) opens a stall.
+
+    **The witness** is the next sample.  The fetch comes one bundle late,
+    so *a* steps were in flight while the long fetch waited.  Had the
+    device's work come late, they could only start when it returned, and
+    the next interval is an ordinary one: ``device``, lost = the long
+    interval minus its steps' baselines.  Had anything else (interpreter,
+    process, the runtime's copy back), the device ran ahead and the next
+    interval comes in short by more than *a*/2 baselines (at one step a
+    fetch: under half a baseline): ``host``, lost = both intervals minus
+    their steps' baselines.  No next sample, or nothing in flight:
+    ``unknown``.  And ``host`` whatever came next where more than half of
+    the lost seconds fell OUTSIDE the fetch (``interval_s - waited_s``):
+    the driver was not waiting for the device, and with the next bundle
+    not yet queued nothing could run ahead (the chip showed it, PERF.md
+    §6 PR 36).  Booked then: the four counters (at 0 from the start), span
+    ``train/stall`` over the long interval, flight event ``train_stall``
+    and a warning with the host's alibi (``obs/host.py``)."""
+
+    WINDOW, MIN_SAMPLES, FLOOR_S = 32, 8, 0.25
+
+    def __init__(self, metrics, probes=None):
+        self.metrics = metrics
+        self.probes = probes
+        self._recent: deque = deque(maxlen=self.WINDOW)
+        self._last_ns: Optional[int] = None  # the previous fetch's return
+        self._compiled = 0.0
+        self._open: Optional[Dict[str, Any]] = None  # awaits its witness
+        for name in STALL_COUNTERS:
+            metrics.inc(name, 0)
+
+    def exclude(self) -> None:
+        """What the driver does next is not step time: the interval it
+        falls in is no sample, and cannot be a witness either."""
+        self._last_ns = None
+        self._book("unknown")
+
+    def fetched(self, end_ns: int, waited_s: float, steps: int,
+                in_flight: int, iteration: int) -> Optional[float]:
+        """A loss fetch covering ``steps`` steps returned at ``end_ns``
+        after ``waited_s`` in the fetch, ``in_flight`` steps still
+        dispatched.  The per-step wall seconds of the interval it closes:
+        None at the first fetch and after :meth:`exclude`; a sample unless
+        the driver compiled in it."""
+        last, self._last_ns = self._last_ns, end_ns
+        compiled, was = compile_seconds(), self._compiled
+        self._compiled = compiled
+        if last is None or steps <= 0:
+            self._book("unknown")
+            return None
+        interval = (end_ns - last) * 1e-9
+        per_step = interval / steps
+        if compiled != was:
+            self._book("unknown")
+            return per_step
+        self.metrics.observe("train.step_time_s", per_step)
+        o = self._open
+        if o is not None:
+            short = steps * o["baseline_s"] - interval
+            ahead = o["in_flight"] * o["baseline_s"]
+            where = ("unknown" if not ahead
+                     else "host" if short > ahead / 2 else "device")
+            self._book(where, -short)
+            if where == "host":  # not an ordinary interval either
+                return per_step
+        if len(self._recent) >= self.MIN_SAMPLES:
+            base = statistics.median(self._recent)
+            excess = interval - steps * base
+            if excess > max(self.FLOOR_S, base):
+                self._open = {
+                    "iteration": iteration, "steps": steps,
+                    "in_flight": in_flight, "interval_s": interval,
+                    "waited_s": waited_s, "baseline_s": base,
+                    "lost_s": excess,
+                    "start_ns": last, "end_ns": end_ns}
+                return per_step
+        self._recent.append(per_step)
+        return per_step
+
+    def _book(self, where: str, witness_excess: float = 0.0) -> None:
+        o, self._open = self._open, None
+        if o is None:
+            return
+        if where == "host":
+            o["lost_s"] += witness_excess
+        elif o["interval_s"] - o["waited_s"] > o["lost_s"] / 2:
+            where = "host"  # lost outside the fetch: nobody waited
+        start, end = o.pop("start_ns"), o.pop("end_ns")
+        o["where"] = where
+        o["alibi"] = self.probes.alibi(start, end) if self.probes else {}
+        self.metrics.inc("train.stalls")
+        self.metrics.inc("train.stall_s", o["lost_s"])
+        if where != "unknown":
+            self.metrics.inc(f"train.stall_{where}_s", o["lost_s"])
+        trace.record("train/stall", start, end, where=where,
+                     lost_s=o["lost_s"], iteration=o["iteration"])
+        flight.record("train_stall", **o)
+        log.warning(
+            "stall at iteration %d: a fetch of %d step(s) took %.3fs (%.3fs "
+            "inside the fetch, %d step(s) in flight) where the baseline is "
+            "%.3fs a step; %.3fs lost, whose: %s; host's alibi: %s",
+            o["iteration"], o["steps"], o["interval_s"], o["waited_s"],
+            o["in_flight"], o["baseline_s"], o["lost_s"], where, o["alibi"])
 
 
 # ---------------------------------------------------------------------------
@@ -270,6 +403,38 @@ def idle_by_phase(device_intervals: Iterable[Tuple[float, float]],
         by["none"] += (g1 - g0) - covered
     return {"busy": busy, "idle": sum(g1 - g0 for g0, g1 in gaps),
             "window": cur_e - dev[0][0], "by_phase": dict(by)}
+
+
+def stall_on_device(ops: Iterable[Tuple[float, float, str]],
+                    host_intervals: Iterable[Interval],
+                    start: float, end: float, scale: float = 1.0) -> dict:
+    """One ``train/stall`` span ``[start, end]`` seen from the chip: its
+    ``length``, the ``busy`` and ``idle`` time inside it, the
+    ``longest_op`` ``(name, length)`` and the ``longest_gap`` ``(length,
+    phase)``, the phase being the driver-thread interval that covered most
+    of the gap.  Everything on one clock, as in :func:`idle_by_phase`, in
+    its unit times ``scale``.  A device that was late shows one long op;
+    one that ran ahead, one long gap."""
+    inside = sorted((max(s, start), min(e, end), n) for s, e, n in ops
+                    if s < end and e > start)
+    busy, cur, gap = 0.0, start, (0.0, start)
+    for s, e, _ in inside + [(end, end, "")]:
+        if s - cur > gap[0]:
+            gap = (s - cur, cur)
+        if e > cur:
+            busy += e - max(s, cur)
+            cur = e
+    g0, g1 = gap[1], gap[1] + gap[0]
+    cover: Dict[str, float] = defaultdict(float)
+    for s, e, name in _innermost(host_intervals):
+        if s < g1 and e > g0:
+            cover[name] += min(g1, e) - max(g0, s)
+    op = max(inside, key=lambda o: o[1] - o[0], default=(0.0, 0.0, "none"))
+    return {"length": (end - start) * scale, "busy": busy * scale,
+            "idle": ((end - start) - busy) * scale,
+            "longest_op": (op[2], (op[1] - op[0]) * scale),
+            "longest_gap": (gap[0] * scale, max(cover, key=cover.get)
+                            if cover else "none")}
 
 
 # ---------------------------------------------------------------------------

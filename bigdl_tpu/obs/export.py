@@ -75,8 +75,15 @@ def _fmt(v: float) -> str:
 # ``# HELP`` lines when the registry carries no explicit describe();
 # keyed by the registry's dotted names
 DEFAULT_HELP = {
-    "train.step_time_s": "step wall time (window mean at coarse log "
-                         "cadence)",
+    "train.step_time_s": "wall time between two loss fetches' returns "
+                         "over the steps between (obs/attr.py StallWatch)",
+    "train.stalls": "step intervals the stall rule flagged",
+    "train.stall_s": "seconds lost to stalls (excess over the median step)",
+    "train.stall_host_s": "stall seconds during which the device ran ahead",
+    "train.stall_device_s": "stall seconds where the device's work was late",
+    "host.gc_pause_s": "pause of each garbage collection in the process",
+    "host.gc_collections": "garbage collections by generation",
+    "host.heartbeat_late_s": "lateness (over 10 ms) of the 50 ms heartbeat",
     "train.data_wait_s": "driver phase data: host time blocked on the "
                          "input pipeline per fetch (input-bound signal)",
     "train.attr.dispatch_s": "driver phase dispatch: issuing the jitted "

@@ -313,6 +313,9 @@ def span(name: str, **attrs):
 # -- timed regions -----------------------------------------------------------
 
 _collector: Optional[Tracer] = None  # a program-owned device trace is running
+# the one clock of the spans, the driver's phases and the stall rule (and
+# of the benchmark's marks); a test may put its own here
+now_ns = time.monotonic_ns
 
 
 def collect_into(tracer: Optional[Tracer]) -> None:
@@ -340,27 +343,30 @@ class timed:
     """One instrumented region that is span and stopwatch at once, so the
     two cannot disagree about where it starts and ends: opens
     ``span(name, **attrs)`` (a no-op when the tracer is off), is recorded
-    under :func:`collect_into`, and always leaves the elapsed
-    ``time.perf_counter()`` seconds in ``.seconds``."""
+    under :func:`collect_into`, and always leaves its end stamp in
+    ``.end_ns`` and the elapsed seconds in ``.seconds``, both read off
+    :data:`now_ns`."""
 
-    __slots__ = ("name", "attrs", "seconds", "_span", "_ns0", "_t0")
+    __slots__ = ("name", "attrs", "seconds", "end_ns", "_span", "_ns0",
+                 "_into")
 
     def __init__(self, name: str, **attrs):
         self.name = name
         self.attrs = attrs
         self.seconds = 0.0
+        self.end_ns = 0
 
     def __enter__(self) -> "timed":
         self._span = span(self.name, **self.attrs).__enter__()
-        self._ns0 = time.monotonic_ns() if _collector is not None else 0
-        self._t0 = time.perf_counter()
+        self._into = _collector
+        self._ns0 = now_ns()
         return self
 
     def __exit__(self, *exc) -> bool:
-        self.seconds = time.perf_counter() - self._t0
+        self.end_ns = now_ns()
+        self.seconds = (self.end_ns - self._ns0) * 1e-9
         c = _collector
-        if c is not None and self._ns0:
-            c.add_span(self.name, self._ns0, time.monotonic_ns(),
-                       **self.attrs)
+        if c is not None and c is self._into:
+            c.add_span(self.name, self._ns0, self.end_ns, **self.attrs)
         self._span.__exit__(*exc)
         return False

@@ -28,6 +28,7 @@ from bigdl_tpu.data.prefetch import thread_prefetch
 from bigdl_tpu.obs import attr as obs_attr
 from bigdl_tpu.obs import cost as obs_cost
 from bigdl_tpu.obs import flight, trace
+from bigdl_tpu.obs.host import HostProbes
 from bigdl_tpu.obs.state_metrics import StateMetricsBooker
 from bigdl_tpu.optim import checkpoint as ckpt
 from bigdl_tpu.optim.metrics import Metrics, SummaryWriter
@@ -508,9 +509,14 @@ class Optimizer:
             for s in self._preempt_signals:
                 old_handlers.append((s, _signal.signal(s, _on_preempt)))
 
+        # the host's alibi for a stalled step (obs/host.py), this run long
+        stalls = self.attribution.stalls
+        stalls.probes = HostProbes(self.metrics).start()
         try:
             return self._optimize_loop(step_engine, state)
         finally:
+            stalls.exclude()  # one still waiting for its witness: unknown
+            stalls.probes.stop()
             if self._recompile is not None:
                 # a later run's warmup compiles must not be flagged
                 self._recompile.mark_warmup()
@@ -578,9 +584,8 @@ class Optimizer:
         retries = 0
         retries_by_cause: Dict[Any, int] = {}
         max_retries = engine.config.failure_retry_times
-        t_loop = time.perf_counter()
         attribution = self.attribution
-        attribution.begin(t_loop)
+        attribution.begin()
         while not self._end_reached(state):
             if self._preempted:
                 # signal landed during epoch-boundary work (validation,
@@ -684,7 +689,7 @@ class Optimizer:
                 # resume point.
                 retries += 1
                 t_fail = time.perf_counter()
-                attribution.end_iteration(t_fail)
+                attribution.end_iteration()
                 # dispatched-but-unfetched bundle results are part of the
                 # rolled-back step chain; drop them so the next log window
                 # never feeds pre-failure losses to the watchdog
@@ -736,8 +741,8 @@ class Optimizer:
                     # MTTR: failure catch → restored-and-ready wall time
                     self.cluster.note_recovered(
                         time.perf_counter() - t_fail)
-                self._last_log = None  # don't count recovery in step time
-                # recovery is not attributable step time either: the failed
+                attribution.stalls.exclude()  # recovery is not step time,
+                # and not attributable step time either: the failed
                 # pass was closed where it failed, the next one starts here
                 self.metrics.reset()
                 attribution.begin()
@@ -960,9 +965,11 @@ class Optimizer:
                         # starting, stopping and reading back a trace is
                         # driver time: booked, not left to "other"
                         with self.attribution.phase("overhead"):
-                            self._profiler.step(
-                                it0 + j, settle=lambda: jax.block_until_ready(
-                                    state["loss"]))
+                            if self._profiler.step(
+                                    it0 + j,
+                                    settle=lambda: jax.block_until_ready(
+                                        state["loss"])):
+                                self.attribution.stalls.exclude()
             xs = [mb[0] for mb in mbs]
             ys = [mb[1] for mb in mbs]
             with self.attribution.phase("dispatch", steps=k, step=it0,
@@ -1027,17 +1034,22 @@ class Optimizer:
         # time — not async dispatch time, which flatters when the in-flight
         # queue hides device latency.  The state's counters come as the
         # bundle's own copy: the state they were in has been donated since.
-        with self.attribution.phase("sync", step=pending[-1].end):
+        with self.attribution.phase("sync", step=pending[-1].end) as sync:
             fetched, counted = jax.device_get((
                 [(b.losses, b.gnorms) for b in pending],
                 pending[-1].counted))
         if self._pending_losses:
             self.metrics.inc("train.fetch_overlapped")
         with self.attribution.phase("overhead"):
+            # the step interval ends where the wait ended, not after the
+            # bookkeeping (obs/attr.py StallWatch: sample, rule, witness)
+            dt = self.attribution.stalls.fetched(
+                sync.end_ns, sync.seconds, sum(b.steps for b in pending),
+                sum(b.steps for b in self._pending_losses), pending[-1].end)
             self._state_metrics.book(counted)
-            self._record_progress(state, pending, fetched)
+            self._record_progress(state, pending, fetched, dt)
 
-    def _record_progress(self, state, pending, fetched):
+    def _record_progress(self, state, pending, fetched, dt):
         """The log point after the fetch: curves, watchdog, step time,
         gauges, the log line, all under the newest FETCHED step's
         iteration number."""
@@ -1065,20 +1077,14 @@ class Optimizer:
                 lv = np.ravel(lv)
                 for j in range(len(lv)):
                     self.watchdog.observe_loss(b.it0 + j, float(lv[j]))
-        now = time.perf_counter()
-        last = getattr(self, "_last_log", None)
-        dt_is_wall = last is not None and it > last[1]
-        if dt_is_wall:
-            dt = (now - last[0]) / (it - last[1])
-        else:  # first window: includes compile; dispatch mean is the best proxy
+        # dt: the wall time between this fetch's return and the one before
+        # over the steps between (exact per step at log_every=1, the
+        # window's mean at a coarser cadence); None where that interval
+        # held a trigger's work or a recovery, and at the first fetch: the
+        # dispatch mean then stands in, for the log line only
+        dt_is_wall = dt is not None
+        if not dt_is_wall:
             dt = self.metrics.mean("step_dispatch")
-        self._last_log = (now, it)
-        # step wall time into the run-lifetime histogram: exact per-step
-        # at log_every=1 (the default); a coarser log cadence records the
-        # WINDOW MEAN once per window, which smooths tails — measuring a
-        # true per-step time would require blocking every dispatch
-        if dt > 0:
-            self.metrics.observe("train.step_time_s", dt)
         if self._bundle_auto and not self._bundle_picked \
                 and dt_is_wall and dt > 0:
             self._pick_bundle_size(dt)
@@ -1180,13 +1186,9 @@ class Optimizer:
                 continue
             setattr(self, last, it)
             self._log_progress(state, flush=True)
-            with phase("overhead") as spent:
+            with phase("overhead"):
                 work(step_engine, state)
-            # trigger work is not step time: shift the log window's start
-            # past it
-            if getattr(self, "_last_log", None) is not None:
-                self._last_log = (self._last_log[0] + spent.seconds,
-                                  self._last_log[1])
+            self.attribution.stalls.exclude()  # trigger work is not step time
 
     def _write_histograms(self, step_engine, state):
         variables = step_engine.get_variables()

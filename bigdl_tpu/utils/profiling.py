@@ -87,14 +87,15 @@ class IterationProfiler:
             table = f"could not be read back ({type(e).__name__}: {e})"
         log.info("device idle time by driver phase: %s", table)
 
-    def step(self, iteration: int, settle=None) -> None:
-        """Call once per training iteration (before the step dispatch).
+    def step(self, iteration: int, settle=None) -> bool:
+        """Call once per training iteration (before the step dispatch);
+        True when it started or stopped the trace (seconds of driver time).
         ``settle`` is called before the trace starts and before it stops:
         a driver that keeps a step in flight waits for it there, so that
         the trace holds whole steps and as many step programs as
         ``train/dispatch`` spans."""
         if self.done:
-            return
+            return False
         starting = not self._active and iteration >= self.start_iter
         stopping = self._active and iteration >= self.stop_iter
         if settle is not None and (starting or stopping):
@@ -103,6 +104,7 @@ class IterationProfiler:
             self._start()
         elif stopping:
             self._stop(f"iters {self.start_iter}-{self.stop_iter - 1}")
+        return starting or stopping
 
     def close(self) -> None:
         """Stop a trace the window left open (training ended inside it);
@@ -118,11 +120,14 @@ class IterationProfiler:
         (``obs.attr.clock_offset``: the k-th ``train/dispatch`` span and
         the k-th run of the program the chip spent most time in),
         ``by_phase`` — the idle seconds by the innermost region of the
-        driver thread that covered them, ``none`` where nothing did.
+        driver thread that covered them, ``none`` where nothing did — and
+        ``stalls``: each ``train/stall`` span of the window on the
+        device's clock (``obs.attr.stall_on_device``, seconds).
         None where the trace holds no device plane (the CPU backend)."""
         from jax.profiler import ProfileData
 
-        from bigdl_tpu.obs.attr import clock_offset, idle_by_phase
+        from bigdl_tpu.obs.attr import (clock_offset, idle_by_phase,
+                                        stall_on_device)
 
         files = sorted(glob.glob(os.path.join(
             self.log_dir, "plugins", "profile", "*", "*.xplane.pb")))
@@ -133,7 +138,9 @@ class IterationProfiler:
                 continue
             for line in plane.lines:
                 if line.name == "XLA Ops":
-                    ops = [(e.start_ns, e.start_ns + e.duration_ns)
+                    # an op's name: "%fusion.1 = (f32[...]) fusion(...)"
+                    ops = [(e.start_ns, e.start_ns + e.duration_ns,
+                            e.name.split(" = ")[0].lstrip("%"))
                            for e in line.events]
                 elif line.name == "XLA Modules":
                     programs = [(e.name.split("(")[0], e.start_ns,
@@ -150,17 +157,22 @@ class IterationProfiler:
             [s.start_ns for s in dispatches],
             [start for name, start, _ in programs or ()
              if name == step_program])
-        driver = []
+        driver, stalls = [], []
         if offset is not None:
             tid = dispatches[0]._tid
             driver = [(s.start_ns - offset, s.end_ns - offset, s.name)
-                      for s in spans if s._tid == tid]
+                      for s in spans
+                      if s._tid == tid and s.name != "train/stall"]
+            stalls = [dict(s.attrs, **stall_on_device(
+                ops, driver, s.start_ns - offset, s.end_ns - offset, 1e-9))
+                for s in spans if s.name == "train/stall"]
         out = idle_by_phase(ops, driver)
         return {"busy": out["busy"] * 1e-9, "idle": out["idle"] * 1e-9,
                 "window": out["window"] * 1e-9,
                 "by_phase": ({k: v * 1e-9
                               for k, v in out["by_phase"].items()}
-                             if driver else None)}
+                             if driver else None),
+                "stalls": stalls}
 
     def __enter__(self) -> "IterationProfiler":
         return self
@@ -185,6 +197,14 @@ def format_idle_table(summary: Optional[dict]) -> str:
                                  key=lambda kv: -kv[1]):
             lines.append(f"  {name:<18} {secs:>8.3f}s"
                          + (f" {secs / idle:>7.1%}" if idle else ""))
+    for st in summary.get("stalls") or ():
+        (op, op_s), (gap_s, phase) = st["longest_op"], st["longest_gap"]
+        lines.append(
+            f"stall at iteration {st.get('iteration')} (the driver says: "
+            f"{st.get('where')}), {st['length']:.3f}s on the device's "
+            f"clock: busy {st['busy']:.3f}s, idle {st['idle']:.3f}s; "
+            f"longest op {op} {op_s:.3f}s; longest gap {gap_s:.3f}s "
+            f"under {phase}")
     return "\n".join(lines)
 
 

@@ -518,32 +518,39 @@ def _train_on(dataset, iterations, batch_size=16):
     return opt
 
 
-def test_data_wait_is_split_where_it_is_spent():
-    """(c) batch_wait + put <= data_wait; a slow producer shows in
-    produce and batch_wait, not in put."""
+@pytest.fixture(scope="module")
+def slow_producer_run():
+    """One run whose producer sleeps 30 ms a batch, beside a fast one."""
     from bigdl_tpu.data import ArrayDataSet
 
     x = np.random.RandomState(0).rand(64, 4).astype(np.float32)
     y = (x.sum(-1) > 2).astype(np.int32)
-    fast = _train_on(ArrayDataSet(x, y), 8)
-    slow = _train_on(_SlowArrays(x, y, 0.03), 8)
-    for opt in (fast, slow):
-        wait = _hist(opt, "data.batch_wait_s")
-        put = _hist(opt, "data.put_s")
-        total = _hist(opt, "train.data_wait_s")
-        assert wait[0] == put[0] == 8
-        assert _hist(opt, "data.produce_s")[0] >= 8
-        assert wait[1] + put[1] <= total[1]
-    # 8 batches x 30 ms of sleep, in the producer's thread
-    assert _hist(slow, "data.produce_s")[1] >= 8 * 0.03
-    assert _hist(fast, "data.produce_s")[1] < 8 * 0.03
-    # the driver waited for most of it (the first steps compile meanwhile)
-    assert _hist(slow, "data.batch_wait_s")[1] >= 4 * 0.03
-    assert _hist(slow, "data.batch_wait_s")[1] > \
-        10 * _hist(fast, "data.batch_wait_s")[1]
-    # and the put did not get slower
-    assert _hist(slow, "data.put_s")[1] < 0.1 + \
-        3 * _hist(fast, "data.put_s")[1]
+    return {"fast": _train_on(ArrayDataSet(x, y), 8),
+            "slow": _train_on(_SlowArrays(x, y, 0.03), 8)}
+
+
+@pytest.mark.parametrize("case", ["parts_within_the_wait", "produce",
+                                  "batch_wait"])
+def test_data_wait_is_split_where_it_is_spent(slow_producer_run, case):
+    """(c) batch_wait + put <= data_wait; a slow producer shows in
+    produce and batch_wait.  Only what the sleeps guarantee is asserted:
+    ratios between two runs on a shared machine are not (PERF.md §7)."""
+    slow = slow_producer_run["slow"]
+    if case == "parts_within_the_wait":
+        for opt in slow_producer_run.values():
+            wait = _hist(opt, "data.batch_wait_s")
+            put = _hist(opt, "data.put_s")
+            total = _hist(opt, "train.data_wait_s")
+            assert wait[0] == put[0] == 8
+            assert _hist(opt, "data.produce_s")[0] >= 8
+            assert wait[1] + put[1] <= total[1]
+    elif case == "produce":
+        # 8 batches x 30 ms of sleep, in the producer's thread
+        assert _hist(slow, "data.produce_s")[1] >= 8 * 0.03
+    else:
+        # the driver waited for most of it (the first steps compile
+        # meanwhile)
+        assert _hist(slow, "data.batch_wait_s")[1] >= 4 * 0.03
 
 
 def test_epoch_first_wait_once_per_epoch():
@@ -735,3 +742,413 @@ def test_profiler_summary_lays_driver_spans_over_a_recorded_tpu_trace(
     cut = prof.summary()
     assert cut["by_phase"] is None and cut["busy"] == out["busy"]
     assert "not aligned" in format_idle_table(cut)
+
+
+# ---------------------------------------------------------------------------
+# stalls (ISSUE 36): the rule on an injected clock, the host's alibi, the
+# device's view
+# ---------------------------------------------------------------------------
+
+def _feed(watch, intervals, steps=1, in_flight=1, t_ns=0, it=0,
+          outside=0.01):
+    """Fetch returns ``intervals`` seconds apart, from ``t_ns``, each
+    after a wait of all of its interval but ``outside`` seconds."""
+    for dt in intervals:
+        t_ns += int(round(dt * 1e9))
+        it += steps
+        watch.fetched(t_ns, max(dt - outside, 0.0), steps, in_flight, it)
+    return t_ns, it
+
+
+def _warm(watch, n=10, step_s=0.2, steps=1):
+    """The first fetch (no sample) and ``n`` ordinary intervals."""
+    watch.fetched(0, 0.0, steps, 1, steps)
+    return _feed(watch, [step_s * steps] * n, steps=steps, it=steps)
+
+
+def _stall_events():
+    return [e for e in flight.global_recorder().snapshot()
+            if e["kind"] == "train_stall"]
+
+
+@pytest.mark.parametrize("after, where, lost, host_s, device_s", [
+    # long, near-zero, normal: the device had run ahead; both intervals'
+    # excess is the loss
+    ([0.01, 0.2], "host", (2.2 - 0.2) + (0.01 - 0.2), True, False),
+    # long, normal: step i+1 could only start when i ended
+    ([0.2, 0.2], "device", 2.2 - 0.2, False, True),
+    # the run ends on the long fetch: nobody saw what came next
+    ([], "unknown", 2.2 - 0.2, False, False),
+])
+def test_stall_rule_and_its_witness(after, where, lost, host_s, device_s):
+    m = Metrics()
+    w = obs_attr.StallWatch(m)
+    t, it = _warm(w)
+    assert m.counter("train.stalls") == 0 and not _stall_events()
+    t, it = _feed(w, [2.2], t_ns=t, it=it)
+    assert m.counter("train.stalls") == 0       # waits for its witness
+    _feed(w, after, t_ns=t, it=it)
+    w.exclude()                                  # the flush where a run ends
+    (e,) = _stall_events()
+    assert e["where"] == where and e["iteration"] == 12
+    assert e["interval_s"] == pytest.approx(2.2)
+    assert e["baseline_s"] == pytest.approx(0.2)
+    assert e["lost_s"] == pytest.approx(lost)
+    assert e["steps"] == 1 and e["in_flight"] == 1 and e["alibi"] == {}
+    assert e["waited_s"] == pytest.approx(2.19)
+    assert m.counter("train.stalls") == 1
+    assert m.counter("train.stall_s") == pytest.approx(lost)
+    assert m.counter("train.stall_host_s") == \
+        pytest.approx(lost if host_s else 0.0)
+    assert m.counter("train.stall_device_s") == \
+        pytest.approx(lost if device_s else 0.0)
+    # every interval is in the program's one histogram, the stall too
+    assert m.hists["train.step_time_s"].n == 11 + len(after)
+    assert m.hists["train.step_time_s"].sum == \
+        pytest.approx(10 * 0.2 + 2.2 + sum(after))
+    # the run-ahead interval and the stall stay out of the baseline
+    assert list(w._recent).count(pytest.approx(0.2)) == len(w._recent)
+
+
+def test_stall_rule_scales_with_the_steps_a_fetch_covers():
+    m = Metrics()
+    w = obs_attr.StallWatch(m)
+    t, it = _warm(w, steps=4)                    # 0.8 s a fetch of 4
+    assert m.hists["train.step_time_s"].sum == pytest.approx(10 * 0.2)
+    # 1.0 s for four steps: 0.2 s over, under max(0.25, 0.2); for one
+    # step it would have been a stall
+    t, it = _feed(w, [1.0], steps=4, t_ns=t, it=it)
+    t, it = _feed(w, [0.8], steps=4, t_ns=t, it=it)
+    assert m.counter("train.stalls") == 0
+    # 1.3 s: half a second over four baselines
+    t, it = _feed(w, [1.3], steps=4, in_flight=4, t_ns=t, it=it)
+    # the bundle in flight ran ahead: the next comes in four steps short
+    _feed(w, [0.05], steps=4, in_flight=4, t_ns=t, it=it)
+    (e,) = _stall_events()
+    assert e["where"] == "host" and e["steps"] == 4
+    assert e["baseline_s"] == pytest.approx(0.2)
+    assert e["lost_s"] == pytest.approx((1.3 - 0.8) + (0.05 - 0.8))
+
+
+def test_witness_counts_the_steps_that_were_in_flight():
+    """At a coarser log cadence one bundle of a fetch's four is ahead:
+    the next interval is short by that one step, not near zero."""
+    for short, where in ((0.8 - 0.19, "host"), (0.8 - 0.05, "device")):
+        flight.global_recorder().clear()
+        w = obs_attr.StallWatch(Metrics())
+        t, it = _warm(w, steps=4)
+        t, it = _feed(w, [3.0], steps=4, in_flight=1, t_ns=t, it=it)
+        _feed(w, [short], steps=4, in_flight=1, t_ns=t, it=it)
+        assert [e["where"] for e in _stall_events()] == [where]
+    # nothing in flight (a flush): nothing could have run ahead
+    flight.global_recorder().clear()
+    w = obs_attr.StallWatch(Metrics())
+    t, it = _warm(w)
+    t, it = _feed(w, [3.0], in_flight=0, t_ns=t, it=it)
+    _feed(w, [0.2], t_ns=t, it=it)
+    assert [e["where"] for e in _stall_events()] == ["unknown"]
+
+
+@pytest.mark.parametrize("after", [[0.2, 0.2], []])
+def test_seconds_lost_outside_the_fetch_are_the_hosts(after):
+    """The driver froze before it queued the next bundle (the chip showed
+    it, PERF.md §6 PR 36): the fetch then returns at once and the next
+    interval is an ordinary one, which alone would read `device`."""
+    m = Metrics()
+    w = obs_attr.StallWatch(m)
+    t, it = _warm(w)
+    t, it = _feed(w, [0.76], outside=0.755, t_ns=t, it=it)
+    _feed(w, after, t_ns=t, it=it)
+    w.exclude()
+    (e,) = _stall_events()
+    assert e["where"] == "host" and e["waited_s"] == pytest.approx(0.005)
+    assert e["lost_s"] == pytest.approx(0.56)
+    assert m.counter("train.stall_host_s") == pytest.approx(0.56)
+    assert m.counter("train.stall_device_s") == 0
+
+
+def test_no_stall_is_judged_before_eight_samples():
+    m = Metrics()
+    w = obs_attr.StallWatch(m)
+    t, it = _warm(w, n=7)
+    t, it = _feed(w, [5.0, 0.2], t_ns=t, it=it)  # the eighth sample
+    assert m.counter("train.stalls") == 0 and not _stall_events()
+    assert m.hists["train.step_time_s"].n == 9
+    # from here the median of those nine judges: 0.2 s
+    t, it = _feed(w, [5.0, 0.2], t_ns=t, it=it)
+    assert m.counter("train.stalls") == 1
+
+
+@pytest.mark.parametrize("what", ["compile", "trigger", "recovery",
+                                  "first"])
+def test_booked_intervals_are_neither_samples_nor_stalls(what):
+    m = Metrics()
+    w = obs_attr.StallWatch(m)
+    if what == "first":
+        # the first window: no interval yet, and nothing stands in for it
+        assert w.fetched(5_000_000_000, 4.9, 1, 1, 1) is None
+        assert "train.step_time_s" not in m.hists
+        return
+    t, it = _warm(w)
+    if what == "compile":
+        obs_attr._note_compile(0.5)   # the driver thread compiled
+        # wall time for the caller (the log line, MFU), not a sample
+        assert w.fetched(t + 3_000_000_000, 2.9, 1, 1, it + 1) == \
+            pytest.approx(3.0)
+    else:
+        w.exclude()                   # a checkpoint was written / a resume
+        assert w.fetched(t + 3_000_000_000, 2.9, 1, 1, it + 1) is None
+    t, it = _feed(w, [0.2, 0.2], t_ns=t + 3_000_000_000, it=it + 1)
+    assert m.counter("train.stalls") == 0 and not _stall_events()
+    assert m.hists["train.step_time_s"].n == 12
+    assert m.hists["train.step_time_s"].max == pytest.approx(0.2)
+
+
+def test_an_excluded_interval_is_no_witness():
+    m = Metrics()
+    w = obs_attr.StallWatch(m)
+    t, it = _warm(w)
+    t, it = _feed(w, [2.2], t_ns=t, it=it)
+    w.exclude()                       # a trigger's work came next
+    (e,) = _stall_events()
+    assert e["where"] == "unknown"
+    assert m.counter("train.stall_s") == pytest.approx(2.0)
+    assert m.counter("train.stall_host_s") == 0
+    assert m.counter("train.stall_device_s") == 0
+
+
+def _host_probe_leftovers():
+    import gc
+    import threading
+
+    from bigdl_tpu.obs.host import HostProbes
+
+    return ([cb for cb in gc.callbacks
+             if isinstance(getattr(cb, "__self__", None), HostProbes)],
+            [t for t in threading.enumerate() if t.name == "obs-heartbeat"])
+
+
+@pytest.mark.parametrize("leaves", ["returns", "raises"])
+def test_host_probes_live_as_long_as_optimize(leaves):
+    """The counters and the gc histogram exist at 0 when optimize() starts,
+    a collection inside the run is booked, and the hook and the heartbeat
+    are gone when it returns or raises."""
+    import gc
+
+    from bigdl_tpu import nn, optim
+    from bigdl_tpu.data import ArrayDataSet
+
+    seen = {}
+
+    def end_when(state):
+        if not seen:
+            snap = opt.metrics.snapshot()
+            seen["counters"] = {k: snap["counters"].get(k)
+                                for k in obs_attr.STALL_COUNTERS}
+            seen["hists"] = set(snap["hists"])
+            seen["global"] = set(global_metrics().snapshot()["hists"])
+            seen["alive"] = _host_probe_leftovers()
+        if state["iteration"] == 3:
+            gc.collect()
+            if leaves == "raises":
+                raise RuntimeError("the run ends here")
+        return state["iteration"] >= 6
+
+    x = np.random.RandomState(0).rand(64, 4).astype(np.float32)
+    y = (x.sum(-1) > 2).astype(np.int32)
+    model = nn.Sequential([nn.Linear(4, 8), nn.ReLU(), nn.Linear(8, 2),
+                           nn.LogSoftMax()])
+    opt = optim.Optimizer(model, ArrayDataSet(x, y), nn.ClassNLLCriterion(),
+                          batch_size=16)
+    opt.set_end_when(optim.Trigger(end_when, "test"))
+    if leaves == "raises":
+        with pytest.raises(RuntimeError, match="ends here"):
+            opt.optimize()
+    else:
+        opt.optimize()
+    assert seen["counters"] == dict.fromkeys(obs_attr.STALL_COUNTERS, 0.0)
+    for name in ("host.gc_pause_s", "host.heartbeat_late_s"):
+        assert name in seen["hists"] and name in seen["global"]
+    hooks, threads = seen["alive"]
+    assert len(hooks) == 1 and len(threads) == 1
+    assert _host_probe_leftovers() == ([], [])
+    n, total = _hist(opt, "host.gc_pause_s")
+    assert n >= 1 and total > 0
+    assert opt.metrics.counter('host.gc_collections{generation="2"}') >= 1
+
+
+def test_heartbeat_reports_the_lateness_it_was_given():
+    import threading
+
+    from bigdl_tpu.obs.host import BEAT_S, HostProbes
+
+    now = [1_000_000_000]
+    lates = [0.0, 0.004, 1.5, 0.0, 0.03]
+    done = threading.Event()
+
+    def sleeper(seconds):
+        assert seconds == BEAT_S
+        if not lates:
+            done.set()
+            probes._stop.wait(10.0)   # until stop(); nothing sleeps
+            return
+        now[0] += int((seconds + lates.pop(0)) * 1e9)
+
+    m = Metrics()
+    probes = HostProbes(m, sleep=sleeper, clock_ns=lambda: now[0])
+    probes.start()
+    try:
+        assert done.wait(10.0)
+        # over 10 ms late is observed; on time and 4 ms are not
+        assert m.hists["host.heartbeat_late_s"].n == 2
+        assert m.hists["host.heartbeat_late_s"].sum == pytest.approx(1.53)
+        # a stall's record carries the largest lateness of a beat that
+        # slept into its interval: the third beat woke at 1.0 + 0.05 +
+        # 0.054 + 1.55 s
+        woke = 1_000_000_000 + 1_654_000_000
+        a = probes.alibi(woke - 1_400_000_000, woke - 100_000_000)
+        assert a["heartbeat_late_s"] == pytest.approx(1.5)
+        assert probes.alibi(0, 900_000_000)["heartbeat_late_s"] == 0.0
+        assert a["gc_collections"] == 0 and a["gc_pause_s"] == 0.0
+        # what the kernel says, each only where readable: the rusage
+        # counts always are
+        assert a["major_faults"] >= 0 and a["involuntary_switches"] >= 0
+        assert a["kernel_over_s"] >= 0
+    finally:
+        probes.stop()
+    assert not probes._thread.is_alive()
+    assert _host_probe_leftovers() == ([], [])
+
+
+def test_gc_pauses_are_booked_off_the_collecting_thread():
+    """The callback only stamps (it may run inside a registry's lock); the
+    booking is the heartbeat's or the alibi's."""
+    import gc
+
+    from bigdl_tpu.obs.host import HostProbes
+
+    m = Metrics()
+    probes = HostProbes(m, sleep=lambda s: probes._stop.wait(10.0))
+    probes.start()
+    try:
+        t0 = probes._clock()
+        gc.collect()
+        assert len(probes._gc_done) >= 1       # stamped, not yet booked
+        a = probes.alibi(t0, probes._clock())
+        assert a["gc_collections"] >= 1 and a["gc_pause_s"] > 0
+        assert m.hists["host.gc_pause_s"].n >= 1
+        assert m.counter('host.gc_collections{generation="2"}') >= 1
+    finally:
+        probes.stop()
+    assert _host_probe_leftovers() == ([], [])
+
+
+def test_a_jump_of_the_clock_is_one_stall_through_a_real_run(monkeypatch):
+    """A real tiny Optimizer run whose clock jumps ahead by two seconds
+    once (nothing sleeps): one train_stall event with every field, one
+    train/stall span."""
+    from bigdl_tpu import optim
+    from bigdl_tpu.obs import trace
+
+    real, jump = trace.now_ns, [0]
+    monkeypatch.setattr(trace, "now_ns", lambda: real() + jump[0])
+    one_bundle = optim.Optimizer._one_bundle
+
+    def jumping(self, step_engine, state, mbs):
+        if state["iteration"] == 25:
+            jump[0] += 2_000_000_000
+        return one_bundle(self, step_engine, state, mbs)
+
+    monkeypatch.setattr(optim.Optimizer, "_one_bundle", jumping)
+    spans = trace.Tracer()
+    trace.collect_into(spans)
+    try:
+        opt = _train(monkeypatch, iterations=40)
+    finally:
+        trace.collect_into(None)
+    # a loaded machine may add a stall of its own; the jump is the one of
+    # two seconds
+    (e,) = [e for e in _stall_events() if e["interval_s"] >= 2.0]
+    assert set(e) >= {"iteration", "steps", "in_flight", "interval_s",
+                      "waited_s", "baseline_s", "lost_s", "where", "alibi"}
+    assert e["iteration"] == 25 and e["steps"] == 1 and e["in_flight"] == 1
+    assert 2.0 <= e["interval_s"] < 2.5 and 0 < e["baseline_s"] < 0.25
+    assert e["lost_s"] == pytest.approx(e["interval_s"] - e["baseline_s"],
+                                        abs=0.3)
+    assert e["where"] in ("device", "host")
+    # the heartbeat reads the same clock: it woke two seconds late, which
+    # on a chip says the interpreter or the process was held
+    alibi = e["alibi"]
+    assert alibi["heartbeat_late_s"] >= 1.9
+    assert {"gc_pause_s", "gc_collections", "kernel_over_s",
+            "major_faults", "involuntary_switches"} <= set(alibi)
+    (s,) = [s for s in spans.spans() if s.name == "train/stall"
+            and s.end_ns - s.start_ns >= 2_000_000_000]
+    assert s.attrs["where"] == e["where"] and s.attrs["iteration"] == 25
+    assert opt.metrics.counter("train.stalls") >= 1
+    assert opt.metrics.counter("train.stall_s") >= 1.5
+    # the first window's dispatch-mean proxy is no longer a sample: 39
+    # intervals of 40 fetches, less those that held a compile
+    assert _hist(opt, "train.step_time_s")[0] <= 39
+
+
+@pytest.mark.parametrize("case", ["device_late", "device_ran_ahead"])
+def test_stall_on_device_on_hand_made_intervals(case):
+    host = [(0, 100, "train/sync"), (100, 104, "train/overhead"),
+            (104, 110, "train/dispatch"), (110, 140, "train/sync")]
+    if case == "device_late":
+        # one op of 90 where a step takes 30
+        ops = [(0, 10, "fusion.1"), (10, 100, "fusion.2"),
+               (100, 130, "fusion.1")]
+        v = obs_attr.stall_on_device(ops, host, 0, 100)
+        assert v["busy"] == 100 and v["idle"] == 0
+        assert v["longest_op"] == ("fusion.2", 90)
+        assert v["longest_gap"][0] == 0
+    else:
+        # both steps done by 60; the chip waits for the host to wake
+        ops = [(-5, 30, "fusion.1"), (30, 60, "fusion.1"),
+               (108, 138, "fusion.1")]
+        v = obs_attr.stall_on_device(ops, host, 0, 100)
+        assert v["busy"] == 60 and v["idle"] == 40
+        assert v["longest_op"] == ("fusion.1", 30)
+        assert v["longest_gap"] == (40, "train/sync")
+
+
+def test_profiler_summary_reads_a_stall_on_the_devices_clock(tmp_path):
+    """A train/stall span over the recorded trace's first step: the
+    summary's paragraph for it is on the device's clock."""
+    import shutil
+
+    from bigdl_tpu.utils.profiling import IterationProfiler, \
+        format_idle_table
+
+    recorded = os.path.join(REPO, "benchmark", "tests", "data",
+                            "resnet50_steps.xplane.pb")
+    if not os.path.isfile(recorded):
+        pytest.skip("no recorded trace in this checkout")
+    run_dir = tmp_path / "plugins" / "profile" / "2026_10_04"
+    run_dir.mkdir(parents=True)
+    shutil.copy(recorded, run_dir / "host.xplane.pb")
+    prof = IterationProfiler(str(tmp_path))
+    host = 5_000_000_000_000
+    p0, p1 = 177_662_171, 352_499_444
+    d0, d1 = host + p0 - 300_000, host + p1 - 300_000
+    for name, a, b in (("train/dispatch", d0, d0 + 2_000_000),
+                       ("train/sync", d0 + 2_000_000, d1 - 1_000_000),
+                       ("train/dispatch", d1, d1 + 2_000_000)):
+        prof._spans.add_span(name, a, b)
+    prof._spans.add_span("train/stall", d0 + 2_000_000, d1 - 1_000_000,
+                         where="host", iteration=7, lost_s=0.04)
+    out = prof.summary()
+    (st,) = out["stalls"]
+    assert st["where"] == "host" and st["iteration"] == 7
+    assert st["busy"] + st["idle"] == pytest.approx(st["length"])
+    assert st["length"] == pytest.approx((p1 - p0 - 3_000_000) * 1e-9)
+    # the step's 127.6 ms of ops, then the 47 ms the chip waited, in sync
+    assert st["longest_gap"][1] == "train/sync"
+    assert st["longest_gap"][0] == pytest.approx(0.046, abs=2e-3)
+    assert 0 < st["longest_op"][1] < 0.02
+    # the stall is not a driver phase of the idle table
+    assert "train/stall" not in out["by_phase"]
+    text = format_idle_table(out)
+    assert "stall at iteration 7" in text and "under train/sync" in text
