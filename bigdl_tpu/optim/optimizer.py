@@ -981,6 +981,11 @@ class Optimizer:
             # bundle sizes (the auto-K pick reads it)
             self.metrics.add("step_dispatch", disp.seconds / k)
         self._last_dispatch_end = time.perf_counter()
+        # every step dispatched, and those dispatched to a program that
+        # carries its state leaf-shaped (train_step.py: one shard)
+        self.metrics.inc("train.updates", k)
+        self.metrics.inc("train.leaf_updates",
+                         k if step_engine.leaf_state else 0)
         if self._recompile is not None:
             self._recompile.note_step(it0 + k)
         # collective-bytes ledger: every dispatched step moves the same

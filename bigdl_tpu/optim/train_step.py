@@ -129,9 +129,54 @@ def _check_seq_parallel_model(model) -> None:
             "make sure custom attention uses the seq-axis collectives")
 
 
+def _flat_layout(params):
+    """``(flat vector's shape and dtype, unravel)`` of a parameter pytree
+    as ``ravel_pytree`` lays it out, without making the vector."""
+    unravel = []
+
+    def ravel(tree):
+        flat, fn = ravel_pytree(tree)
+        unravel.append(fn)
+        return flat
+
+    return jax.eval_shape(ravel, params), unravel[0]
+
+
+def _host_zeros(tree):
+    return jax.tree_util.tree_map(
+        lambda x: np.zeros(jnp.shape(x), x.dtype), tree)
+
+
+def _mask_leaves(params, trainable_mask):
+    """``[(parameter leaf, its bool mask as given)]``; a mask leaf is a
+    per-leaf scalar or broadcasts to the parameter's shape."""
+    leaves_p = jax.tree_util.tree_leaves(params)
+    leaves_m = jax.tree_util.tree_leaves(trainable_mask)
+    if len(leaves_p) != len(leaves_m):
+        raise ValueError(
+            "trainable_mask structure does not match params "
+            f"({len(leaves_m)} leaves vs {len(leaves_p)})")
+    pairs = [(p, np.asarray(m, bool)) for p, m in zip(leaves_p, leaves_m)]
+    for p, m in pairs:
+        np.broadcast_to(m, np.shape(p))  # raises where it cannot
+    return pairs
+
+
 class ShardedParameterStep:
     """Builds the jitted ZeRO-1 train/eval steps for a model+criterion over a
-    mesh.  Owns the flat-parameter layout (the ``AllReduceParameter`` role)."""
+    mesh.  Owns the flat-parameter layout (the ``AllReduceParameter`` role).
+
+    The layout of the state the step programs carry follows the number of
+    shards (``leaf_state``, decided once here from the mesh and the
+    ``OptimMethod``; no option).  On several shards, and for layerwise
+    methods, parameters / EMA / optimizer state are flat vectors: what
+    ``psum_scatter`` and ``all_gather`` need.  Where the parameters live
+    on ONE shard (data axis 1, no ``dcn_data`` or ``seq`` axis over 1) and
+    the method is elementwise, there is no wire, and the programs carry
+    pytrees shaped like the model's parameters: no flat gradient is
+    assembled and no flat vector is cut up.  Either way the flat vector
+    is the wire and disk format: ``flat_params``, ``ema_flat`` and
+    ``opt_state`` read and assign it (docs/parallelism.md)."""
 
     def __init__(self, model, criterion, optim_method, mesh: Mesh,
                  init_variables: Dict[str, Any],
@@ -287,8 +332,10 @@ class ShardedParameterStep:
                     "(init_engine(seq=N))")
             _check_seq_parallel_model(model)
 
-        flat, self.unravel = ravel_pytree(init_variables["params"])
-        self.n_real = flat.shape[0]
+        # the flat layout (ravel_pytree's leaf order): the wire and disk
+        # format on any mesh, and what several shards compute on
+        flat_aval, self.unravel = _flat_layout(init_variables["params"])
+        self.n_real = flat_aval.shape[0]
         self.n_pad = -(-self.n_real // self.ndev) * self.ndev
         self.shard_size = self.n_pad // self.ndev
         # gradient-sync bucket table: contiguous column ranges of the
@@ -299,53 +346,72 @@ class ShardedParameterStep:
             collectives.wire_itemsize(self.grad_comm),
             self.quant_block if self.grad_comm == "int8" else None)
 
+        self._rep = NamedSharding(mesh, P())
+        self._sharded_vec = NamedSharding(mesh, P(AXIS_DATA))
+        self._batch_sh = NamedSharding(mesh, P(self._batch_axes))
+
+        # the rule: parameters that live on ONE shard have no wire to
+        # cross, so the step programs carry them (and the EMA, the
+        # optimizer's state, the mask) shaped like the model's leaves;
+        # anything else carries flat vectors.  Chosen once, here
+        self.leaf_state = (self.ndev == 1 and self.dcn == 1
+                           and self.n_seq == 1 and self.optim.elementwise)
+        init_state = (self._init_leaf_state if self.leaf_state
+                      else self._init_flat_state)
+        init_state(init_variables["params"], trainable_mask,
+                   flat_aval.dtype)
+        self.model_state = jax.device_put(init_variables.get("state", {}),
+                                          self._rep)
+        # host-side structure templates for checkpoint load (safe to use even
+        # when device buffers were consumed by a failed donated step)
+        self.model_state_template = _host_zeros(
+            init_variables.get("state", {}))
+
+        # seq_parallel specs depend on leaf ranks (which dims shard), so
+        # the jitted step is built lazily on the first batch
+        self._train = None if self.seq_parallel else self._build_train()
+        self._eval_cache: Dict[Any, Callable] = {}
+        # fused multi-step programs, one per distinct bundle size (the
+        # driver's remainder bundles compile once per K' and are reused)
+        self._bundle_cache: Dict[Any, Callable] = {}
+        self._base_key = None  # set_step_seed: device-resident PRNG root
+
+    # -- the two layouts of the carried state ---------------------------
+    def _init_flat_state(self, params, trainable_mask, dtype) -> None:
+        """Several shards (or a layerwise method): ``_params`` / ``_ema``
+        are flat vectors replicated over the mesh, ``_opt`` the
+        optimizer's state on this shard's slice, ``_mask`` the trainable
+        mask as a vector (the scalar 1 when everything trains)."""
         # partial-training mask (LoRA / linear probe / freezing): a pytree
         # matching params with bool leaves (per-leaf scalars, e.g.
         # nn.lora.lora_filter, or per-element arrays).  Frozen entries get
         # zero gradient (optimizer moments stay clean) AND are restored
         # bitwise after the update (weight decay cannot drift them).
-        self._mask_flat = None
+        self._mask = jnp.asarray(1.0, jnp.float32)
         if trainable_mask is not None:
-            import numpy as _np
-
-            leaves_p = jax.tree_util.tree_leaves(init_variables["params"])
-            leaves_m = jax.tree_util.tree_leaves(trainable_mask)
-            if len(leaves_p) != len(leaves_m):
-                raise ValueError(
-                    "trainable_mask structure does not match params "
-                    f"({len(leaves_m)} leaves vs {len(leaves_p)})")
-            parts = [_np.broadcast_to(
-                _np.asarray(m, bool), _np.shape(p)).reshape(-1)
-                for p, m in zip(leaves_p, leaves_m)]
-            mask = _np.concatenate(parts).astype(_np.float32)
-            self._mask_flat = jnp.pad(jnp.asarray(mask),
-                                      (0, self.n_pad - self.n_real))
-
-        self._rep = NamedSharding(mesh, P())
-        self._sharded_vec = NamedSharding(mesh, P(AXIS_DATA))
-        self._batch_sh = NamedSharding(mesh, P(self._batch_axes))
+            parts = [np.broadcast_to(m, np.shape(p)).reshape(-1)
+                     for p, m in _mask_leaves(params, trainable_mask)]
+            mask = np.concatenate(parts).astype(np.float32)
+            self._mask = jnp.pad(jnp.asarray(mask),
+                                 (0, self.n_pad - self.n_real))
 
         # initial device state.  The flat vector is the only copy of the
         # parameters this object makes (0.6 B parameters are 2.4 GB a
         # copy): padded only if the shards need it, never kept twice
-        dtype = flat.dtype
+        flat, _ = ravel_pytree(params)
         if self.n_pad != self.n_real:
             flat = jnp.pad(flat, (0, self.n_pad - self.n_real))
-        self.flat_params = jax.device_put(flat, self._rep)
+        self._params = jax.device_put(flat, self._rep)
         del flat
-        self.model_state = jax.device_put(init_variables.get("state", {}),
-                                          self._rep)
         # jnp.copy: device_put of an already-placed array is a no-op and
-        # would ALIAS ema to flat_params (double donation)
-        self.ema_flat = (jax.device_put(jnp.copy(self.flat_params),
-                                        self._rep)
-                         if self.ema_decay else None)
-        # EMA disabled: a distinct 1-element buffer rides the donated slot
-        # (donating flat_params twice is an XLA error); it is re-captured
-        # from the step output each iteration (donation aliases it through)
-        self._ema_dummy = (None if self.ema_decay else
-                           jax.device_put(jnp.zeros((1,), dtype),
-                                          self._rep))
+        # would ALIAS ema to the parameters (double donation).  EMA
+        # disabled: a distinct 1-element buffer rides the donated slot
+        # (donating the parameters twice is an XLA error); it is
+        # re-captured from the step output each iteration (donation
+        # aliases it through)
+        self._ema = jax.device_put(
+            jnp.copy(self._params) if self.ema_decay
+            else jnp.zeros((1,), dtype), self._rep)
         if self.optim.elementwise:
             # made in place, sharded, by one program: no zeros vector
             # beside the moments and no copy into the sharding
@@ -368,26 +434,118 @@ class ShardedParameterStep:
                         f"({self.n_pad},)); {type(self.optim).__name__} "
                         f"has leaves shaped {bad} — use "
                         "comm_bucket_bytes=None with this OptimMethod")
-            self.opt_state = jax.jit(
+            self._opt = jax.jit(
                 init_opt, out_shardings=self._sharded_vec)()
+            self._opt_spec = P(AXIS_DATA)
         else:
-            opt_state = self.optim.init_state(init_variables["params"])
-            self.opt_state = jax.device_put(opt_state, self._rep)
-        # host-side structure templates for checkpoint load (safe to use even
-        # when device buffers were consumed by a failed donated step)
-        _z = lambda t: jax.tree_util.tree_map(
-            lambda x: np.zeros(jnp.shape(x), x.dtype), t)
-        self.opt_template = _z(opt_state)
-        self.model_state_template = _z(init_variables.get("state", {}))
+            opt_state = self.optim.init_state(params)
+            self._opt = jax.device_put(opt_state, self._rep)
+            self._opt_spec = P()
+        self.opt_template = _host_zeros(opt_state)
+        unravel, n_real = self.unravel, self.n_real
+        self._params_of = lambda flat_p: unravel(flat_p[:n_real])
+        # the carried vectors ARE the wire format
+        self._wire = self._carry = self._opt_wire = self._opt_carry = \
+            lambda state: state
 
-        # seq_parallel specs depend on leaf ranks (which dims shard), so
-        # the jitted step is built lazily on the first batch
-        self._train = None if self.seq_parallel else self._build_train()
-        self._eval_cache: Dict[Any, Callable] = {}
-        # fused multi-step programs, one per distinct bundle size (the
-        # driver's remainder bundles compile once per K' and are reused)
-        self._bundle_cache: Dict[Any, Callable] = {}
-        self._base_key = None  # set_step_seed: device-resident PRNG root
+    def _init_leaf_state(self, params, trainable_mask, dtype) -> None:
+        """One shard: ``_params`` / ``_ema`` / ``_opt`` / ``_mask`` are
+        pytrees shaped like the model's parameters (no EMA, no mask:
+        ``None``).  The flat vector is made only where something reads
+        ``flat_params`` / ``ema_flat`` / ``opt_state`` (a checkpoint, a
+        peer publish: triggers, never a timed step) and cut up only where
+        something assigns them."""
+        optim, rep, unravel, n_real = (self.optim, self._rep, self.unravel,
+                                       self.n_real)
+        tmap = jax.tree_util.tree_map
+        # frozen entries: mask leaves stay as given (a per-leaf scalar
+        # stays a scalar), as the float the gradient is multiplied by
+        self._mask = None
+        if trainable_mask is not None:
+            self._mask = jax.device_put(jax.tree_util.tree_unflatten(
+                jax.tree_util.tree_structure(params),
+                [m.astype(np.float32)
+                 for _, m in _mask_leaves(params, trainable_mask)]), rep)
+        # own the buffers: the caller may keep ``init_variables`` alive
+        # (and the programs donate what they are handed), so every leaf
+        # is copied, one at a time: the only copy this object makes
+        self._params = jax.device_put(tmap(jnp.copy, params), rep)
+        self._ema = (jax.device_put(tmap(jnp.copy, self._params), rep)
+                     if self.ema_decay else None)
+        shapes = tmap(lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype),
+                      self._params)
+        # made in place by one program: no zeros beside the moments
+        self._opt = jax.jit(
+            lambda: optim.init_state(
+                tmap(lambda s: jnp.zeros(s.shape, s.dtype), shapes)),
+            out_shardings=rep)()
+        self._opt_spec = P()
+        # the disk format's template: the state of a flat vector
+        self.opt_template = _host_zeros(jax.eval_shape(
+            lambda: optim.init_state(jnp.zeros((n_real,), dtype))))
+        self._params_of = lambda tree: tree
+        to_flat = jax.jit(lambda tree: ravel_pytree(tree)[0],
+                          out_shardings=rep)
+        to_tree = jax.jit(lambda flat: unravel(flat[:n_real]),
+                          out_shardings=rep)
+
+        def carry(flat):
+            if jnp.ndim(flat) != 1 or flat.shape[0] < n_real:
+                raise ValueError(
+                    f"a flat vector of shape {jnp.shape(flat)} for a "
+                    f"model of {n_real} parameters")
+            return to_tree(flat)
+
+        # a leaf of the flat state the size of the vector stands for a
+        # whole subtree shaped like the parameters; any other (a scalar
+        # statistic) is itself in both formats
+        template = self.opt_template
+
+        def per_vector(convert):
+            return lambda state: tmap(
+                lambda tmpl, sub: (convert(sub)
+                                   if tmpl.shape == (n_real,) else sub),
+                template, state)
+
+        self._wire, self._carry = to_flat, carry
+        self._opt_wire, self._opt_carry = per_vector(to_flat), \
+            per_vector(carry)
+
+    @property
+    def flat_params(self):
+        """The parameters as ONE flat vector (``n_pad`` elements, in
+        ``ravel_pytree``'s leaf order, which is ``self.unravel``'s): the
+        wire and disk format.  What the step programs carry on several
+        shards; raveled on read and cut up on assignment on one."""
+        return self._wire(self._params)
+
+    @flat_params.setter
+    def flat_params(self, flat):
+        self._params = None  # the old copy goes before the new one comes
+        self._params = self._carry(flat)
+
+    @property
+    def ema_flat(self):
+        """The parameters' moving average in the format of
+        ``flat_params``; ``None`` without ``ema_decay``."""
+        return self._wire(self._ema) if self.ema_decay else None
+
+    @ema_flat.setter
+    def ema_flat(self, flat):
+        self._ema = None
+        self._ema = self._carry(flat)
+
+    @property
+    def opt_state(self):
+        """The optimizer's state in the wire and disk format: the state
+        of the flat vector (this shard's slice of it under ZeRO-1), the
+        structure of ``opt_template``."""
+        return self._opt_wire(self._opt)
+
+    @opt_state.setter
+    def opt_state(self, state):
+        self._opt = None
+        self._opt = self._opt_carry(state)
 
     # ------------------------------------------------------------------
     def _leaf_spec(self, a) -> P:
@@ -412,8 +570,179 @@ class ShardedParameterStep:
         return jax.tree_util.tree_map(self._leaf_spec, tree)
 
     # ------------------------------------------------------------------
+    def _make_grads(self, form):
+        """The forward and backward both layouts share: ``(params, mstate,
+        rng, x, y) -> (loss, new_mstate, gradient)``, the gradient in the
+        layout's ``form`` (the flat layout ravels the leaves autodiff
+        wrote into one vector, the leaf layout takes them as they are).
+        With ``accum_steps`` > 1 a scan over the microbatches sums it in
+        float32, one microbatch's activations alive at a time."""
+        model, criterion = self.model, self.criterion
+        remat, remat_policy = self.remat, self.remat_policy
+        accum = max(1, self.accum_steps)
+        ndev, dcn_axis, seq_par = self.ndev, self._dcn_axis, \
+            self.seq_parallel
+        tmap = jax.tree_util.tree_map
+
+        def grad_of(p, ms, xs_mb, y_mb, rng_mb):
+            def loss_fn(pp):
+                out, new_ms = model.forward(
+                    pp, ms, *xs_mb, training=True, rng=rng_mb)
+                return criterion.forward(out, y_mb), new_ms
+
+            if remat:
+                loss_fn = jax.checkpoint(loss_fn, policy=remat_policy)
+            return jax.value_and_grad(loss_fn, has_aux=True)(p)
+
+        def grads(params, mstate, rng, x, y):
+            replica = jax.lax.axis_index(AXIS_DATA)
+            if dcn_axis:
+                replica = replica + ndev * jax.lax.axis_index(dcn_axis)
+            if seq_par:
+                replica = (replica * jax.lax.axis_size(AXIS_SEQ)
+                           + jax.lax.axis_index(AXIS_SEQ))
+            dev_rng = jax.random.fold_in(rng, replica)
+            if accum == 1:
+                (loss, new_mstate), g = grad_of(
+                    params, mstate, as_inputs(x), y, dev_rng)
+                return loss, new_mstate, form(g)
+
+            # microbatch scan: one microbatch's activations live at a
+            # time; the f32 gradient accumulates across iterations
+            def split(a):
+                return a.reshape((accum, a.shape[0] // accum)
+                                 + a.shape[1:])
+
+            xs_s = tuple(split(a) for a in as_inputs(x))
+            y_s = split(y)
+
+            def micro(carry, inp):
+                ms_c, gsum, lsum, k = carry
+                xs_mb = inp[:-1]
+                y_mb = inp[-1]
+                rng_mb = jax.random.fold_in(dev_rng, k)
+                (l, new_ms), g = grad_of(params, ms_c, xs_mb, y_mb,
+                                         rng_mb)
+                gsum = tmap(lambda s, fg: s + fg.astype(jnp.float32),
+                            gsum, form(g))
+                return (new_ms, gsum, lsum + l, k + 1), None
+
+            gsum0 = tmap(lambda a: jnp.zeros(a.shape, jnp.float32),
+                         jax.eval_shape(form, params))
+            (new_mstate, gsum, lsum, _), _ = jax.lax.scan(
+                micro, (mstate, gsum0, jnp.asarray(0.0, jnp.float32),
+                        jnp.asarray(0, jnp.int32)),
+                xs_s + (y_s,))
+            g = tmap(lambda s: s / accum, gsum)
+            return lsum / accum, new_mstate, g
+
+        return grads
+
+    def _make_stats_sync(self):
+        """``(loss, mstate, new_mstate) -> (loss, new_mstate)`` as the job
+        sees them: what both layouts do with the per-replica loss and
+        model state after their update."""
+        # axes every per-block statistic (loss, model state, layerwise
+        # grads) averages over
+        stat_axes = self._batch_axes + ((AXIS_SEQ,)
+                                        if self.seq_parallel else ())
+
+        def stats_sync(loss, mstate, new_mstate):
+            loss = jax.lax.pmean(loss, stat_axes)
+            # model state across replicas: floating leaves (running
+            # statistics) are averaged; unsigned leaves are event counters
+            # (obs/state_metrics.py), to which every replica added its own
+            # events: the job's count is the old value plus the sum of the
+            # additions; anything else is each replica's own
+            old_leaf = dict(jax.tree_util.tree_flatten_with_path(mstate)[0])
+
+            def sync_state(path, a):
+                dtype = jnp.asarray(a).dtype
+                if jnp.issubdtype(dtype, jnp.floating):
+                    return jax.lax.pmean(a, stat_axes)
+                if jnp.issubdtype(dtype, jnp.unsignedinteger) \
+                        and path in old_leaf:
+                    return old_leaf[path] + jax.lax.psum(
+                        a - old_leaf[path], stat_axes)
+                return a
+
+            return loss, jax.tree_util.tree_map_with_path(
+                sync_state, new_mstate)
+
+        return stats_sync
+
     def _make_step_shard(self, want_gnorm: bool = False, comm: bool = True):
-        """The single-step body shared by the classic one-step program and
+        """The single-step body of this engine's layout, shared by the
+        one-step program and the K-step bundle.  (A method, not a bound
+        method kept on the instance: the engine must hold no reference
+        cycle, so that dropping it frees its buffers at once.)"""
+        make = (self._make_leaf_step if self.leaf_state
+                else self._make_flat_step)
+        return make(want_gnorm, comm)
+
+    def _make_leaf_step(self, want_gnorm: bool = False, comm: bool = True):
+        """The single-step body of the ONE-shard programs: ``(params, ema,
+        opt_state, mstate, step, rng, x, y, mask) -> (new_params, new_ema,
+        new_opt, new_mstate, loss, gnorm)``, every parameter-sized thing a
+        pytree shaped like the model's parameters (``ema`` / ``mask``:
+        ``None`` when off).  The gradient's leaves go into
+        ``OptimMethod.update`` as autodiff wrote them; the norms are sums
+        of per-leaf sums.  There is no wire here, so ``comm`` has nothing
+        to leave out."""
+        optim, clip, ema_decay = self.optim, self.clip, self.ema_decay
+        grads_of = self._make_grads(lambda g: g)
+        stats_sync = self._make_stats_sync()
+        tmap = jax.tree_util.tree_map
+
+        def sum_sq(tree):
+            return sum(jnp.sum(g * g)
+                       for g in jax.tree_util.tree_leaves(tree))
+
+        def step_shard(params, ema, opt_state, mstate, step, rng, x, y,
+                       mask):
+            loss, new_mstate, grads = grads_of(params, mstate, rng, x, y)
+            # every leaf is written once, as its weight-gradient product
+            # made it.  Left to fuse, XLA:TPU runs the update as that
+            # product's epilogue (three more operands, three outputs a
+            # tile) at a sixth of the update's own bandwidth (PERF.md §6,
+            # PR 37); a barrier a leaf keeps them two programs' worth of
+            # work and forces no two leaves alive together
+            grads = tmap(jax.lax.optimization_barrier, grads)
+            if mask is not None:
+                # frozen entries: zero gradient (keeps the moments clean)
+                grads = tmap(lambda g, m: g * m.astype(g.dtype), grads,
+                             mask)
+            grads = tmap(lambda g: g.astype(jnp.float32), grads)
+            gnorm = (jnp.sqrt(sum_sq(grads)) if want_gnorm
+                     else jnp.asarray(0.0, jnp.float32))
+            if clip is not None:
+                if (clip.constant_min is not None
+                        or clip.constant_max is not None):
+                    grads = tmap(lambda g: jnp.clip(
+                        g, clip.constant_min, clip.constant_max), grads)
+                if clip.l2_norm is not None:
+                    scale = jnp.minimum(
+                        1.0,
+                        clip.l2_norm / (jnp.sqrt(sum_sq(grads)) + 1e-12))
+                    grads = tmap(lambda g: g * scale, grads)
+            new_params, new_opt = optim.update(step, grads, params,
+                                               opt_state)
+            if mask is not None:
+                # restore frozen entries bitwise: weight decay must not
+                # drift parameters that carry no gradient
+                new_params = tmap(lambda m, new, old: jnp.where(
+                    m > 0, new, old), mask, new_params, params)
+            loss, new_mstate = stats_sync(loss, mstate, new_mstate)
+            new_ema = (tmap(lambda e, p: ema_decay * e
+                            + (1.0 - ema_decay) * p, ema, new_params)
+                       if ema_decay else ema)
+            return new_params, new_ema, new_opt, new_mstate, loss, gnorm
+
+        return step_shard
+
+    def _make_flat_step(self, want_gnorm: bool = False, comm: bool = True):
+        """The single-step body of the programs on several shards (and of
+        layerwise methods), shared by the classic one-step program and
         the K-step bundle: (flat_p, ema, opt_state, mstate, step, rng, x,
         y, mask) -> (new_flat, new_ema, new_opt, new_mstate, loss, gnorm).
         ``want_gnorm`` adds the global mean-gradient L2 norm (one extra
@@ -427,82 +756,29 @@ class ShardedParameterStep:
         codec cost to the collective side, matching the comm-only
         probe's denominator) so :meth:`measure_overlap` can time the
         step without its collectives."""
-        model, criterion, optim = self.model, self.criterion, self.optim
+        optim = self.optim
         unravel, n_real = self.unravel, self.n_real
         ndev, shard_size = self.ndev, self.shard_size
         clip = self.clip
         elementwise = optim.elementwise
-        remat = self.remat
         grad_comm, quant_block = self.grad_comm, self.quant_block
         param_comm = self.param_comm
         bucket_cols = tuple(self._bucket_cols)
         dcn = self.dcn
-        remat_policy = self.remat_policy
-        accum = max(1, self.accum_steps)
         ema_decay = self.ema_decay
 
         dcn_axis, n_replicas = self._dcn_axis, self.ndev * self.dcn
         batch_axes = self._batch_axes
         seq_par = self.seq_parallel
-        # axes every per-block statistic (loss, model state, layerwise
-        # grads) averages over
-        stat_axes = batch_axes + ((AXIS_SEQ,) if seq_par else ())
+        grads_of = self._make_grads(lambda g: ravel_pytree(g)[0])
+        stats_sync = self._make_stats_sync()
 
         def step_shard(flat_p, ema, opt_state, mstate, step, rng, x, y,
                        mask):
             # mask: trainable-mask vector (n_pad,) — or the scalar 1.0
             # when everything trains (broadcast no-op)
             params = unravel(flat_p[:n_real])
-            replica = jax.lax.axis_index(AXIS_DATA)
-            if dcn_axis:
-                replica = replica + ndev * jax.lax.axis_index(dcn_axis)
-            if seq_par:
-                replica = (replica * jax.lax.axis_size(AXIS_SEQ)
-                           + jax.lax.axis_index(AXIS_SEQ))
-            dev_rng = jax.random.fold_in(rng, replica)
-
-            def grad_of(p, ms, xs_mb, y_mb, rng_mb):
-                def loss_fn(pp):
-                    out, new_ms = model.forward(
-                        pp, ms, *xs_mb, training=True, rng=rng_mb)
-                    return criterion.forward(out, y_mb), new_ms
-
-                if remat:
-                    loss_fn = jax.checkpoint(loss_fn, policy=remat_policy)
-                return jax.value_and_grad(loss_fn, has_aux=True)(p)
-
-            if accum == 1:
-                (loss, new_mstate), grads = grad_of(
-                    params, mstate, as_inputs(x), y, dev_rng)
-                flat_g, _ = ravel_pytree(grads)
-            else:
-                # microbatch scan: one microbatch's activations live at a
-                # time; flat f32 gradient accumulates across iterations
-                def split(a):
-                    return a.reshape((accum, a.shape[0] // accum)
-                                     + a.shape[1:])
-
-                xs_s = tuple(split(a) for a in as_inputs(x))
-                y_s = split(y)
-
-                def micro(carry, inp):
-                    ms_c, gsum, lsum, k = carry
-                    xs_mb = inp[:-1]
-                    y_mb = inp[-1]
-                    rng_mb = jax.random.fold_in(dev_rng, k)
-                    (l, new_ms), grads = grad_of(params, ms_c, xs_mb, y_mb,
-                                                 rng_mb)
-                    fg, _ = ravel_pytree(grads)
-                    return (new_ms, gsum + fg.astype(jnp.float32),
-                            lsum + l, k + 1), None
-
-                gsum0 = jnp.zeros((n_real,), jnp.float32)
-                (new_mstate, gsum, lsum, _), _ = jax.lax.scan(
-                    micro, (mstate, gsum0, jnp.asarray(0.0, jnp.float32),
-                            jnp.asarray(0, jnp.int32)),
-                    xs_s + (y_s,))
-                flat_g = gsum / accum
-                loss = lsum / accum
+            loss, new_mstate, flat_g = grads_of(params, mstate, rng, x, y)
             if seq_par:
                 # per-sequence-block grads average over the seq axis (the
                 # loss is a per-token mean, blocks are equal-sized); params
@@ -645,26 +921,7 @@ class ShardedParameterStep:
             # restore frozen entries bitwise: weight decay / bias-corrected
             # moments must not drift parameters that carry no gradient
             new_flat = jnp.where(mask > 0, new_flat, flat_p)
-            loss = jax.lax.pmean(loss, stat_axes)
-            # model state across replicas: floating leaves (running
-            # statistics) are averaged; unsigned leaves are event counters
-            # (obs/state_metrics.py), to which every replica added its own
-            # events: the job's count is the old value plus the sum of the
-            # additions; anything else is each replica's own
-            old_leaf = dict(jax.tree_util.tree_flatten_with_path(mstate)[0])
-
-            def sync_state(path, a):
-                dtype = jnp.asarray(a).dtype
-                if jnp.issubdtype(dtype, jnp.floating):
-                    return jax.lax.pmean(a, stat_axes)
-                if jnp.issubdtype(dtype, jnp.unsignedinteger) \
-                        and path in old_leaf:
-                    return old_leaf[path] + jax.lax.psum(
-                        a - old_leaf[path], stat_axes)
-                return a
-
-            new_mstate = jax.tree_util.tree_map_with_path(
-                sync_state, new_mstate)
+            loss, new_mstate = stats_sync(loss, mstate, new_mstate)
             new_ema = (ema_decay * ema + (1.0 - ema_decay) * new_flat
                        if ema_decay else ema)
             return new_flat, new_ema, new_opt, new_mstate, loss, gnorm
@@ -674,7 +931,7 @@ class ShardedParameterStep:
     def _train_specs(self, x_ex=None, y_ex=None):
         """(opt_spec, x_spec, y_spec) for the train programs — seq_parallel
         specs depend on leaf ranks, so they need example batches."""
-        opt_spec = (P(AXIS_DATA) if self.optim.elementwise else P())
+        opt_spec = self._opt_spec
         if self.seq_parallel:
             x_spec = self._batch_specs(x_ex)
             y_spec = self._batch_specs(y_ex)
@@ -760,7 +1017,7 @@ class ShardedParameterStep:
 
     # ------------------------------------------------------------------
     def _build_eval(self, methods: Tuple, x_ex=None, y_ex=None, w_ex=None):
-        model, unravel, n_real = self.model, self.unravel, self.n_real
+        model, params_of = self.model, self._params_of
 
         # seq_parallel models MUST see seq-sharded inputs in eval too (their
         # attention layers run seq collectives unconditionally); stats then
@@ -768,8 +1025,8 @@ class ShardedParameterStep:
         stat_axes = self._batch_axes + ((AXIS_SEQ,)
                                         if self.seq_parallel else ())
 
-        def eval_shard(flat_p, mstate, x, y, w):
-            params = unravel(flat_p[:n_real])
+        def eval_shard(carried_p, mstate, x, y, w):
+            params = params_of(carried_p)
             xs = as_inputs(x)
             out, _ = model.forward(params, mstate, *xs, training=False)
             stats = []
@@ -923,16 +1180,13 @@ class ShardedParameterStep:
                 "data-parallel mesh")
         if rng is None:
             rng = jax.random.PRNGKey(0)
-        ema_in = self.ema_flat if self.ema_flat is not None \
-            else self._ema_dummy
-        mask_in = (self._mask_flat if self._mask_flat is not None
-                   else jnp.asarray(1.0, jnp.float32))
         full = self._build_train(donate=False)
         nocomm = self._build_train(donate=False, comm=False)
         probe = self._build_comm_probe()
-        args = (self.flat_params, ema_in, self.opt_state,
+        args = (self._params, self._ema, self._opt,
                 self.model_state, jnp.asarray(0, jnp.int32), rng,
-                x_dev, y_dev, mask_in)
+                x_dev, y_dev, self._mask)
+        flat_p = self.flat_params
 
         def timed(fn, *a):
             jax.block_until_ready(fn(*a))  # compile + warm
@@ -946,7 +1200,7 @@ class ShardedParameterStep:
         with expected_compile():
             t_full = timed(full, *args)
             t_nocomm = timed(nocomm, *args)
-            t_comm = timed(probe, self.flat_params, self.flat_params)
+            t_comm = timed(probe, flat_p, flat_p)
         exposed = max(0.0, t_full - t_nocomm)
         eff = (min(1.0, max(0.0, 1.0 - exposed / t_comm))
                if t_comm > 0 else 0.0)
@@ -977,18 +1231,10 @@ class ShardedParameterStep:
         see ``bigdl_tpu.data.prefetch``)."""
         if self._train is None:  # seq_parallel: specs need leaf ranks
             self._train = self._build_train(x_dev, y_dev)
-        ema_in = self.ema_flat if self.ema_flat is not None \
-            else self._ema_dummy
-        mask_in = (self._mask_flat if self._mask_flat is not None
-                   else jnp.asarray(1.0, jnp.float32))
-        (self.flat_params, new_ema, self.opt_state, self.model_state,
+        (self._params, self._ema, self._opt, self.model_state,
          loss) = self._train(
-            self.flat_params, ema_in, self.opt_state, self.model_state,
-            jnp.asarray(step, jnp.int32), rng, x_dev, y_dev, mask_in)
-        if self.ema_flat is not None:
-            self.ema_flat = new_ema
-        else:
-            self._ema_dummy = new_ema
+            self._params, self._ema, self._opt, self.model_state,
+            jnp.asarray(step, jnp.int32), rng, x_dev, y_dev, self._mask)
         return loss
 
     # -- fused multi-step execution (docs/performance.md) ---------------
@@ -1033,23 +1279,15 @@ class ShardedParameterStep:
         if new_program:
             fn = self._bundle_cache[key] = self._build_bundle(
                 k, xs[0], ys[0])
-        ema_in = self.ema_flat if self.ema_flat is not None \
-            else self._ema_dummy
-        mask_in = (self._mask_flat if self._mask_flat is not None
-                   else jnp.asarray(1.0, jnp.float32))
         # a first-seen bundle size (epoch-tail remainder, trigger-clamped
         # span) legitimately compiles mid-run: announce it so the
         # recompilation sentinel only flags true cache misses
         with expected_compile() if new_program else nullcontext():
-            (self.flat_params, new_ema, self.opt_state, self.model_state,
+            (self._params, self._ema, self._opt, self.model_state,
              losses, gnorms, counted) = fn(
-                self.flat_params, ema_in, self.opt_state, self.model_state,
+                self._params, self._ema, self._opt, self.model_state,
                 jnp.asarray(step0, jnp.int32), base_key,
-                tuple(xs), tuple(ys), mask_in)
-        if self.ema_flat is not None:
-            self.ema_flat = new_ema
-        else:
-            self._ema_dummy = new_ema
+                tuple(xs), tuple(ys), self._mask)
         return losses, gnorms, counted
 
     def evaluate(self, methods, batches) -> list:
@@ -1077,7 +1315,7 @@ class ShardedParameterStep:
             # a first validation pass mid-run compiles its eval program —
             # expected, not an XLA cache miss
             with expected_compile() if new_program else nullcontext():
-                acc.add(fn(self.flat_params, self.model_state,
+                acc.add(fn(self._params, self.model_state,
                            self.shard_batch(x),
                            self.shard_batch(mb["target"]),
                            self.shard_batch(w)))
@@ -1099,14 +1337,14 @@ class ShardedParameterStep:
             self._predict_jit = None
 
     def get_variables(self, ema: bool = False) -> Dict[str, Any]:
-        src = self.ema_flat if (ema and self.ema_flat is not None) \
-            else self.flat_params
-        flat = np.asarray(src)[: self.n_real]
-        # split on the host: on the device the flat copy, its pieces and
-        # their reshapes would stand beside the training state, three more
-        # vectors of the model's size where one is wanted
+        host = jax.device_get(self._ema if (ema and self.ema_decay)
+                              else self._params)
+        # a flat vector is split on the host: on the device the flat copy,
+        # its pieces and their reshapes would stand beside the training
+        # state, three more vectors of the model's size where one is wanted
         with jax.default_device(jax.local_devices(backend="cpu")[0]):
-            host = jax.device_get(self.unravel(flat))
+            host = jax.device_get(self._params_of(host))
+        # fresh arrays: the engine donates its own at the next step
         return {"params": jax.tree_util.tree_map(jnp.asarray, host),
                 "state": jax.device_get(self.model_state)}
 
@@ -1116,10 +1354,10 @@ class ShardedParameterStep:
         calls don't recompile."""
         fwd = getattr(self, "_predict_jit", None)
         if fwd is None:
-            model, unravel, n_real = self.model, self.unravel, self.n_real
+            model, params_of = self.model, self._params_of
 
-            def raw(flat_p, mstate, x):
-                params = unravel(flat_p[:n_real])
+            def raw(carried_p, mstate, x):
+                params = params_of(carried_p)
                 xs = as_inputs(x)
                 out, _ = model.forward(params, mstate, *xs, training=False)
                 return out
@@ -1133,14 +1371,14 @@ class ShardedParameterStep:
                 mesh = self.mesh
                 _cache: Dict[Any, Callable] = {}
 
-                def fwd(flat_p, mstate, x):
+                def fwd(carried_p, mstate, x):
                     key = jax.tree_util.tree_structure(x)
                     if key not in _cache:
                         _cache[key] = jax.jit(shard_map(
                             raw, mesh=mesh,
                             in_specs=(P(), P(), self._batch_specs(x)),
                             out_specs=out_spec))
-                    return _cache[key](flat_p, mstate, x)
+                    return _cache[key](carried_p, mstate, x)
             else:
                 fwd = jax.jit(raw)
 
@@ -1163,7 +1401,7 @@ class ShardedParameterStep:
                            jax.tree_util.tree_map(jnp.asarray, x))
         else:
             def run(x):
-                return fwd(self.flat_params, self.model_state,
+                return fwd(self._params, self.model_state,
                            self.shard_batch(x))
 
         return run

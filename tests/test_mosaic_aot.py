@@ -277,3 +277,68 @@ def test_hc_mix_backward_both_ways(v5e, n, tokens, d):
                                              interpret=False),
              f32(1, n, tokens), f32(1, tokens, d), f32(n, tokens, d),
              f32(n, tokens, d))
+
+
+def test_one_shard_train_step_assembles_no_flat_vector(v5e):
+    """A whole train step (forward, backward, Adam) on ONE shard, compiled
+    for the described chip in the layout the engine picks there (state
+    shaped like the model's leaves) and, steered from here, in the layout
+    several shards carry (flat vectors): the leaf-shaped program holds no
+    ``concatenate`` and no ``dynamic-update-slice`` as large as a weight
+    matrix, anywhere, and needs less scratch than the flat one."""
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from bigdl_tpu import nn, optim
+    from bigdl_tpu.optim.train_step import ShardedParameterStep
+    from bigdl_tpu.runtime.mesh import MeshSpec, build_mesh
+
+    chip = next(iter(v5e((), jnp.float32).sharding.device_set))
+    chip_mesh = build_mesh(MeshSpec(data=1), devices=[chip])
+    rep = NamedSharding(chip_mesh, P())
+    widths = (1024, 4096, 4096, 1024)
+    layers = []
+    for a, b in zip(widths, widths[1:]):
+        layers += [nn.Linear(a, b), nn.ReLU()]
+    model = nn.Sequential(layers[:-1] + [nn.LogSoftMax()])
+    variables = model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, widths[0]), jnp.float32))
+    step = ShardedParameterStep(
+        model, nn.ClassNLLCriterion(), optim.Adam(learning_rate=1e-3),
+        build_mesh(MeshSpec(data=1), devices=jax.devices()[:1]), variables)
+    assert step.leaf_state
+    matrix = min(a * b for a, b in zip(widths, widths[1:]))
+
+    def compiled():
+        step.mesh = chip_mesh  # the programs are built for this mesh
+        args = (step._params, step._ema, step._opt, step.model_state,
+                jnp.asarray(0, jnp.int32), jax.random.PRNGKey(0),
+                jnp.zeros((64, widths[0]), jnp.float32),
+                jnp.zeros((64,), jnp.int32), step._mask)
+        shapes = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
+            args)
+        return step._build_train().lower(*shapes).compile()
+
+    def assembled(text):
+        """``concatenate`` / ``dynamic-update-slice`` instructions whose
+        result is at least a weight matrix."""
+        found = []
+        for m in re.finditer(r"= \w+\[([\d,]*)\]\S* "
+                             r"(concatenate|dynamic-update-slice)\(", text):
+            if np.prod([int(d) for d in m.group(1).split(",") if d]) \
+                    >= matrix:
+                found.append(m.group(0))
+        return found
+
+    leaf = compiled()
+    # the same engine in the other layout: what a one-shard step carried
+    # before the layout followed the number of shards
+    step.leaf_state = False
+    step._init_flat_state(variables["params"], None, jnp.float32)
+    flat = compiled()
+    assert assembled(flat.as_text()), "the flat form assembles its vector"
+    assert not assembled(leaf.as_text())
+    temp = [c.memory_analysis().temp_size_in_bytes for c in (leaf, flat)]
+    assert temp[0] < temp[1], temp
