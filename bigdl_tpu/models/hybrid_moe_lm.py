@@ -25,6 +25,18 @@ the vocabulary (docs/parallelism.md §Held-share expert layer).  With no
 shared expert a token none of whose chosen experts is held gets exactly
 zero from the layer: the residual stream alone carries it on.
 
+Two more mixer kinds (MiniCPM-SALA's ``mixer_types``, read as
+``layer_types``): ``"lightning-attn"`` (``nn.sparse_linear_attention.
+LightningAttention``: causal linear attention with a decay a head) and
+``"minicpm4"`` (``SparseBlockAttention``: InfLLM-v2 attention over each
+query's selected key blocks; its block list is kept across the layer's
+``jax.checkpoint``, not recomputed).  Such a decoder has no experts (every
+FFN dense, ``held_ffn_columns`` of them on a tensor-parallel rank), an
+untied ``head``, a share of heads ``held_heads`` and MiniCPM's muP scales
+(``scale_emb``, ``scale_depth / sqrt(mup_denominator)`` on every branch,
+the final state over ``hidden_size / dim_model_base``), all of which
+default to what LFM2 had: its parameter tree and logits are as they were.
+
 This decoder and ``models/mla_moe_lm.py`` share their parts (``HeldMoE``,
 ``swiglu``, ``rms_norm``, ``rope``, the state metrics) by import and their
 skeleton by shape only.
@@ -57,10 +69,25 @@ from bigdl_tpu.nn.attention import GroupedQueryAttention
 from bigdl_tpu.nn.layers import rms_norm
 from bigdl_tpu.nn.module import EMPTY, Module
 from bigdl_tpu.nn.short_conv import GatedShortConv
+from bigdl_tpu.nn.sparse_linear_attention import (SELECTION,
+                                                  LightningAttention,
+                                                  SparseBlockAttention)
 from bigdl_tpu.parallel.moe import HeldMoE, swiglu, swiglu_init
 from bigdl_tpu.tensor.policy import cast_compute
 
-LAYER_TYPES = ("conv", "full_attention")
+LAYER_TYPES = ("conv", "full_attention", "lightning-attn", "minicpm4")
+# MiniCPM4-8B's published ``sparse_config`` (InfLLM-v2), the default of a
+# ``minicpm4`` layer
+SPARSE_CONFIG = (("block_size", 64), ("dense_len", 8192),
+                 ("init_blocks", 1), ("kernel_size", 32),
+                 ("kernel_stride", 16), ("topk", 64), ("window_size", 2048))
+# the source's mixer switches, and the one value each has where the two
+# new mixers are built (MiniCPM-SALA's): RoPE, QK-norm, the output gates
+# and lightning's output norm always, no RoPE in the sparse layer
+MIXER_FIXED = (("attn_use_output_gate", True), ("attn_use_rope", False),
+               ("lightning_scale", "1/sqrt(d)"), ("lightning_use_rope", True),
+               ("qk_norm", True), ("use_output_gate", True),
+               ("use_output_norm", True))
 # beside the sum of the chosen scores, as the family's published forward
 # pass has it (``route_sigmoid_topk``'s default is another family's 1e-20)
 TOPK_SUM_EPS = 1e-6
@@ -75,9 +102,10 @@ class HybridMoEConfig:
     num_attention_heads: int
     num_key_value_heads: int
     intermediate_size: int
-    moe_intermediate_size: int
-    num_experts: int
-    num_experts_per_tok: int
+    # no experts (0): every layer's FFN is dense
+    moe_intermediate_size: int = 0
+    num_experts: int = 0
+    num_experts_per_tok: int = 0
     num_dense_layers: int = 1
     conv_L_cache: int = 3
     conv_bias: bool = False
@@ -89,6 +117,27 @@ class HybridMoEConfig:
         ("rope_theta", 10000.0), ("rope_type", "default"))
     # this chip's share of the experts, (first, count); None = all of them
     held_experts: Optional[Tuple[int, int]] = None
+    # 0: hidden_size // num_attention_heads
+    head_dim: int = 0
+    # a rank's share of the query heads of every mixer that has heads,
+    # (first, count) of ``num_attention_heads``; None = all of them
+    held_heads: Optional[Tuple[int, int]] = None
+    # a rank's share of the dense FFN's columns; None = intermediate_size
+    held_ffn_columns: Optional[int] = None
+    tie_word_embeddings: bool = True
+    # MiniCPM's muP scales: the embedding times ``scale_emb``, each residual
+    # branch times ``scale_depth / sqrt(mup_denominator)``, the normed final
+    # state over ``hidden_size / dim_model_base``; 1 where not given
+    scale_emb: float = 1.0
+    scale_depth: float = 1.0
+    mup_denominator: Optional[int] = None
+    dim_model_base: Optional[int] = None
+    # ``lightning-attn`` (0: as num_attention_heads / head_dim)
+    lightning_nh: int = 0
+    lightning_nkv: int = 0
+    lightning_head_dim: int = 0
+    # ``minicpm4``
+    sparse_config: Tuple[Tuple[str, int], ...] = SPARSE_CONFIG
 
     def __post_init__(self):
         if len(self.layer_types) != self.num_hidden_layers:
@@ -102,53 +151,103 @@ class HybridMoEConfig:
         if dict(self.rope_parameters).get("rope_type", "default") != "default":
             raise ValueError(f"rope_parameters {self.rope_parameters}: only "
                              "the default (unscaled) rotary table is built")
+        if not self.head_dim:
+            object.__setattr__(self, "head_dim",
+                               self.hidden_size // self.num_attention_heads)
+        if "lightning-attn" in self.layer_types and (
+                self.lightning_heads != self.num_attention_heads
+                or self.lightning_nkv not in (0, self.lightning_heads)):
+            raise ValueError("lightning-attn: as many key/value heads as "
+                             "query heads, as many as the layer's")
 
     @property
     def rope_theta(self) -> float:
         return float(dict(self.rope_parameters)["rope_theta"])
 
     @property
-    def head_dim(self) -> int:
-        return self.hidden_size // self.num_attention_heads
+    def lightning_heads(self) -> int:
+        return self.lightning_nh or self.num_attention_heads
+
+    @property
+    def residual_scale(self) -> float:
+        return self.scale_depth / (self.mup_denominator or 1) ** 0.5
+
+    @property
+    def head_divisor(self) -> float:
+        return self.hidden_size / (self.dim_model_base or self.hidden_size)
 
     @classmethod
     def from_dict(cls, cfg: dict) -> "HybridMoEConfig":
+        """The source's keys as they are; MiniCPM's names (``mixer_types``,
+        ``rms_norm_eps``, a top-level ``rope_theta``) are read as this
+        family's, and its switches (``MIXER_FIXED``) must hold the one
+        value the two mixers are built for."""
+        cfg = dict(cfg)
+        for key, value in MIXER_FIXED:
+            if cfg.get(key, value) != value:
+                raise ValueError(f"{key}={cfg[key]!r}: the lightning-attn "
+                                 f"and minicpm4 mixers are built for "
+                                 f"{value!r} only")
+        for theirs, ours in (("mixer_types", "layer_types"),
+                             ("rms_norm_eps", "norm_eps")):
+            if theirs in cfg:
+                cfg.setdefault(ours, cfg[theirs])
+        if "rope_theta" in cfg and "rope_parameters" not in cfg:
+            cfg["rope_parameters"] = {"rope_theta": cfg["rope_theta"],
+                                      "rope_type": "default"}
         names = {f.name for f in fields(cls)}
         kw = {k: v for k, v in cfg.items() if k in names}
         kw["layer_types"] = tuple(kw["layer_types"])
-        if kw.get("held_experts") is not None:
-            kw["held_experts"] = tuple(kw["held_experts"])
-        if kw.get("rope_parameters") is not None:
-            kw["rope_parameters"] = tuple(sorted(
-                kw["rope_parameters"].items()))
+        for key in ("held_experts", "held_heads"):
+            if kw.get(key) is not None:
+                kw[key] = tuple(kw[key])
+        for key in ("rope_parameters", "sparse_config"):
+            if kw.get(key) is not None:
+                kw[key] = tuple(sorted(kw[key].items()))
         return cls(**kw)
 
 
 class HybridMoELM(Module):
     """``forward(params, state, ids)`` → (batch, seq, vocab) float32 logits
-    and the new state (each expert layer's bias and routing statistics)."""
+    and the new state (each expert layer's bias and routing statistics,
+    each sparse layer's counters)."""
 
     def __init__(self, config: HybridMoEConfig, name=None):
         super().__init__(name)
         c = self.config = config
+        kinds = set(c.layer_types)
         self.conv = GatedShortConv(c.hidden_size, c.conv_L_cache)
         self.attn = GroupedQueryAttention(
             c.hidden_size, c.num_attention_heads, c.num_key_value_heads,
             c.head_dim, rope_theta=c.rope_theta, qk_norm_eps=c.norm_eps)
-        self.moe = HeldMoE(
-            c.num_experts, c.moe_intermediate_size, c.num_experts_per_tok,
-            held=c.held_experts, shared_hidden=0,
-            scale=c.routed_scaling_factor, norm_topk=c.norm_topk_prob,
-            norm_eps=TOPK_SUM_EPS)
+        if "lightning-attn" in kinds:
+            self.lightning = LightningAttention(
+                c.hidden_size, c.lightning_heads,
+                c.lightning_head_dim or c.head_dim, held=c.held_heads,
+                rope_theta=c.rope_theta, eps=c.norm_eps)
+        if "minicpm4" in kinds:
+            self.sparse = SparseBlockAttention(
+                c.hidden_size, c.num_attention_heads, c.num_key_value_heads,
+                c.head_dim, held=c.held_heads, eps=c.norm_eps,
+                **dict(c.sparse_config))
+        if c.num_experts:
+            self.moe = HeldMoE(
+                c.num_experts, c.moe_intermediate_size,
+                c.num_experts_per_tok, held=c.held_experts, shared_hidden=0,
+                scale=c.routed_scaling_factor, norm_topk=c.norm_topk_prob,
+                norm_eps=TOPK_SUM_EPS)
 
     def _operator(self, i: int):
         """(parameter key, module) of layer ``i``'s token mixer."""
-        if self.config.layer_types[i] == "conv":
-            return "conv", self.conv
-        return "attn", self.attn
+        return {"conv": ("conv", self.conv),
+                "full_attention": ("attn", self.attn),
+                "lightning-attn": ("lightning", getattr(
+                    self, "lightning", None)),
+                "minicpm4": ("sparse", getattr(self, "sparse", None)),
+                }[self.config.layer_types[i]]
 
     def _is_dense(self, i: int) -> bool:
-        return i < self.config.num_dense_layers
+        return i < self.config.num_dense_layers or not self.config.num_experts
 
     def init(self, rng, ids):
         c = self.config
@@ -158,47 +257,75 @@ class HybridMoELM(Module):
         params = {"embed": jax.random.normal(ks[0], (c.vocab_size, d))
                   * d ** -0.5,
                   "ln_out": jnp.ones((d,))}
+        if not c.tie_word_embeddings:
+            # logits of a normed state near unit standard deviation after
+            # the division by ``head_divisor``
+            params["head"] = jax.random.normal(
+                jax.random.fold_in(ks[0], 1), (c.vocab_size, d)) * (
+                    c.head_divisor * d ** -0.5)
         state = {}
         for i in range(c.num_hidden_layers):
             k_op, k_ffn = jax.random.split(ks[i + 1])
             key, op = self._operator(i)
+            v = op.init(k_op, x)
             layer = {"ln1": jnp.ones((d,)), "ln2": jnp.ones((d,)),
-                     key: op.init(k_op, x)["params"]}
+                     key: v["params"]}
+            if v["state"]:
+                state[f"layer{i}"] = {key: v["state"]}
             if self._is_dense(i):
-                layer["ffn"] = swiglu_init(k_ffn, d, c.intermediate_size)
+                layer["ffn"] = swiglu_init(
+                    k_ffn, d, c.held_ffn_columns or c.intermediate_size)
             else:
                 v = self.moe.init(k_ffn, x)
-                layer["moe"], state[f"layer{i}"] = v["params"], v["state"]
+                layer["moe"] = v["params"]
+                state[f"layer{i}"] = dict(state.get(f"layer{i}", {}),
+                                          **v["state"])
             params[f"layer{i}"] = layer
         return {"params": params, "state": state}
 
     def _layer(self, i: int, p, st, h):
-        eps = self.config.norm_eps
+        c = self.config
+        eps, scale = c.norm_eps, c.residual_scale
         key, op = self._operator(i)
-        y, _ = op.forward(p[key], EMPTY, rms_norm(h, p["ln1"], eps))
-        h = h + y
+        st = dict(st) if st else {}
+        y, op_st = op.forward(p[key], st.pop(key, EMPTY),
+                              rms_norm(h, p["ln1"], eps))
+        h = h + (y if scale == 1.0 else scale * y)
         x = rms_norm(h, p["ln2"], eps)
         if self._is_dense(i):
             with jax.named_scope("lm/dense_ffn"):
-                return h + swiglu(x, p["ffn"]), EMPTY
-        y, st = self.moe.forward(p["moe"], st, x)
-        return h + y, st
+                y = swiglu(x, p["ffn"])
+            st = EMPTY
+        else:
+            y, st = self.moe.forward(p["moe"], st, x)
+        if op_st:
+            st = dict(st or {}, **{key: op_st})
+        return h + (y if scale == 1.0 else scale * y), st
 
     def forward(self, params, state, ids, training=False, rng=None):
         c = self.config
         h = jnp.take(params["embed"], ids.astype(jnp.int32), axis=0)
+        if c.scale_emb != 1.0:
+            h = h * c.scale_emb
         new_state = {}
         for i in range(c.num_hidden_layers):
             key = f"layer{i}"
             fn = functools.partial(self._layer, i)
             if training:
-                fn = jax.checkpoint(fn)
+                # a sparse layer's block selection is kept, not recomputed
+                fn = jax.checkpoint(
+                    fn, policy=jax.checkpoint_policies.save_only_these_names(
+                        SELECTION)) if c.layer_types[i] == "minicpm4" \
+                    else jax.checkpoint(fn)
             h, st = fn(params[key], state.get(key, EMPTY), h)
             if st:
                 new_state[key] = st
         h = rms_norm(h, params["ln_out"], c.norm_eps)
+        if c.head_divisor != 1.0:
+            h = h / c.head_divisor
+        head = params["embed" if c.tie_word_embeddings else "head"]
         with jax.named_scope("lm/head"):
             logits = jnp.einsum(
-                "btd,vd->btv", cast_compute(h), cast_compute(params["embed"]),
+                "btd,vd->btv", cast_compute(h), cast_compute(head),
                 preferred_element_type=jnp.float32)
         return logits, new_state

@@ -76,7 +76,10 @@ FULL = dict(
                max_new=12, requests=8, prompt_lens=(24, 640)),
     kern=dict(slots=8, heads=12, hd=64, page=16, nb=64, chunk=5,
               flash_b=2, flash_s=1024, ffn=(768, 3072), bs_block=(128, 128),
-              ln_rows=4096, mm=(256, 768, 3072), hc=(4, 4096, 3584)),
+              ln_rows=4096, mm=(256, 768, 3072), hc=(4, 4096, 3584),
+              sala=dict(heads=4, first=28, t=32768, hd=128, kernel=32,
+                        stride=16, block=64, topk=64, init_blocks=1,
+                        window=2048)),
     moe=dict(tokens=4096, d=3584, hidden=1024, experts=64, held=(8, 8), k=4),
 )
 TINY = dict(
@@ -87,7 +90,10 @@ TINY = dict(
                max_new=4, requests=4, prompt_lens=(3, 24)),
     kern=dict(slots=2, heads=2, hd=16, page=4, nb=2, chunk=3,
               flash_b=1, flash_s=32, ffn=(32, 64), bs_block=(16, 16),
-              ln_rows=16, mm=(32, 128, 128), hc=(4, 64, 128)),
+              ln_rows=16, mm=(32, 128, 128), hc=(4, 64, 128),
+              sala=dict(heads=2, first=30, t=512, hd=32, kernel=32,
+                        stride=16, block=64, topk=4, init_blocks=1,
+                        window=128)),
     moe=dict(tokens=256, d=32, hidden=16, experts=16, held=(4, 2), k=2),
 )
 
@@ -221,6 +227,7 @@ class Smoke:
         check("hc_mix_pre_bwd_dx", got[0], dx + through, TOL_F32_SUM)
         check("hc_mix_pre_bwd_dh", got[1], dh, TOL_F32_SUM)
         del xs, gs, through, got, want, dx
+        self.sala_kernels(check, ref)
 
         # paged decode / verify attention over a page pool, f32 and int8
         P = S * nb
@@ -324,6 +331,88 @@ class Smoke:
                             f"tiles={json.dumps(tiles)}")
         if bad:
             raise AssertionError(f"kernels off their reference: {bad}")
+
+    def sala_kernels(self, check, ref):
+        """Lightning attention and the block-sparse kernels at the
+        MiniCPM-SALA cell's shape (4 held heads of 128, 32,768 positions,
+        the slowest decays, MiniCPM4's selection), forward and dq/dk/dv
+        against autodiff of their plain forms: Mosaic compiles the reversed
+        chunk walk and the transposed-selection dk/dv only here (tier-1
+        runs them interpreted).  The plain forms go a block of queries at
+        a time under ``jax.checkpoint``: (heads, T, T) scores are 17 GB."""
+        import jax
+        import jax.numpy as jnp
+
+        from bigdl_tpu.ops.lightning_attention import (alibi_slopes,
+                                                       lightning_attention)
+        from bigdl_tpu.ops.sparse_attention import (select_blocks,
+                                                    sparse_attention)
+
+        c = self.sz["kern"]["sala"]
+        n, t, hd, blk = c["heads"], c["t"], c["hd"], c["block"]
+        qb = min(1024, t)
+        ks = jax.random.split(jax.random.PRNGKey(38), 4)
+        q, kk, v, g = (jax.random.normal(x, (1, n, t, hd)) for x in ks)
+        slopes = alibi_slopes(32, c["first"], n)
+        pos = jnp.arange(t)
+
+        def by_blocks(weights):
+            """o (n, t, hd) of q, k, v (n, t, hd): ``weights(i, scores (n,
+            qb, t))`` is block i's attention."""
+            def f(q, k, v):
+                @jax.checkpoint
+                def one(i):
+                    qi = jax.lax.dynamic_slice_in_dim(q, i * qb, qb, 1)
+                    w = weights(i, jnp.einsum("nqd,nsd->nqs", qi, k)
+                                * hd ** -0.5)
+                    return jnp.einsum("nqs,nsd->nqd", w, v)
+                o = jax.lax.map(one, jnp.arange(t // qb))
+                return o.transpose(1, 0, 2, 3).reshape(n, t, hd)
+            return f
+
+        def vjp_of(f):
+            return lambda q, k, v, g: jax.vjp(f, q, k, v)[1](g)
+
+        def decayed(i, s):
+            lag = (i * qb + jnp.arange(qb))[:, None] - pos[None, :]
+            decay = jnp.exp(-jnp.asarray(slopes)[:, None, None]
+                            * jnp.maximum(lag, 0))
+            return jnp.where(lag >= 0, s * decay, 0.0)
+
+        light = lambda q, k, v: lightning_attention(q, k, v, slopes)
+        plain = lambda q, k, v: by_blocks(decayed)(q[0], k[0], v[0])[None]
+        check("lightning_attention_fwd", jax.jit(light)(q, kk, v),
+              ref(plain, q, kk, v), TOL_MXU)
+        for name, a, b in zip("qkv", jax.jit(vjp_of(light))(q, kk, v, g),
+                              ref(vjp_of(plain), q, kk, v, g)):
+            check(f"lightning_attention_bwd_d{name}", a, b, TOL_MXU_BWD)
+
+        # one group of ``n`` query heads on key/value head 0
+        sel = jax.jit(lambda q, k: select_blocks(
+            q[None], k[:, :1], kernel=c["kernel"], stride=c["stride"],
+            block=blk, topk=c["topk"], init_blocks=c["init_blocks"],
+            window=c["window"]))(q, kk)
+        nblk = t // blk
+
+        def chosen(i, s):
+            rows = jax.lax.dynamic_slice_in_dim(sel[0, 0], i * qb, qb, 0)
+            taken = jnp.zeros((qb, nblk + 1), bool).at[
+                jnp.arange(qb)[:, None], jnp.where(rows >= 0, rows, nblk)
+            ].set(True)[:, :nblk]
+            mask = jnp.repeat(taken, blk, 1) & (
+                (i * qb + jnp.arange(qb))[:, None] >= pos[None, :])
+            return jax.nn.softmax(jnp.where(mask, s, -jnp.inf), -1)
+
+        sparse = lambda q, k, v: sparse_attention(
+            q[:, None], k[:, :1], v[:, :1], sel, block=blk)[:, 0]
+        group = lambda x: jnp.broadcast_to(x[0, :1], (n, t, hd))
+        dense = lambda q, k, v: by_blocks(chosen)(
+            q[0], group(k), group(v))[None]
+        check("sparse_attention_fwd", jax.jit(sparse)(q, kk, v),
+              ref(dense, q, kk, v), TOL_MXU)
+        for name, a, b in zip("qkv", jax.jit(vjp_of(sparse))(q, kk, v, g),
+                              ref(vjp_of(dense), q, kk, v, g)):
+            check(f"sparse_attention_bwd_d{name}", a, b, TOL_MXU_BWD)
 
     # -- phase: expert layer -------------------------------------------------
     def expert_layer(self):

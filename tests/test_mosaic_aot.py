@@ -279,6 +279,53 @@ def test_hc_mix_backward_both_ways(v5e, n, tokens, d):
              f32(n, tokens, d))
 
 
+@pytest.mark.parametrize("t,chunk", [(32768, None), (1000, 128)])
+def test_lightning_attention_forward_and_backward(v5e, t, chunk):
+    """The chunked linear-attention kernel, forward in time and backward
+    (dq forward, dk and dv walking the chunks from the last), at the
+    minicpm-sala cell's per-layer shape (4 held heads of 128, 32k) and at
+    a length the chunks do not divide."""
+    from bigdl_tpu.ops.lightning_attention import alibi_slopes, \
+        lightning_attention
+    from bigdl_tpu.tensor.policy import compute_dtype
+
+    x = v5e((1, 4, t, 128), jnp.float32)
+
+    def loss(q, k, v):
+        return lightning_attention(q, k, v, alibi_slopes(32, 28, 4),
+                                   chunk=chunk, interpret=False).sum()
+
+    with compute_dtype(jnp.bfloat16):
+        _compile(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
+
+
+@pytest.mark.parametrize("t,block_q,block_k", [(32768, None, None),
+                                               (16384, 256, 128),
+                                               (16384, 128, 1024)])
+def test_sparse_attention_selection_forward_and_backward(v5e, t, block_q,
+                                                          block_k):
+    """Block selection and the three block-sparse kernels at the cell's
+    sparse layer (4 query heads on 1 key/value head of 128, top-64 of
+    64-key blocks): the scalar-prefetched (tile, span) list of the causal
+    bound is what SMEM could refuse, the span's selection bits and the
+    (512, 512) score tiles of four heads what VMEM could."""
+    from bigdl_tpu.ops.sparse_attention import select_blocks, \
+        sparse_attention
+    from bigdl_tpu.tensor.policy import compute_dtype
+
+    def loss(q, k, v):
+        sel = select_blocks(q, k, kernel=32, stride=16, block=64, topk=64,
+                            init_blocks=1, window=2048)
+        return sparse_attention(q, k, v, sel, block_q=block_q,
+                                block_k=block_k, interpret=False).sum()
+
+    with compute_dtype(jnp.bfloat16):
+        _compile(jax.grad(loss, argnums=(0, 1, 2)),
+                 v5e((1, 1, 4, t, 128), jnp.float32),
+                 v5e((1, 1, t, 128), jnp.float32),
+                 v5e((1, 1, t, 128), jnp.float32))
+
+
 def test_one_shard_train_step_assembles_no_flat_vector(v5e):
     """A whole train step (forward, backward, Adam) on ONE shard, compiled
     for the described chip in the layout the engine picks there (state
