@@ -45,7 +45,9 @@ Precision is the repo's policy: float32 parameters, bfloat16 matmul inputs
 on a TPU with float32 accumulation; router, softmax, RMSNorm, RoPE, the
 convolution's gates and taps in float32.  When training, every layer is
 recomputed in the backward pass (``jax.checkpoint`` per layer; the layers'
-inputs are what is kept): there is no switch.
+inputs are what is kept, and of what a layer holds, the attention kernels'
+``out`` and ``lse`` and a sparse layer's block list:
+``ops.common.layer_remat_policy``): there is no switch.
 
 Initialisation (``init``): the tied matrix N(0, 1/hidden), so that the
 logits of a normed state have standard deviation near 1; every other matrix
@@ -72,6 +74,7 @@ from bigdl_tpu.nn.short_conv import GatedShortConv
 from bigdl_tpu.nn.sparse_linear_attention import (SELECTION,
                                                   LightningAttention,
                                                   SparseBlockAttention)
+from bigdl_tpu.ops.common import layer_remat_policy
 from bigdl_tpu.parallel.moe import HeldMoE, swiglu, swiglu_init
 from bigdl_tpu.tensor.policy import cast_compute
 
@@ -312,11 +315,9 @@ class HybridMoELM(Module):
             key = f"layer{i}"
             fn = functools.partial(self._layer, i)
             if training:
-                # a sparse layer's block selection is kept, not recomputed
-                fn = jax.checkpoint(
-                    fn, policy=jax.checkpoint_policies.save_only_these_names(
-                        SELECTION)) if c.layer_types[i] == "minicpm4" \
-                    else jax.checkpoint(fn)
+                # the attention kernels' out and lse, and a sparse layer's
+                # block selection, are kept, not recomputed
+                fn = jax.checkpoint(fn, policy=layer_remat_policy(SELECTION))
             h, st = fn(params[key], state.get(key, EMPTY), h)
             if st:
                 new_state[key] = st

@@ -26,8 +26,9 @@ dense FFN's columns held of ``intermediate_size``; what ``W_o`` and
 Precision is the repo's policy: float32 parameters, bfloat16 matmul inputs
 on a TPU with float32 accumulation; router, softmax, RMSNorm and RoPE in
 float32.  When training, every layer is recomputed in the backward pass
-(``jax.checkpoint`` per layer; the layers' inputs are what is kept): there
-is no switch."""
+(``jax.checkpoint`` per layer; the layers' inputs are what is kept, and the
+flash kernels' ``out`` and ``lse``, so the forward kernel is not run again:
+``ops.common.layer_remat_policy``): there is no switch."""
 
 import functools
 from dataclasses import dataclass, fields
@@ -42,6 +43,7 @@ from bigdl_tpu.nn.layers import rms_norm
 from bigdl_tpu.nn.module import EMPTY, Module
 from bigdl_tpu.obs.state_metrics import (bump_state_metrics,
                                          new_state_metrics)
+from bigdl_tpu.ops.common import layer_remat_policy
 from bigdl_tpu.parallel.moe import HeldMoE, swiglu, swiglu_init
 from bigdl_tpu.tensor.policy import cast_compute
 
@@ -208,7 +210,7 @@ class MLAMoELM(Module):
             key = f"layer{i}"
             fn = functools.partial(self._layer, i)
             if training:
-                fn = jax.checkpoint(fn)
+                fn = jax.checkpoint(fn, policy=layer_remat_policy())
             h, st = fn(params[key], state.get(key, EMPTY), h)
             if _STREAM_RMS in st:
                 new_state["hc"] = {"metrics": bump_state_metrics(

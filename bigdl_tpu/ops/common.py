@@ -4,6 +4,23 @@ import functools
 
 import jax
 
+# The attention kernels' forward residuals, named inside their custom VJPs'
+# forward rules (``ops/flash_attention.py``, ``ops/sparse_attention.py``):
+# a ``jax.checkpoint`` whose policy saves these names hands the forward
+# kernel's results to the backward instead of running the kernel again.
+# Outside such a checkpoint a name is the identity.
+ATTN_OUT = "attn_out"
+ATTN_LSE = "attn_lse"
+
+
+def layer_remat_policy(*names):
+    """The policy of a decoder layer's ``jax.checkpoint``: keep the
+    attention kernels' ``out`` and ``lse`` and any of ``names`` the layer
+    holds; recompute everything else from the layer's input.  A layer
+    that names none of them keeps only its input."""
+    return jax.checkpoint_policies.save_only_these_names(ATTN_OUT, ATTN_LSE,
+                                                         *names)
+
 
 @functools.lru_cache(maxsize=1)
 def on_tpu() -> bool:

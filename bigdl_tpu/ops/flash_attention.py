@@ -50,10 +50,12 @@ from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from bigdl_tpu.ops.common import default_interpret, round_up
+from bigdl_tpu.ops.common import (ATTN_LSE, ATTN_OUT, default_interpret,
+                                  round_up)
 from bigdl_tpu.tensor.policy import cast_compute
 from bigdl_tpu.utils.log import get_logger
 
@@ -483,6 +485,8 @@ def _flash_vjp_fwd(q, k, v, sm_scale, causal, block_q, block_k,
                    block_q_bwd, block_k_bwd, interpret):
     out, lse = _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k,
                           interpret)
+    # named for a layer's jax.checkpoint to keep (ops/common.py)
+    out, lse = checkpoint_name(out, ATTN_OUT), checkpoint_name(lse, ATTN_LSE)
     return out, (q, k, v, out, lse)
 
 

@@ -50,10 +50,11 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from bigdl_tpu.ops.common import default_interpret
+from bigdl_tpu.ops.common import ATTN_LSE, ATTN_OUT, default_interpret
 from bigdl_tpu.tensor.policy import cast_compute
 
 _NEG_INF = -1e30
@@ -492,6 +493,8 @@ def _sparse_vjp_fwd(q, k, v, sel, sm_scale, block, block_q, block_k,
     lists = work_lists(sel, block, block_q, block_k)
     out, lse = _sparse_fwd(q, k, v, sel, lists[0], lists[2], sm_scale,
                            block, block_q, block_k, interpret)
+    # named for a layer's jax.checkpoint to keep (ops/common.py)
+    out, lse = checkpoint_name(out, ATTN_OUT), checkpoint_name(lse, ATTN_LSE)
     return out, (q, k, v, sel, out, lse, lists)
 
 

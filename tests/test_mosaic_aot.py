@@ -389,3 +389,65 @@ def test_one_shard_train_step_assembles_no_flat_vector(v5e):
     assert not assembled(leaf.as_text())
     temp = [c.memory_analysis().temp_size_in_bytes for c in (leaf, flat)]
     assert temp[0] < temp[1], temp
+
+
+def _checkpointed_layer_grad(v5e, attend, q_shape, kv_shape, policy):
+    """``(tpu_custom_call count, temporaries)`` of the gradient of one
+    layer under ``jax.checkpoint(policy=)``, compiled for the described
+    chip: a projection in front of ``attend(q, k, v)`` and a loss that
+    reads the layer's output, as a decoder's step has them."""
+    from bigdl_tpu.tensor.policy import compute_dtype
+
+    d = q_shape[-1]
+    f = jax.checkpoint(lambda w, x, k, v: attend(x @ w, k, v), policy=policy)
+
+    def loss(w, x, k, v):
+        return jnp.sum(jnp.square(f(w, x, k, v).astype(jnp.float32)))
+
+    with compute_dtype(jnp.bfloat16):
+        compiled = jax.jit(jax.grad(loss, (0, 1, 2, 3))).lower(
+            v5e((d, d), jnp.float32), v5e(q_shape, jnp.float32),
+            v5e(kv_shape, jnp.float32),
+            v5e(kv_shape, jnp.float32)).compile()
+    return (compiled.as_text().count('custom_call_target="tpu_custom_call"'),
+            compiled.memory_analysis().temp_size_in_bytes)
+
+
+@pytest.mark.parametrize("kind,q_shape,kv_shape", [
+    ("sparse", (1, 1, 4, 32768, 128), (1, 1, 32768, 128)),  # minicpm-sala
+    ("flash", (2, 20, 4096, 256), (2, 20, 4096, 256)),      # glm-4.7-flash
+    ("flash", (1, 32, 8192, 64), (1, 8, 8192, 64)),         # lfm2-24b-a2b
+])
+def test_checkpointed_layer_keeps_out_and_lse(v5e, kind, q_shape, kv_shape):
+    """The decoders' layer policy at the cells' shapes: the gradient holds
+    the forward kernel once (forward, dq, dk/dv: three custom calls, four
+    under the policy the decoders had before), and its temporaries grow by
+    no more than the kept ``out`` (bf16) and ``lse`` (float32)."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    from bigdl_tpu.nn.sparse_linear_attention import SELECTION
+    from bigdl_tpu.ops.common import layer_remat_policy
+    from bigdl_tpu.ops.flash_attention import flash_attention
+    from bigdl_tpu.ops.sparse_attention import select_blocks, \
+        sparse_attention
+
+    if kind == "sparse":
+        def attend(q, k, v):
+            sel = checkpoint_name(select_blocks(
+                q, k, kernel=32, stride=16, block=64, topk=64,
+                init_blocks=1, window=2048), SELECTION)
+            return sparse_attention(q, k, v, sel, interpret=False)
+        names, before = (SELECTION,), \
+            jax.checkpoint_policies.save_only_these_names(SELECTION)
+    else:
+        def attend(q, k, v):
+            return flash_attention(q, k, v, causal=True, interpret=False)
+        names, before = (), None
+    calls, temp = _checkpointed_layer_grad(v5e, attend, q_shape, kv_shape,
+                                           layer_remat_policy(*names))
+    calls_before, temp_before = _checkpointed_layer_grad(
+        v5e, attend, q_shape, kv_shape, before)
+    rows = int(np.prod(q_shape[:-1]))
+    assert (calls, calls_before) == (3, 4)
+    assert temp - temp_before <= rows * kv_shape[-1] * 2 + rows * 4, (
+        temp, temp_before)
