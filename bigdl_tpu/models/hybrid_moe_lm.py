@@ -37,6 +37,17 @@ untied ``head``, a share of heads ``held_heads`` and MiniCPM's muP scales
 the final state over ``hidden_size / dim_model_base``), all of which
 default to what LFM2 had: its parameter tree and logits are as they were.
 
+A fifth (Granite-4.0-H's ``granitemoehybrid``, its ``layer_types``
+``"mamba"`` and ``"attention"`` read as ``"mamba"`` and
+``"full_attention"``): ``"mamba"`` (``nn.mamba2.Mamba2``: the Mamba-2
+mixer, its selective scan through ``ops/ssd.py``, whole on every
+tensor-parallel rank).  Granite's scalars are this family's:
+``embedding_multiplier`` is ``scale_emb``, ``residual_multiplier`` the
+scale of every branch, ``logits_scaling`` divides the normed final state,
+``attention_multiplier`` is the softmax scale; its attention layers hold
+``held_heads`` of the query heads, have no QK-norm (``qk_norm`` False)
+and, with ``position_embedding_type`` ``"nope"``, no positions.
+
 This decoder and ``models/mla_moe_lm.py`` share their parts (``HeldMoE``,
 ``swiglu``, ``rms_norm``, ``rope``, the state metrics) by import and their
 skeleton by shape only.
@@ -69,6 +80,7 @@ import jax.numpy as jnp
 
 from bigdl_tpu.nn.attention import GroupedQueryAttention
 from bigdl_tpu.nn.layers import rms_norm
+from bigdl_tpu.nn.mamba2 import Mamba2
 from bigdl_tpu.nn.module import EMPTY, Module
 from bigdl_tpu.nn.short_conv import GatedShortConv
 from bigdl_tpu.nn.sparse_linear_attention import (SELECTION,
@@ -78,7 +90,8 @@ from bigdl_tpu.ops.common import layer_remat_policy
 from bigdl_tpu.parallel.moe import HeldMoE, swiglu, swiglu_init
 from bigdl_tpu.tensor.policy import cast_compute
 
-LAYER_TYPES = ("conv", "full_attention", "lightning-attn", "minicpm4")
+LAYER_TYPES = ("conv", "full_attention", "lightning-attn", "minicpm4",
+               "mamba")
 # MiniCPM4-8B's published ``sparse_config`` (InfLLM-v2), the default of a
 # ``minicpm4`` layer
 SPARSE_CONFIG = (("block_size", 64), ("dense_len", 8192),
@@ -91,6 +104,15 @@ MIXER_FIXED = (("attn_use_output_gate", True), ("attn_use_rope", False),
                ("lightning_scale", "1/sqrt(d)"), ("lightning_use_rope", True),
                ("qk_norm", True), ("use_output_gate", True),
                ("use_output_norm", True))
+# Granite-4.0-H's names (``granitemoehybrid``) for this family's, and the
+# one value each of its switches has where its layers are built: one group,
+# no projection bias, a convolution bias, RMSNorm
+GRANITE_NAMES = (("embedding_multiplier", "scale_emb"),
+                 ("residual_multiplier", "scale_depth"),
+                 ("shared_intermediate_size", "intermediate_size"))
+GRANITE_FIXED = (("mamba_conv_bias", True), ("mamba_n_groups", 1),
+                 ("mamba_proj_bias", False),
+                 ("normalization_function", "rmsnorm"))
 # beside the sum of the chosen scores, as the family's published forward
 # pass has it (``route_sigmoid_topk``'s default is another family's 1e-20)
 TOPK_SUM_EPS = 1e-6
@@ -141,6 +163,21 @@ class HybridMoEConfig:
     lightning_head_dim: int = 0
     # ``minicpm4``
     sparse_config: Tuple[Tuple[str, int], ...] = SPARSE_CONFIG
+    # ``full_attention``: "nope" = no positions; per-head QK-norm or none;
+    # the softmax scale (None: head_dim ** -0.5)
+    position_embedding_type: str = "rope"
+    qk_norm: bool = True
+    attention_multiplier: Optional[float] = None
+    # the normed final state over this before the head (None: as
+    # ``dim_model_base`` says)
+    logits_scaling: Optional[float] = None
+    # ``mamba`` (Mamba-2, one group of B and C)
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_chunk_size: int = 256
 
     def __post_init__(self):
         if len(self.layer_types) != self.num_hidden_layers:
@@ -157,6 +194,15 @@ class HybridMoEConfig:
         if not self.head_dim:
             object.__setattr__(self, "head_dim",
                                self.hidden_size // self.num_attention_heads)
+        if self.position_embedding_type not in ("rope", "nope"):
+            raise ValueError(f"position_embedding_type "
+                             f"{self.position_embedding_type!r}: rope or "
+                             "nope")
+        if "mamba" in self.layer_types and (
+                self.mamba_n_heads * self.mamba_d_head
+                != self.mamba_expand * self.hidden_size):
+            raise ValueError("mamba: mamba_n_heads x mamba_d_head must be "
+                             "mamba_expand x hidden_size")
         if "lightning-attn" in self.layer_types and (
                 self.lightning_heads != self.num_attention_heads
                 or self.lightning_nkv not in (0, self.lightning_heads)):
@@ -177,6 +223,8 @@ class HybridMoEConfig:
 
     @property
     def head_divisor(self) -> float:
+        if self.logits_scaling is not None:
+            return float(self.logits_scaling)
         return self.hidden_size / (self.dim_model_base or self.hidden_size)
 
     @classmethod
@@ -184,13 +232,23 @@ class HybridMoEConfig:
         """The source's keys as they are; MiniCPM's names (``mixer_types``,
         ``rms_norm_eps``, a top-level ``rope_theta``) are read as this
         family's, and its switches (``MIXER_FIXED``) must hold the one
-        value the two mixers are built for."""
+        value the two mixers are built for; so are Granite-4.0-H's
+        (``GRANITE_NAMES``, ``layer_types`` ``"attention"`` as
+        ``"full_attention"``; ``GRANITE_FIXED``)."""
         cfg = dict(cfg)
-        for key, value in MIXER_FIXED:
-            if cfg.get(key, value) != value:
-                raise ValueError(f"{key}={cfg[key]!r}: the lightning-attn "
-                                 f"and minicpm4 mixers are built for "
-                                 f"{value!r} only")
+        for fixed, kinds in ((MIXER_FIXED, "the lightning-attn and minicpm4 "
+                              "mixers"), (GRANITE_FIXED, "the mamba mixer")):
+            for key, value in fixed:
+                if cfg.get(key, value) != value:
+                    raise ValueError(f"{key}={cfg[key]!r}: {kinds} are "
+                                     f"built for {value!r} only")
+        if cfg.get("model_type") == "granitemoehybrid":
+            cfg.update({ours: cfg[theirs] for theirs, ours in GRANITE_NAMES
+                        if theirs in cfg})
+            cfg["layer_types"] = ["full_attention" if k == "attention" else k
+                                  for k in cfg["layer_types"]]
+            # its attention layers have no per-head QK-norm
+            cfg["qk_norm"] = False
         for theirs, ours in (("mixer_types", "layer_types"),
                              ("rms_norm_eps", "norm_eps")):
             if theirs in cfg:
@@ -220,9 +278,19 @@ class HybridMoELM(Module):
         c = self.config = config
         kinds = set(c.layer_types)
         self.conv = GatedShortConv(c.hidden_size, c.conv_L_cache)
-        self.attn = GroupedQueryAttention(
-            c.hidden_size, c.num_attention_heads, c.num_key_value_heads,
-            c.head_dim, rope_theta=c.rope_theta, qk_norm_eps=c.norm_eps)
+        if "full_attention" in kinds:
+            self.attn = GroupedQueryAttention(
+                c.hidden_size, c.num_attention_heads, c.num_key_value_heads,
+                c.head_dim, held=c.held_heads,
+                rope_theta=(None if c.position_embedding_type == "nope"
+                            else c.rope_theta),
+                qk_norm_eps=c.norm_eps if c.qk_norm else None,
+                sm_scale=c.attention_multiplier)
+        if "mamba" in kinds:
+            self.mamba = Mamba2(c.hidden_size, c.mamba_n_heads,
+                                c.mamba_d_head, c.mamba_d_state,
+                                c.mamba_d_conv, eps=c.norm_eps,
+                                chunk=c.mamba_chunk_size)
         if "lightning-attn" in kinds:
             self.lightning = LightningAttention(
                 c.hidden_size, c.lightning_heads,
@@ -243,10 +311,11 @@ class HybridMoELM(Module):
     def _operator(self, i: int):
         """(parameter key, module) of layer ``i``'s token mixer."""
         return {"conv": ("conv", self.conv),
-                "full_attention": ("attn", self.attn),
+                "full_attention": ("attn", getattr(self, "attn", None)),
                 "lightning-attn": ("lightning", getattr(
                     self, "lightning", None)),
                 "minicpm4": ("sparse", getattr(self, "sparse", None)),
+                "mamba": ("mamba", getattr(self, "mamba", None)),
                 }[self.config.layer_types[i]]
 
     def _is_dense(self, i: int) -> bool:

@@ -253,6 +253,27 @@ def _fan_in_normal(key, fan_in, fan_out):
     return jax.random.normal(key, (fan_in, fan_out)) * fan_in ** -0.5
 
 
+# the standard deviation of a NoPE layer's seeded scores without QK-norm
+# (GroupedQueryAttention)
+NOPE_SCORE_STD = 4.0
+
+
+def held_share(heads, kv_heads, held):
+    """``(first, count, kv_count)`` of a tensor-parallel rank's share
+    ``held = (first, count)`` of ``heads`` query heads on ``kv_heads``
+    key/value heads (None: all of them): whole groups with their key/value
+    heads, or heads of one group with its one."""
+    first, count = (0, heads) if held is None else held
+    group = heads // kv_heads if kv_heads and heads % kv_heads == 0 else 0
+    if not (group and 0 <= first and count > 0 and first + count <= heads) \
+            or (first % group or count % group) and (
+                first // group != (first + count - 1) // group):
+        raise ValueError(f"held heads {held} of {heads} on {kv_heads} "
+                         "key/value heads: within one group, or whole "
+                         "groups")
+    return first, count, max(1, count // group)
+
+
 def _flash_wanted(use_flash):
     """A module's ``use_flash``: None = the Pallas flash kernels on a TPU,
     XLA's attention elsewhere."""
@@ -354,17 +375,29 @@ class LatentAttention(Module):
 
 
 class GroupedQueryAttention(Module):
-    """Causal self-attention with grouped-query heads, a per-head RMSNorm
-    on queries and keys, and RoPE (the attention layers of a hybrid decoder
-    such as LFM2's ``lfm2_moe``).
+    """Causal self-attention with grouped-query heads (the attention layers
+    of a hybrid decoder: LFM2's ``lfm2_moe``, Granite-4.0-H's
+    ``granitemoehybrid``).
 
     ``q = u W_q`` as ``heads`` heads of ``head_dim``, ``k = u W_k`` and ``v
-    = u W_v`` as ``kv_heads`` heads, no biases; every query head and every
-    key head is normalised over its ``head_dim`` numbers (one weight
-    ``q_norm`` for all query heads, one ``k_norm`` for all key heads), THEN
-    turned by :func:`rope` over all ``head_dim`` dims; query head ``j``
+    = u W_v`` as ``kv_heads`` heads, no biases; with ``qk_norm_eps`` every
+    query head and every key head is normalised over its ``head_dim``
+    numbers (one weight ``q_norm`` for all query heads, one ``k_norm`` for
+    all key heads), THEN, with ``rope_theta``, turned by :func:`rope` over
+    all ``head_dim`` dims (None: no positions, "NoPE"); query head ``j``
     attends through key/value head ``j // (heads / kv_heads)``; scores
-    scaled by ``head_dim ** -0.5``; ``W_o`` (heads * head_dim, hidden).
+    scaled by ``sm_scale`` (default ``head_dim ** -0.5``); ``W_o`` (heads *
+    head_dim, hidden).
+
+    ``held = (first, count)``: a tensor-parallel rank's share of the query
+    heads, whole groups with their key/value heads or heads of one group
+    with its one; ``W_q``, ``W_k``, ``W_v`` are those heads' columns,
+    ``W_o`` their rows, and the output is the rank's partial sum of the
+    layer's (docs/parallelism.md §Held heads).  Without QK-norm, ``W_q`` and
+    ``W_k`` are drawn so that the scaled scores of unit-variance inputs have
+    standard deviation ``NOPE_SCORE_STD``: at N(0, 1/fan_in) and a scale of
+    1/64 they would have 0.125, and every query would average v over its
+    whole prefix (PERF.md §4).
 
     On a TPU the Pallas flash kernels take the grouped heads as they are (K
     and V are not repeated); elsewhere K and V are repeated for
@@ -373,15 +406,16 @@ class GroupedQueryAttention(Module):
     take one K/V head a query head."""
 
     def __init__(self, hidden_size: int, num_heads: int, kv_heads: int,
-                 head_dim: int, *, rope_theta: float = 10000.0,
-                 qk_norm_eps: float = 1e-6, use_flash=None, name=None):
+                 head_dim: int, *, rope_theta: Optional[float] = 10000.0,
+                 qk_norm_eps: Optional[float] = 1e-6,
+                 sm_scale: Optional[float] = None, held=None,
+                 use_flash=None, name=None):
         super().__init__(name)
-        if num_heads % kv_heads:
-            raise ValueError(f"{num_heads} query heads on {kv_heads} "
-                             "key/value heads: not a whole group each")
-        self.hidden_size, self.num_heads = hidden_size, num_heads
-        self.kv_heads, self.head_dim = kv_heads, head_dim
+        _, count, kv_count = held_share(num_heads, kv_heads, held)
+        self.hidden_size, self.num_heads = hidden_size, count
+        self.kv_heads, self.head_dim = kv_count, head_dim
         self.rope_theta, self.qk_norm_eps = rope_theta, qk_norm_eps
+        self.sm_scale = sm_scale
         # None = the Pallas flash kernel on a TPU, XLA attention elsewhere
         self.use_flash = use_flash
 
@@ -390,9 +424,15 @@ class GroupedQueryAttention(Module):
         h, h_kv = self.num_heads, self.kv_heads
         ks = jax.random.split(rng, 4)
         w = _fan_in_normal
-        return {"wq": w(ks[0], d, h * hd), "wk": w(ks[1], d, h_kv * hd),
-                "wv": w(ks[2], d, h_kv * hd), "q_norm": jnp.ones((hd,)),
-                "k_norm": jnp.ones((hd,)), "wo": w(ks[3], h * hd, d)}, EMPTY
+        p = {"wq": w(ks[0], d, h * hd), "wk": w(ks[1], d, h_kv * hd),
+             "wv": w(ks[2], d, h_kv * hd), "wo": w(ks[3], h * hd, d)}
+        if self.qk_norm_eps is None:
+            scale = hd ** -0.5 if self.sm_scale is None else self.sm_scale
+            gain = (NOPE_SCORE_STD / (scale * hd ** 0.5)) ** 0.5
+            p["wq"], p["wk"] = p["wq"] * gain, p["wk"] * gain
+        else:
+            p["q_norm"], p["k_norm"] = jnp.ones((hd,)), jnp.ones((hd,))
+        return p, EMPTY
 
     def forward(self, params, state, x, training=False, rng=None):
         b, t, _ = x.shape
@@ -405,21 +445,24 @@ class GroupedQueryAttention(Module):
             q = heads(_project(x, params["wq"]), h)
             k = heads(_project(x, params["wk"]), h_kv)
             v = heads(_project(x, params["wv"]), h_kv)
-            q = rope(rms_norm(q, params["q_norm"], self.qk_norm_eps),
-                     self.rope_theta)
-            k = rope(rms_norm(k, params["k_norm"], self.qk_norm_eps),
-                     self.rope_theta)
+            if self.qk_norm_eps is not None:
+                q = rms_norm(q, params["q_norm"], self.qk_norm_eps)
+                k = rms_norm(k, params["k_norm"], self.qk_norm_eps)
+            if self.rope_theta is not None:
+                q, k = rope(q, self.rope_theta), rope(k, self.rope_theta)
         with jax.named_scope("gqa/attn"):
             if _flash_wanted(self.use_flash):
                 from bigdl_tpu.ops.flash_attention import flash_attention
 
-                out = flash_attention(q, k, v, causal=True)
+                out = flash_attention(q, k, v, causal=True,
+                                      sm_scale=self.sm_scale)
             else:
                 group = h // h_kv
                 out = dot_product_attention(
                     q, jnp.repeat(k, group, axis=1),
                     jnp.repeat(v, group, axis=1),
-                    mask=jnp.tril(jnp.ones((t, t), bool)))
+                    mask=jnp.tril(jnp.ones((t, t), bool)),
+                    scale=self.sm_scale)
         with jax.named_scope("gqa/proj"):
             out = out.transpose(0, 2, 1, 3).reshape(b, t, h * hd)
             return _project(out, params["wo"]), EMPTY

@@ -40,7 +40,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from bigdl_tpu.nn.attention import (_fan_in_normal, _flash_wanted, _project,
-                                    dot_product_attention, rope)
+                                    dot_product_attention, held_share, rope)
 from bigdl_tpu.nn.layers import rms_norm
 from bigdl_tpu.nn.module import EMPTY, Module
 from bigdl_tpu.obs.state_metrics import bump_state_metrics, new_state_metrics
@@ -61,13 +61,6 @@ SPARSE_COUNTERS = ("sparse.selected_blocks", "sparse.visible_blocks",
 QK_NORM_INIT = 2.0
 
 
-def _held(heads, held):
-    first, count = (0, heads) if held is None else held
-    if not (0 <= first and count > 0 and first + count <= heads):
-        raise ValueError(f"held heads {held} of {heads}")
-    return first, count
-
-
 class _HeldHeads(Module):
     """Projections shared by both mixers: a share of ``heads`` query heads
     of ``head_dim`` with their QK-norm, the output gate and W_o."""
@@ -76,7 +69,7 @@ class _HeldHeads(Module):
         super().__init__(name)
         self.hidden_size, self.heads, self.head_dim = (hidden_size, heads,
                                                        head_dim)
-        self.first, self.count = _held(heads, held)
+        self.first, self.count, _ = held_share(heads, heads, held)
         self.eps = eps
 
     def _build(self, rng, kv_count):
@@ -140,14 +133,7 @@ class SparseBlockAttention(_HeldHeads):
                  topk: int = 64, init_blocks: int = 1,
                  window_size: int = 2048, dense_len: int = 8192, name=None):
         super().__init__(hidden_size, heads, head_dim, held, eps, name)
-        group = heads // kv_heads
-        if heads % kv_heads or (
-                self.first // group != (self.first + self.count - 1) // group
-                and (self.first % group or self.count % group)):
-            raise ValueError(f"held heads {held} of {heads} on {kv_heads} "
-                             "key/value heads: within one group, or whole "
-                             "groups")
-        self.kv_count = max(1, self.count // group)
+        self.kv_count = held_share(heads, kv_heads, held)[2]
         self.select_kw = dict(kernel=kernel_size, stride=kernel_stride,
                               block=block_size, topk=topk,
                               init_blocks=init_blocks, window=window_size)

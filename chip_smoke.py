@@ -79,7 +79,8 @@ FULL = dict(
               ln_rows=4096, mm=(256, 768, 3072), hc=(4, 4096, 3584),
               sala=dict(heads=4, first=28, t=32768, hd=128, kernel=32,
                         stride=16, block=64, topk=64, init_blocks=1,
-                        window=2048)),
+                        window=2048),
+              ssd=dict(heads=64, hd=64, state=128, t=16384, chunk=256)),
     moe=dict(tokens=4096, d=3584, hidden=1024, experts=64, held=(8, 8), k=4),
 )
 TINY = dict(
@@ -93,7 +94,8 @@ TINY = dict(
               ln_rows=16, mm=(32, 128, 128), hc=(4, 64, 128),
               sala=dict(heads=2, first=30, t=512, hd=32, kernel=32,
                         stride=16, block=64, topk=4, init_blocks=1,
-                        window=128)),
+                        window=128),
+              ssd=dict(heads=2, hd=8, state=16, t=200, chunk=64)),
     moe=dict(tokens=256, d=32, hidden=16, experts=16, held=(4, 2), k=2),
 )
 
@@ -228,6 +230,7 @@ class Smoke:
         check("hc_mix_pre_bwd_dh", got[1], dh, TOL_F32_SUM)
         del xs, gs, through, got, want, dx
         self.sala_kernels(check, ref)
+        self.ssd_kernels(check, ref)
 
         # paged decode / verify attention over a page pool, f32 and int8
         P = S * nb
@@ -413,6 +416,64 @@ class Smoke:
         for name, a, b in zip("qkv", jax.jit(vjp_of(sparse))(q, kk, v, g),
                               ref(vjp_of(dense), q, kk, v, g)):
             check(f"sparse_attention_bwd_d{name}", a, b, TOL_MXU_BWD)
+
+    def ssd_kernels(self, check, ref):
+        """The SSD kernels (Mamba-2's selective scan) at the
+        granite-4.0-h-micro cell's mixer (64 heads of 64, a state of 128,
+        chunks of 256 over 16,384 positions), forward and the six gradients
+        against autodiff of the token-by-token recurrence: Mosaic compiles
+        the heads-innermost grid, the reversed chunk walk and the (128, 64)
+        float32 states in VMEM only here.  The recurrence goes in segments
+        of 128 positions under ``jax.checkpoint``: its per-position states
+        would be 32 GB."""
+        import jax
+        import jax.numpy as jnp
+
+        from bigdl_tpu.ops.ssd import ssd
+
+        c = self.sz["kern"]["ssd"]
+        h, p, n, t = c["heads"], c["hd"], c["state"], c["t"]
+        ks = jax.random.split(jax.random.PRNGKey(40), 6)
+        x = jax.random.normal(ks[0], (1, t, h, p))
+        dt = jax.nn.softplus(jax.random.normal(ks[1], (1, t, h)) - 3.0)
+        a_log = jnp.log(jax.random.uniform(ks[2], (h,), minval=1.0,
+                                           maxval=16.0))
+        b, cc = (jax.random.normal(k_, (1, t, n)) for k_ in ks[3:5])
+        d = jnp.ones((h,))
+        g = jax.random.normal(ks[5], (1, t, h, p))
+        seg = 128 if t % 128 == 0 else t
+
+        def plain(x, dt, a_log, b, cc, d):
+            a = -jnp.exp(a_log)
+
+            def step(s, inputs):
+                xt, dtt, bt, ct = inputs
+                s = (jnp.exp(dtt * a)[:, None, None] * s
+                     + (dtt[:, None] * xt)[:, :, None] * bt[None, None, :])
+                return s, jnp.einsum("hpn,n->hp", s, ct)
+
+            @jax.checkpoint
+            def segment(s, inputs):
+                return jax.lax.scan(step, s, inputs)
+
+            split = lambda v: v[0].reshape((t // seg, seg) + v.shape[2:])
+            _, y = jax.lax.scan(segment, jnp.zeros((h, p, n)),
+                                tuple(map(split, (x, dt, b, cc))))
+            return (y.reshape(t, h, p) + d[:, None] * x[0])[None]
+
+        mine = lambda x, dt, a_log, b, cc, d: ssd(
+            x, dt, -jnp.exp(a_log), b, cc, d, chunk=c["chunk"])
+        args = (x, dt, a_log, b, cc, d)
+        check("ssd_fwd", jax.jit(mine)(*args), ref(plain, *args), TOL_MXU)
+
+        def vjp_of(f):
+            return lambda g, *a: jax.vjp(f, *a)[1](g)
+
+        for name, got, want in zip(
+                ("x", "dt", "a_log", "b", "c", "d"),
+                jax.jit(vjp_of(mine))(g, *args),
+                ref(vjp_of(plain), g, *args)):
+            check(f"ssd_bwd_d{name}", got, want, TOL_MXU_BWD)
 
     # -- phase: expert layer -------------------------------------------------
     def expert_layer(self):

@@ -451,3 +451,25 @@ def test_checkpointed_layer_keeps_out_and_lse(v5e, kind, q_shape, kv_shape):
     assert (calls, calls_before) == (3, 4)
     assert temp - temp_before <= rows * kv_shape[-1] * 2 + rows * 4, (
         temp, temp_before)
+
+
+@pytest.mark.parametrize("t,chunk", [(16384, None), (1000, 256)])
+def test_ssd_forward_and_backward(v5e, t, chunk):
+    """The SSD kernels (forward; the states and gradient kernels of the
+    backward) at the granite-4.0-h-micro cell's Mamba-2 mixer (64 heads of
+    64, a state of 128, chunks of 256 over 16k) and at a length the chunks
+    do not divide: every head's (128, 64) float32 state in VMEM is what
+    Mosaic could refuse."""
+    from bigdl_tpu.ops.ssd import ssd
+    from bigdl_tpu.tensor.policy import compute_dtype
+
+    def loss(x, dt, a_log, b, c, d):
+        return ssd(x, dt, -jnp.exp(a_log), b, c, d, chunk=chunk,
+                   interpret=False).sum()
+
+    with compute_dtype(jnp.bfloat16):
+        _compile(jax.grad(loss, argnums=tuple(range(6))),
+                 v5e((1, t, 64, 64), jnp.float32), v5e((1, t, 64),
+                                                      jnp.float32),
+                 v5e((64,), jnp.float32), v5e((1, t, 128), jnp.float32),
+                 v5e((1, t, 128), jnp.float32), v5e((64,), jnp.float32))
