@@ -7,7 +7,7 @@ For ``u`` (..., T, d), with ``d_in = heads · head_dim``:
 - ``[z, xBC, Δ_raw] = u W_in`` (widths ``d_in``, ``d_in + 2N``, ``heads``;
   no bias);
 - ``xBC ← SiLU(conv_K(xBC) + b_conv)``: a depthwise causal convolution of
-  ``K`` taps, one filter a channel (``nn.short_conv.causal_taps``);
+  ``K`` taps, one filter a channel (``ops/causal_conv.py``);
 - ``[x, B, C] = xBC``, x viewed as (T, heads, head_dim), B and C (T, N);
 - ``Δ = softplus(Δ_raw + dt_bias)``, ``A = −exp(A_log)``, one of each a
   head;
@@ -18,15 +18,20 @@ For ``u`` (..., T, d), with ``d_in = heads · head_dim``:
 - ``Mix(u) = g W_out`` (``d_in`` x d, no bias).
 
 The two matmuls take their inputs in the policy's compute dtype with
-float32 accumulation (device scope ``mamba/proj``); the convolution, its
-gate and ``Δ`` float32 (``mamba/conv``); the scan (``mamba/ssd``); the gate
-and the norm float32 (``mamba/norm``).  The mixer is whole on every rank of
+float32 accumulation (device scope ``mamba/proj``); the convolution with
+its bias and SiLU float32, one Pallas pass each way that reads ``xBC`` in
+place out of ``u W_in`` (``mamba/causal_conv``: ``ops/causal_conv.py``)
+wherever its tile rule takes the widths, else the plain expression
+(``nn.short_conv.causal_taps``, in ``mamba/conv``); the gate's split and
+``Δ`` float32 (``mamba/conv``); the scan (``mamba/ssd``); the gate and the
+norm float32 (``mamba/norm``).  The mixer is whole on every rank of
 a tensor-parallel group: its heads share ``B``, ``C`` and the norm's
 statistic (docs/parallelism.md §A whole Mamba-2 mixer beside held
 attention heads).
 
-It counts, in its model state, each scan it applies (``ssm.scans``) and,
-as a fine mean, the share of the carried state that survives one chunk
+It counts, in its model state, each scan it applies (``ssm.scans``), each
+convolution traced through the kernels (``ssm.fused_convs``) and, as a
+fine mean, the share of the carried state that survives one chunk
 (``ssm.chunk_carry``: the mean over heads and chunks of ``exp(Σ_chunk Δ
 A)``) (``obs/state_metrics.py``).  This module mixes a whole sequence
 (training, prefill); a decode step would need each layer's scan state and
@@ -43,10 +48,12 @@ from bigdl_tpu.nn.layers import rms_norm
 from bigdl_tpu.nn.module import Module
 from bigdl_tpu.nn.short_conv import causal_taps
 from bigdl_tpu.obs.state_metrics import bump_state_metrics, new_state_metrics
+from bigdl_tpu.ops.causal_conv import causal_conv, conv_blocks
 from bigdl_tpu.ops.ssd import DEFAULT_CHUNK, chunk_carry, ssd
 from bigdl_tpu.tensor.policy import cast_compute
 
-SSM_COUNTERS = ("ssm.scans",)
+SCANS, FUSED_CONVS = "ssm.scans", "ssm.fused_convs"
+SSM_COUNTERS = (SCANS, FUSED_CONVS)
 SSM_FINE = ("ssm.chunk_carry",)
 # Mamba-2's initialisation of the step size and the decay (the published
 # config gives none): Δ log-uniform in [DT_RANGE], floored; A in [A_RANGE]
@@ -105,11 +112,18 @@ class Mamba2(Module):
         d_in, n, h = self.d_in, self.d_state, self.heads
         with jax.named_scope("mamba/proj"):
             zxbcdt = _mm(u, params["w_in"])
+        fused = conv_blocks(t, d_in, self.conv_dim, self.kernel) is not None
+        if fused:
+            with jax.named_scope("mamba/causal_conv"):
+                xbc = causal_conv(zxbcdt, params["conv_w"], params["conv_b"],
+                                  offset=d_in)
+        else:
+            with jax.named_scope("mamba/conv"):
+                xbc = jax.nn.silu(causal_taps(
+                    zxbcdt[..., d_in:d_in + self.conv_dim],
+                    params["conv_w"].astype(jnp.float32)) + params["conv_b"])
         with jax.named_scope("mamba/conv"):
             z = zxbcdt[..., :d_in]
-            xbc = jax.nn.silu(causal_taps(
-                zxbcdt[..., d_in:d_in + self.conv_dim],
-                params["conv_w"].astype(jnp.float32)) + params["conv_b"])
             x = xbc[..., :d_in].reshape(batch, t, h, self.head_dim)
             b, c = xbc[..., d_in:d_in + n], xbc[..., d_in + n:]
             dt = jax.nn.softplus(zxbcdt[..., d_in + self.conv_dim:]
@@ -124,4 +138,5 @@ class Mamba2(Module):
             out = _mm(g, params["w_out"]).astype(u.dtype)
         carry = jax.lax.stop_gradient(chunk_carry(dt, a, self.chunk))
         return out, {"metrics": bump_state_metrics(
-            state["metrics"], {"ssm.scans": 1}, {"ssm.chunk_carry": carry})}
+            state["metrics"], {SCANS: 1, FUSED_CONVS: int(fused)},
+            {"ssm.chunk_carry": carry})}

@@ -80,7 +80,9 @@ FULL = dict(
               sala=dict(heads=4, first=28, t=32768, hd=128, kernel=32,
                         stride=16, block=64, topk=64, init_blocks=1,
                         window=2048),
-              ssd=dict(heads=64, hd=64, state=128, t=16384, chunk=256)),
+              ssd=dict(heads=64, hd=64, state=128, t=16384, chunk=256),
+              conv=dict(t=16384, wide=8512, offset=4096, width=4352,
+                        taps=4)),
     moe=dict(tokens=4096, d=3584, hidden=1024, experts=64, held=(8, 8), k=4),
 )
 TINY = dict(
@@ -95,7 +97,8 @@ TINY = dict(
               sala=dict(heads=2, first=30, t=512, hd=32, kernel=32,
                         stride=16, block=64, topk=4, init_blocks=1,
                         window=128),
-              ssd=dict(heads=2, hd=8, state=16, t=200, chunk=64)),
+              ssd=dict(heads=2, hd=8, state=16, t=200, chunk=64),
+              conv=dict(t=1152, wide=640, offset=128, width=384, taps=4)),
     moe=dict(tokens=256, d=32, hidden=16, experts=16, held=(4, 2), k=2),
 )
 
@@ -231,6 +234,7 @@ class Smoke:
         del xs, gs, through, got, want, dx
         self.sala_kernels(check, ref)
         self.ssd_kernels(check, ref)
+        self.conv_kernels(check, ref)
 
         # paged decode / verify attention over a page pool, f32 and int8
         P = S * nb
@@ -474,6 +478,46 @@ class Smoke:
                 jax.jit(vjp_of(mine))(g, *args),
                 ref(vjp_of(plain), g, *args)):
             check(f"ssd_bwd_d{name}", got, want, TOL_MXU_BWD)
+
+    def conv_kernels(self, check, ref):
+        """Mamba-2's causal convolution with its bias and SiLU
+        (``ops/causal_conv.py``) at the granite-4.0-h-micro cell's mixer
+        (x‖B‖C, 4,352 channels, read at 4,096 of ``W_in``'s 8,512-wide
+        output, 4 taps, 16,384 positions), forward and the three gradients
+        against autodiff of ``causal_taps``: float32 both ways, so only the
+        ``exp`` of the SiLU and the order of the sums differ.  Mosaic
+        compiles the halo blocks and the unaligned lane slices only
+        here."""
+        import jax
+        import jax.numpy as jnp
+
+        from bigdl_tpu.nn.short_conv import causal_taps
+        from bigdl_tpu.ops.causal_conv import causal_conv
+
+        c = self.sz["kern"]["conv"]
+        t, off, width = c["t"], c["offset"], c["width"]
+        ks = jax.random.split(jax.random.PRNGKey(41), 4)
+        u = jax.random.normal(ks[0], (1, t, c["wide"]))
+        bound = c["taps"] ** -0.5
+        w = jax.random.uniform(ks[1], (c["taps"], width), minval=-bound,
+                               maxval=bound)
+        b = jax.random.uniform(ks[2], (width,), minval=-bound, maxval=bound)
+        g = jax.random.normal(ks[3], (1, t, width))
+
+        def plain(u, w, b):
+            return jax.nn.silu(causal_taps(u[..., off:off + width], w) + b)
+
+        mine = lambda u, w, b: causal_conv(u, w, b, offset=off)
+        check("causal_conv_fwd", jax.jit(mine)(u, w, b), ref(plain, u, w, b),
+              TOL_F32)
+
+        def vjp_of(f):
+            return lambda g, *a: jax.vjp(f, *a)[1](g)
+
+        for name, got, want in zip(("u", "w", "b"),
+                                   jax.jit(vjp_of(mine))(g, u, w, b),
+                                   ref(vjp_of(plain), g, u, w, b)):
+            check(f"causal_conv_bwd_d{name}", got, want, TOL_F32)
 
     # -- phase: expert layer -------------------------------------------------
     def expert_layer(self):

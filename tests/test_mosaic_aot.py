@@ -473,3 +473,78 @@ def test_ssd_forward_and_backward(v5e, t, chunk):
                                                       jnp.float32),
                  v5e((64,), jnp.float32), v5e((1, t, 128), jnp.float32),
                  v5e((1, t, 128), jnp.float32), v5e((64,), jnp.float32))
+
+
+@pytest.mark.parametrize("t,wide,offset,width", [
+    (16384, 8512, 4096, 4352),      # granite-4.0-h-micro.train-tp4-16k
+    (1152, 640, 128, 384),          # a partial last tile
+])
+def test_causal_conv_forward_and_backward(v5e, t, wide, offset, width):
+    """The Mamba-2 causal convolution's two kernels at the Granite cell's
+    mixer (x‖B‖C, 4,352 channels at 4,096 of ``W_in``'s 8,512-wide
+    output, 4 taps, 16k) and at a length the tiles do not divide: the
+    128-position halo blocks of the same array and the unaligned lane
+    slices of the scratch are what Mosaic could refuse."""
+    from bigdl_tpu.ops.causal_conv import causal_conv
+
+    f32 = lambda *shape: v5e(shape, jnp.float32)
+
+    def vjp(u, w, b, g):
+        return jax.vjp(lambda u, w, b: causal_conv(
+            u, w, b, offset=offset, interpret=False), u, w, b)[1](g)
+
+    _compile(vjp, f32(1, t, wide), f32(4, width), f32(width),
+             f32(1, t, width))
+
+
+def test_checkpointed_mamba_gradient_shifts_nothing_in_hbm(v5e, monkeypatch):
+    """``jax.checkpoint(Mamba2)``'s gradient at the Granite cell's widths
+    and a short sequence, through the kernels: no top-level ``slice`` in
+    the convolution's scope and none shifted along the sequence, no copy
+    of ``W_in``'s output in front of the kernels (they read its T-minor
+    layout as a (B, W, T) array, a bitcast), and no more temporaries than
+    the same layer through the plain expression."""
+    import re
+
+    import bigdl_tpu.ops.common as common
+    from bigdl_tpu.nn import mamba2
+    from bigdl_tpu.tensor.policy import compute_dtype
+
+    monkeypatch.setattr(common, "on_tpu", lambda: True)
+    t, d = 1024, 2048
+    m = mamba2.Mamba2(d, 64, 64, 128, 4)
+    u = jax.ShapeDtypeStruct((1, t, d), jnp.float32)
+    v = jax.eval_shape(lambda u: m.init(jax.random.PRNGKey(0), u), u)
+    state = jax.tree_util.tree_map(lambda a: jnp.zeros(a.shape, a.dtype),
+                                   v["state"])
+
+    def compiled():
+        # a checkpoint of its own each time: a traced one is cached
+        layer = jax.checkpoint(lambda p, u: m.forward(p, state, u)[0])
+
+        def loss(p, u):
+            return jnp.sum(jnp.square(layer(p, u).astype(jnp.float32)))
+
+        shaped = lambda a: v5e(a.shape, a.dtype)
+        with compute_dtype(jnp.bfloat16):
+            return jax.jit(jax.grad(loss, (0, 1))).lower(
+                jax.tree_util.tree_map(shaped, v["params"]),
+                shaped(u)).compile()
+
+    fused = compiled()
+    text = fused.as_text()
+    entry = text[text.index("\nENTRY"):]
+    assert entry.count('custom_call_target="tpu_custom_call"') >= 6
+    assert "causal_conv" in entry
+    slices = re.findall(r"= \S+\[([\d,]*)\]\S* slice\(.*", entry)
+    assert not [s for s in re.findall(
+        r"= \S+ slice\(.*op_name=\"([^\"]*)\"", entry) if "causal_conv" in s]
+    assert not [s for s in slices if int(s.split(",")[1]) in
+                range(t - 3, t)]
+    assert not re.search(rf"= f32\[1,{t},8512\]\S* copy\(", entry)
+    monkeypatch.setattr(mamba2, "conv_blocks", lambda *a: None)
+    plain = compiled()
+    assert re.search(rf"= f32\[1,{t - 1},4352\]\S* slice\(",
+                     plain.as_text()), "the plain expression shifts"
+    temp = [c.memory_analysis().temp_size_in_bytes for c in (fused, plain)]
+    assert temp[0] <= temp[1], temp
