@@ -24,10 +24,16 @@ place out of ``u W_in`` (``mamba/causal_conv``: ``ops/causal_conv.py``)
 wherever its tile rule takes the widths, else the plain expression
 (``nn.short_conv.causal_taps``, in ``mamba/conv``); the gate's split and
 ``Δ`` float32 (``mamba/conv``); the scan (``mamba/ssd``); the gate and the
-norm float32 (``mamba/norm``).  The mixer is whole on every rank of
-a tensor-parallel group: its heads share ``B``, ``C`` and the norm's
-statistic (docs/parallelism.md §A whole Mamba-2 mixer beside held
-attention heads).
+norm float32 (``mamba/norm``).  Around the scan nothing changes layout:
+the SSD kernels take ``Δ⊙x`` and give ``y`` with T on the lanes, as XLA
+holds every activation of the mixer; x reaches them as a slice of
+``xBC``'s view as rows of ``head_dim`` (``_heads``), which XLA fuses into
+``Δ⊙x``.  ``D ⊙ x`` is added here, not by ``ssd``, in the mixer's
+``(…, T, d_in)`` layout, where XLA fuses it with the gate into the norm's
+passes (an add of its own in the scan's layout otherwise).  The mixer is
+whole on every rank of a tensor-parallel group: its heads share ``B``,
+``C`` and the norm's statistic (docs/parallelism.md §A whole Mamba-2 mixer
+beside held attention heads).
 
 It counts, in its model state, each scan it applies (``ssm.scans``), each
 convolution traced through the kernels (``ssm.fused_convs``) and, as a
@@ -38,6 +44,7 @@ A)``) (``obs/state_metrics.py``).  This module mixes a whole sequence
 its last ``K − 1`` convolution inputs as per-slot state beside the paged
 K/V, which the serving engine does not have yet."""
 
+import functools
 import math
 from typing import Optional
 
@@ -63,6 +70,30 @@ DT_RANGE, DT_FLOOR, A_RANGE = (1e-3, 1e-1), 1e-4, (1.0, 16.0)
 def _mm(x, w):
     return jnp.matmul(cast_compute(x), cast_compute(w),
                       preferred_element_type=jnp.float32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _heads(xbc, heads, head_dim):
+    """x of ``x‖B‖C`` (batch, T, W) as (batch, T, heads, head_dim), taken
+    from the view of ``xbc`` as rows of ``head_dim``: XLA fuses that slice
+    into ``Δ⊙x``, where it writes a slice of the (batch, T, W) array out
+    first.  The cotangent comes back as a pad of the (batch, T, W) array,
+    which XLA fuses into the convolution's cotangent (a pad of the view it
+    writes out)."""
+    whole = xbc.shape[-1] // head_dim * head_dim
+    return xbc[..., :whole].reshape(*xbc.shape[:2], -1, head_dim)[:, :, :heads]
+
+
+def _heads_fwd(xbc, heads, head_dim):
+    return _heads(xbc, heads, head_dim), xbc.shape[-1]
+
+
+def _heads_bwd(heads, head_dim, wide, g):
+    g = g.reshape(*g.shape[:2], heads * head_dim)
+    return (jnp.pad(g, ((0, 0), (0, 0), (0, wide - heads * head_dim))),)
+
+
+_heads.defvjp(_heads_fwd, _heads_bwd)
 
 
 class Mamba2(Module):
@@ -123,17 +154,17 @@ class Mamba2(Module):
                     zxbcdt[..., d_in:d_in + self.conv_dim],
                     params["conv_w"].astype(jnp.float32)) + params["conv_b"])
         with jax.named_scope("mamba/conv"):
-            z = zxbcdt[..., :d_in]
-            x = xbc[..., :d_in].reshape(batch, t, h, self.head_dim)
+            z, x = zxbcdt[..., :d_in], xbc[..., :d_in]
+            xh = _heads(xbc, h, self.head_dim)
             b, c = xbc[..., d_in:d_in + n], xbc[..., d_in + n:]
             dt = jax.nn.softplus(zxbcdt[..., d_in + self.conv_dim:]
                                  + params["dt_bias"])
             a = -jnp.exp(params["A_log"])
         with jax.named_scope("mamba/ssd"):
-            y = ssd(x, dt, a, b, c, params["D"], chunk=self.chunk)
+            y = ssd(xh, dt, a, b, c, chunk=self.chunk).reshape(
+                batch, t, d_in) + jnp.repeat(params["D"], self.head_dim) * x
         with jax.named_scope("mamba/norm"):
-            g = rms_norm(y.reshape(batch, t, d_in) * jax.nn.silu(z),
-                         params["norm"], self.eps)
+            g = rms_norm(y * jax.nn.silu(z), params["norm"], self.eps)
         with jax.named_scope("mamba/proj"):
             out = _mm(g, params["w_out"]).astype(u.dtype)
         carry = jax.lax.stop_gradient(chunk_carry(dt, a, self.chunk))

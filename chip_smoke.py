@@ -426,7 +426,7 @@ class Smoke:
         granite-4.0-h-micro cell's mixer (64 heads of 64, a state of 128,
         chunks of 256 over 16,384 positions), forward and the six gradients
         against autodiff of the token-by-token recurrence: Mosaic compiles
-        the heads-innermost grid, the reversed chunk walk and the (128, 64)
+        the heads-innermost grid, the reversed chunk walk and the (64, 128)
         float32 states in VMEM only here.  The recurrence goes in segments
         of 128 positions under ``jax.checkpoint``: its per-position states
         would be 32 GB."""

@@ -98,6 +98,9 @@ def scan_inputs(seed, batch, t, heads, p, n, a_log):
     # a head whose decay underflows within a chunk (A = -e^5: exp(Δ A)
     # under 1e-30 after a few positions), one with A near 0 (-e^-20)
     (1, 96, 4, 8, 32, (5.0, -20.0)),
+    # the TPU's shape rule at lane-shaped blocks: (64, 128) per head and
+    # chunk, a state of 128, three chunks with the last one padded
+    (1, 320, 64, 128, 128, (0.3, 1.2)),
 ])
 def test_ssd_kernels_are_the_token_recurrence(batch, t, p, n, chunk, a_log):
     """Forward and all six gradients (x, Δ, A_log, B, C, D) against

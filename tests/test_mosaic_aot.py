@@ -497,6 +497,30 @@ def test_causal_conv_forward_and_backward(v5e, t, wide, offset, width):
              f32(1, t, width))
 
 
+def _checkpointed_mamba_grad(v5e, t):
+    """``jax.checkpoint(Mamba2)``'s gradient at the Granite cell's widths
+    (d 2,048, 64 heads of 64, a state of 128, 4 taps), batch 1 x ``t``, in
+    bf16, compiled for a described v5e (``on_tpu`` patched by the caller);
+    a checkpoint of its own each time: a traced one is cached."""
+    from bigdl_tpu.nn import mamba2
+    from bigdl_tpu.tensor.policy import compute_dtype
+
+    m = mamba2.Mamba2(2048, 64, 64, 128, 4)
+    u = jax.ShapeDtypeStruct((1, t, 2048), jnp.float32)
+    v = jax.eval_shape(lambda u: m.init(jax.random.PRNGKey(0), u), u)
+    state = jax.tree_util.tree_map(lambda a: jnp.zeros(a.shape, a.dtype),
+                                   v["state"])
+    layer = jax.checkpoint(lambda p, u: m.forward(p, state, u)[0])
+
+    def loss(p, u):
+        return jnp.sum(jnp.square(layer(p, u).astype(jnp.float32)))
+
+    shaped = lambda a: v5e(a.shape, a.dtype)
+    with compute_dtype(jnp.bfloat16):
+        return jax.jit(jax.grad(loss, (0, 1))).lower(
+            jax.tree_util.tree_map(shaped, v["params"]), shaped(u)).compile()
+
+
 def test_checkpointed_mamba_gradient_shifts_nothing_in_hbm(v5e, monkeypatch):
     """``jax.checkpoint(Mamba2)``'s gradient at the Granite cell's widths
     and a short sequence, through the kernels: no top-level ``slice`` in
@@ -508,30 +532,10 @@ def test_checkpointed_mamba_gradient_shifts_nothing_in_hbm(v5e, monkeypatch):
 
     import bigdl_tpu.ops.common as common
     from bigdl_tpu.nn import mamba2
-    from bigdl_tpu.tensor.policy import compute_dtype
 
     monkeypatch.setattr(common, "on_tpu", lambda: True)
-    t, d = 1024, 2048
-    m = mamba2.Mamba2(d, 64, 64, 128, 4)
-    u = jax.ShapeDtypeStruct((1, t, d), jnp.float32)
-    v = jax.eval_shape(lambda u: m.init(jax.random.PRNGKey(0), u), u)
-    state = jax.tree_util.tree_map(lambda a: jnp.zeros(a.shape, a.dtype),
-                                   v["state"])
-
-    def compiled():
-        # a checkpoint of its own each time: a traced one is cached
-        layer = jax.checkpoint(lambda p, u: m.forward(p, state, u)[0])
-
-        def loss(p, u):
-            return jnp.sum(jnp.square(layer(p, u).astype(jnp.float32)))
-
-        shaped = lambda a: v5e(a.shape, a.dtype)
-        with compute_dtype(jnp.bfloat16):
-            return jax.jit(jax.grad(loss, (0, 1))).lower(
-                jax.tree_util.tree_map(shaped, v["params"]),
-                shaped(u)).compile()
-
-    fused = compiled()
+    t = 1024
+    fused = _checkpointed_mamba_grad(v5e, t)
     text = fused.as_text()
     entry = text[text.index("\nENTRY"):]
     assert entry.count('custom_call_target="tpu_custom_call"') >= 6
@@ -543,8 +547,40 @@ def test_checkpointed_mamba_gradient_shifts_nothing_in_hbm(v5e, monkeypatch):
                 range(t - 3, t)]
     assert not re.search(rf"= f32\[1,{t},8512\]\S* copy\(", entry)
     monkeypatch.setattr(mamba2, "conv_blocks", lambda *a: None)
-    plain = compiled()
+    plain = _checkpointed_mamba_grad(v5e, t)
     assert re.search(rf"= f32\[1,{t - 1},4352\]\S* slice\(",
                      plain.as_text()), "the plain expression shifts"
     temp = [c.memory_analysis().temp_size_in_bytes for c in (fused, plain)]
     assert temp[0] <= temp[1], temp
+
+
+# the temporaries of the same layer gradient at t = 1024 as the SSD kernels
+# compiled before they took T on the lanes, with head-major (heads, T, P)
+# operands at P = 64 on the lanes (for the same described v5e, libtpu
+# 0.0.34)
+HEAD_MAJOR_TEMP_BYTES = 85_047_296
+
+
+def test_checkpointed_mamba_gradient_keeps_the_scan_t_minor(v5e, monkeypatch):
+    """The SSD kernels take x, y and their cotangents with T on the lanes,
+    the layout XLA gives the mixer around them: in the checkpointed layer's
+    gradient no copy or fusion writes the head-major ``(B, T, heads, P)``
+    layout, no ``(heads, T, P)`` operand exists, the convolution's output is
+    never copied to channels-minor, and the temporaries are no more than
+    with the head-major operands."""
+    import re
+
+    import bigdl_tpu.ops.common as common
+
+    monkeypatch.setattr(common, "on_tpu", lambda: True)
+    t = 1024
+    compiled = _checkpointed_mamba_grad(v5e, t)
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY"):]
+    assert "ssd_" in entry
+    assert not re.search(
+        rf"= \w+\[1,{t},64,64\]{{3,1,2,0\S* (copy|fusion)\(", text)
+    assert f"bf16[64,{t},64]" not in text
+    assert not re.search(rf"= f32\[1,4352,{t}\]\S* copy\(", entry)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= HEAD_MAJOR_TEMP_BYTES, temp
