@@ -110,12 +110,6 @@ DEFAULT_HELP = {
     "train.effective_flops_per_step": "analytic training FLOPs of one "
                                       "global step counting only "
                                       "nonzero-block (executed) work",
-    "ops.autotune_trials": "kernel-autotuner timing trials executed in "
-                           "this process",
-    "ops.autotune_cache_hits": "kernel tile lookups answered from the "
-                               "autotune cache",
-    "ops.autotune_cache_misses": "kernel tile lookups that fell back to "
-                                 "the defaults (no cache entry)",
     "kernel.flash.traces": "flash_attention lowerings traced, by direction "
                            "(fwd, bwd), implementation, operand dtype and "
                            "query heads to a key/value head (kv_group)",

@@ -194,6 +194,10 @@ def _bsmm_bwd(smask, block_m, block_k, block_n, interpret, res, g):
 _bsmm.defvjp(_bsmm_fwd, _bsmm_bwd)
 
 
+# rows of x per grid step where a call leaves ``block_m`` as None
+BLOCK_M = 128
+
+
 def block_sparse_matmul(x, w, mask, *, block_k: int, block_n: int,
                         block_m: Optional[int] = None,
                         interpret: Optional[bool] = None):
@@ -202,8 +206,8 @@ def block_sparse_matmul(x, w, mask, *, block_k: int, block_n: int,
     ``mask`` is a HOST bool array ``(ceil(K/block_k), ceil(N/block_n))``
     (or a :class:`StaticMask`) — it must be concrete; a traced mask cannot
     drive the static index maps.  Differentiable (see module docstring for
-    the backward split).  ``block_m=None`` consults the autotune cache
-    (docs/performance.md §Kernel autotuning); explicit wins."""
+    the backward split).  ``block_m=None`` takes :data:`BLOCK_M`;
+    explicit wins."""
     if isinstance(mask, jax.core.Tracer):
         raise TypeError(
             "block_sparse_matmul needs a concrete (host) block mask — the "
@@ -213,15 +217,7 @@ def block_sparse_matmul(x, w, mask, *, block_k: int, block_n: int,
     k = x.shape[-1]
     x2 = x.reshape(-1, k)
     if block_m is None:
-        from bigdl_tpu.ops import autotune
-
-        shape_key = autotune.block_sparse_key(
-            x2.shape[0], k, w.shape[1], block_k, block_n, x.dtype)
-        online = ((int(x2.shape[0]), k, int(w.shape[1]), block_k,
-                   block_n, x.dtype.name)
-                  if autotune.is_concrete(x, w) else None)
-        block_m = autotune.resolve("block_sparse_matmul", shape_key,
-                                   online_shape=online)["block_m"]
+        block_m = BLOCK_M
     out = _bsmm(x2, w, smask, int(block_m), int(block_k), int(block_n),
                 default_interpret(interpret))
     return out.reshape(*lead, w.shape[1])

@@ -28,7 +28,7 @@ innermost) and a dq kernel (grid over q-blocks, k-blocks innermost), with
   names a tile already resident), and the element mask is applied only on
   tiles the diagonal (or the key padding) crosses;
 - take their blocks from :func:`default_blocks`, a rule on the shape the
-  call can see (docs/performance.md §Kernel autotuning).
+  call can see (docs/performance.md §Kernel tiles).
 
 Cross-chip sequence parallelism lives in
 ``bigdl_tpu/parallel/ring_attention.py``; the paged decode/verify kernels
@@ -73,8 +73,8 @@ _NT = (((1,), (1,)), ((), ()))
 _VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 _VMEM_BLOCK_BUDGET = _VMEM_LIMIT_BYTES // 2
 _LANES = 128
-# the blocks the rule (and the autotune spaces) choose among: multiples of
-# 128 that nest, so a length one of them divides is divided by the smaller
+# the blocks the rule chooses among: multiples of 128 that nest, so a length
+# one of them divides is divided by the smaller
 BLOCK_CHOICES = (128, 256, 512, 1024)
 
 
@@ -562,6 +562,14 @@ def _decode_kernel(pt_ref, len_ref, *refs, sm_scale, page_size, quantized):
         o_ref[...] = (acc_scr[:] / l_safe[:, None]).astype(o_ref.dtype)
 
 
+def decode_block_h(heads: int) -> int:
+    """The decode kernel's head tile: 4 where it divides ``heads``, else
+    the largest of (1, 2, 4, 8) that does."""
+    if heads % 4 == 0:
+        return 4
+    return max(c for c in (1, 2, 4, 8) if heads % c == 0)
+
+
 def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
                            k_scales=None, v_scales=None,
                            sm_scale: Optional[float] = None,
@@ -585,9 +593,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
     page block is dequantized in-register after its 1-byte/element DMA.
 
     ``block_h`` tiles the head dimension per program (must divide
-    heads); ``None`` consults the autotune cache under the
-    ``flash_attention_decode`` registry entry and falls back to the
-    largest of {1,2,4,8} that divides ``heads``."""
+    heads); ``None`` takes :func:`decode_block_h`."""
     S, h, d = q.shape
     P, hk, page, dk = k_pages.shape
     assert (h, d) == (hk, dk), (q.shape, k_pages.shape)
@@ -601,17 +607,8 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
     nb = page_table.shape[1]
     if sm_scale is None:
         sm_scale = d ** -0.5
-    from bigdl_tpu.ops import autotune
-
     if block_h is None:
-        key = autotune.decode_attention_key(S, h, page, d, nb,
-                                            q.dtype)
-        shape = ((S, h, page, d, nb, q.dtype.name)
-                 if autotune.is_concrete(q, k_pages, v_pages) else None)
-        bh = int(autotune.resolve("flash_attention_decode", key,
-                                  online_shape=shape)["block_h"])
-        if h % bh != 0:  # cached winner from another head count
-            bh = max(c for c in (1, 2, 4, 8) if h % c == 0)
+        bh = decode_block_h(h)
     else:
         bh = int(block_h)
         if h % bh != 0:
@@ -752,7 +749,7 @@ def paged_verify_attention(q, k_pages, v_pages, page_table, positions, *,
     int8 pools pass ``k_scales``/``v_scales`` per-page f32 abs-max
     scales, dequantized in-register like the decode kernel.  ``block_h``
     tiles heads (``None`` = the largest of {1, 2, 4, 8} dividing
-    ``heads`` — the verify chunk is not autotuned separately)."""
+    ``heads``)."""
     S, h, C, d = q.shape
     P, hk, page, dk = k_pages.shape
     assert (h, d) == (hk, dk), (q.shape, k_pages.shape)
@@ -844,33 +841,25 @@ def _book_trace(direction, q_shape, kv_heads, skv, dtype, causal, block_q,
 
 
 def resolve_blocks(q_shape, skv, dtype, *, d_v=None, block_q=None,
-                   block_k=None, block_k_bwd=None, online_shape=None):
+                   block_k=None, block_k_bwd=None):
     """``(forward, backward)`` blocks of one call on operands of ``dtype``:
-    per axis an explicit kwarg, else a cached autotune winner for this
-    device/shape bucket, else :func:`default_blocks`' pick.  Explicit
-    ``block_q``/``block_k`` also pin the backward pair's; ``block_k_bwd``
-    frees its key block again.  ``d_v``: the width of v where it is not
-    q's."""
-    from bigdl_tpu.ops import autotune
-
+    per axis an explicit kwarg, else :func:`default_blocks`' pick.
+    Explicit ``block_q``/``block_k`` also pin the backward pair's;
+    ``block_k_bwd`` frees its key block again.  ``d_v``: the width of v
+    where it is not q's."""
     dtype = jnp.dtype(dtype)
     sq, d = q_shape[2], q_shape[3]
-    key = autotune.attention_key(q_shape, skv, dtype, d_v)
-    fwd = autotune.resolve(
-        "flash_attention_fwd", key,
-        explicit={"block_q": block_q, "block_k": block_k},
-        online_shape=online_shape,
-        defaults=default_blocks("fwd", sq, skv, d, dtype.itemsize, d_v))
-    # the backward: cache/defaults only — no online_shape: a forward-only
-    # eager call must not pay a jax.grad tuning sweep for a backward it
-    # may never run (the offline CLI tunes flash_attention_bwd)
-    bwd = autotune.resolve(
-        "flash_attention_bwd", key,
-        explicit={"block_q": block_q,
-                  "block_k": block_k if block_k_bwd is None
-                  else block_k_bwd},
-        defaults=default_blocks("bwd", sq, skv, d, dtype.itemsize, d_v))
-    return fwd, bwd
+
+    def pick(direction, bq, bk):
+        blocks = default_blocks(direction, sq, skv, d, dtype.itemsize, d_v)
+        for axis, v in (("block_q", bq), ("block_k", bk)):
+            if v is not None:
+                blocks[axis] = int(v)
+        return blocks
+
+    return (pick("fwd", block_q, block_k),
+            pick("bwd", block_q,
+                 block_k if block_k_bwd is None else block_k_bwd))
 
 
 def flash_attention(q, k, v, *, causal: bool = False,
@@ -893,9 +882,8 @@ def flash_attention(q, k, v, *, causal: bool = False,
     accumulation and the softmax statistics are float32 and the result
     comes back in ``q``'s dtype.
 
-    ``block_*=None``: :func:`resolve_blocks` — a cached autotune winner if
-    there is one, else the block rule's pick for this shape
-    (docs/performance.md §Kernel autotuning); explicit kwargs always
+    ``block_*=None``: :func:`resolve_blocks` — the block rule's pick for
+    this shape (docs/performance.md §Kernel tiles); explicit kwargs always
     win."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
@@ -905,23 +893,13 @@ def flash_attention(q, k, v, *, causal: bool = False,
             f"flash_attention: {q.shape[1]} query heads on {kv_heads} key "
             f"and {v.shape[1]} value heads: k and v share a head count "
             "that divides q's")
-    from bigdl_tpu.ops import autotune
-
     dtype = q.dtype
     q, k, v = cast_compute(q, k, v)
     skv = k.shape[2]
-    # online mode tunes on a cache miss, but only on EAGER calls —
-    # inside a jit trace the args are tracers and we must not run timing
-    # trials mid-trace
     d_v = None if v.shape[-1] == q.shape[-1] else v.shape[-1]
-    # the online tuner's bench shape is (b, h, s, d): equal widths and
-    # head counts only
-    shape = (tuple(q.shape) + (q.dtype.name,)
-             if d_v is None and kv_heads == q.shape[1]
-             and autotune.is_concrete(q, k, v) else None)
     fwd, bwd = resolve_blocks(q.shape, skv, q.dtype, d_v=d_v,
                               block_q=block_q, block_k=block_k,
-                              block_k_bwd=block_k_bwd, online_shape=shape)
+                              block_k_bwd=block_k_bwd)
     _book_trace("fwd", q.shape, kv_heads, skv, q.dtype, bool(causal),
                 int(fwd["block_q"]), int(fwd["block_k"]))
     out = _flash(q, k, v, float(sm_scale), bool(causal),

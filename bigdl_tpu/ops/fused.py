@@ -17,6 +17,8 @@ from jax.experimental import pallas as pl
 
 from bigdl_tpu.ops.common import default_interpret, round_up
 
+# rows per grid step where a call leaves ``block_rows`` as None
+BLOCK_ROWS = 256
 
 def _ln_kernel(x_ref, g_ref, b_ref, o_ref, *, eps, d):
     x = x_ref[:].astype(jnp.float32)
@@ -36,19 +38,10 @@ def fused_layernorm(x, gamma, beta, *, eps: float = 1e-5,
     """Single-pass LayerNorm over the last axis.  Differentiable: backward
     is the closed-form LayerNorm VJP evaluated with jnp (XLA fuses it).
 
-    ``block_rows=None`` consults the autotune cache (default 256);
-    an explicit value always wins."""
+    ``block_rows=None`` takes :data:`BLOCK_ROWS`; an explicit value
+    always wins."""
     if block_rows is None:
-        from bigdl_tpu.ops import autotune
-
-        rows = 1
-        for d in x.shape[:-1]:
-            rows *= int(d)
-        key = autotune.rows_key(rows, x.shape[-1], x.dtype)
-        shape = ((rows, int(x.shape[-1]), x.dtype.name)
-                 if autotune.is_concrete(x) else None)
-        block_rows = autotune.resolve("fused_layernorm", key,
-                                      online_shape=shape)["block_rows"]
+        block_rows = BLOCK_ROWS
     return _fused_ln(x, gamma, beta, eps, int(block_rows), interpret)
 
 

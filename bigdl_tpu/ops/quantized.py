@@ -123,6 +123,10 @@ def _int8_mm_kernel(x_ref, w_ref, o_ref):
         precision=jax.lax.Precision.DEFAULT)
 
 
+# (block_m, block_n, block_k) where a call leaves an axis as None
+TILES = (256, 256, 512)
+
+
 def int8_matmul(x_q, w_q, *, block_m: Optional[int] = None,
                 block_n: Optional[int] = None,
                 block_k: Optional[int] = None,
@@ -130,22 +134,14 @@ def int8_matmul(x_q, w_q, *, block_m: Optional[int] = None,
     """int8 (M,K) × int8 (K,N) → int32 (M,N) on the MXU, tiled on all
     three dimensions (one (bm,bk) + (bk,bn) tile pair in VMEM per step).
 
-    ``block_*=None`` consults the autotune cache (defaults 256/256/512);
-    explicit kwargs always win."""
+    ``block_*=None`` takes that axis's tile from :data:`TILES`; explicit
+    kwargs always win."""
     m, k = x_q.shape
     k2, n = w_q.shape
     assert k == k2, (x_q.shape, w_q.shape)
-    if block_m is None or block_n is None or block_k is None:
-        from bigdl_tpu.ops import autotune
-
-        tiles = autotune.resolve(
-            "int8_matmul", autotune.matmul_key(m, k, n, x_q.dtype),
-            explicit={"block_m": block_m, "block_n": block_n,
-                      "block_k": block_k},
-            online_shape=((m, k, n) if autotune.is_concrete(x_q, w_q)
-                          else None))
-        block_m, block_n, block_k = (tiles["block_m"], tiles["block_n"],
-                                     tiles["block_k"])
+    block_m, block_n, block_k = (
+        tile if b is None else int(b)
+        for b, tile in zip((block_m, block_n, block_k), TILES))
     bm = min(block_m, round_up(m, 32))
     bn = min(block_n, round_up(n, 128))
     bk = min(block_k, round_up(k, 128))

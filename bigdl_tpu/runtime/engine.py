@@ -100,15 +100,6 @@ class EngineConfig:
     # overrides fleet-wide.  None keeps the classic ZeRO-1 data-parallel
     # driver.
     parallelism: Optional[str] = None
-    # kernel tile autotuning (docs/performance.md §Kernel autotuning):
-    # "off" = hand-picked defaults only, "cache" = consult the on-disk
-    # winner cache (default; never measures), "online" = measure-and-
-    # cache on a miss (EAGER kernel calls only — jitted paths rely on
-    # the offline CLI `python -m bigdl_tpu.ops.autotune`).
-    # BIGDL_TPU_AUTOTUNE overrides fleet-wide — resolved at call time by
-    # ops.autotune.autotune_mode(), the env var's single owner, so it is
-    # NOT parsed into this field by from_env().
-    kernel_autotune: str = "cache"
 
     def resolved_failure_policy(self) -> FailurePolicy:
         """The effective FailurePolicy: the explicit one, else defaults

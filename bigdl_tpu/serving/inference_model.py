@@ -60,7 +60,7 @@ class InferenceModel:
         ``weight_quant="int8"``: serve int8 weights (docs/quantization.md
         §Serving memory hierarchy).  Layered models (Container / keras
         Model) get the module-swap quantization — Linear/Conv2D leaves
-        become int8 twins running the autotuned int8 MXU matmul.  Raw-
+        become int8 twins running the int8 MXU matmul.  Raw-
         matrix models (Transformer) get weight-only int8 param storage
         dequantized inside the jitted forward, so HBM at rest drops 4x
         and one chip holds a proportionally bigger checkpoint; the
@@ -101,7 +101,7 @@ class InferenceModel:
                 if isinstance(model, Container) or nq._is_keras_model(
                         model):
                     # module swap: Linear/Conv2D leaves become int8
-                    # twins on the autotuned int8 MXU matmul path
+                    # twins on the int8 MXU matmul path
                     model, v = nq.quantize(
                         model, {"params": self._params,
                                 "state": self._state})

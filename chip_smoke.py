@@ -52,8 +52,8 @@ TOL_F32 = 2e-3    # VPU/f32 kernels: only exp/rsqrt approximations differ
 TOL_F32_SUM = 1e-5  # float32 multiply-adds and sums in another order, nothing
 #                     approximated (the mixing's one-pass backward: 2e-7 on
 #                     the v5e at (4, 4096, 3584), PR 35)
-TOL_MXU = 2e-2    # kernels whose dots take bf16 MXU passes (KERNELS_r04 used
-#                   0.02 forward / 0.05 backward for the same reason)
+TOL_MXU = 2e-2    # kernels whose dots take bf16 MXU passes: 0.02 forward,
+#                   0.05 backward
 TOL_MXU_BWD = 5e-2
 # flash attention under the bf16 policy (operands cast to bf16, float32
 # accumulation and statistics), measured on the v5e at (2, 12, 1024, 64)
@@ -151,17 +151,17 @@ class Smoke:
         import jax.numpy as jnp
 
         from bigdl_tpu.nn.attention import dot_product_attention
-        from bigdl_tpu.ops import autotune
-        from bigdl_tpu.ops.block_sparse import (block_sparse_matmul,
+        from bigdl_tpu.ops.block_sparse import (BLOCK_M, block_sparse_matmul,
                                                 expand_mask)
         from bigdl_tpu.ops.common import default_interpret
         from bigdl_tpu.ops.flash_attention import (flash_attention,
+                                                   decode_block_h,
                                                    paged_decode_attention,
                                                    paged_verify_attention,
                                                    resolve_blocks)
-        from bigdl_tpu.ops.fused import fused_layernorm
-        from bigdl_tpu.ops.quantized import (dequantize_pages, int8_matmul,
-                                             quantize_pages)
+        from bigdl_tpu.ops.fused import BLOCK_ROWS, fused_layernorm
+        from bigdl_tpu.ops.quantized import (TILES, dequantize_pages,
+                                             int8_matmul, quantize_pages)
 
         k = self.sz["kern"]
         S, h, d, page, nb, C = (k["slots"], k["heads"], k["hd"], k["page"],
@@ -316,26 +316,20 @@ class Smoke:
         check("int8_matmul", jax.jit(int8_matmul)(a8, w8),
               a8.astype(np.int64) @ w8.astype(np.int64), 0.0)
 
-        # the default tiles every auto-resolved call above used (flash
-        # attention's: its block rule's pick for operands it casts to the
-        # compute dtype)
+        # the tiles every call above that left them as None used, each from
+        # its kernel's own rule (flash attention's: its block rule's pick
+        # for operands it casts to the compute dtype)
         flash_fwd, flash_bwd = resolve_blocks(q.shape, T, cdt)
         tiles = {
             "flash_attention_fwd": flash_fwd,
             "flash_attention_bwd": flash_bwd,
-            "flash_attention_decode": autotune.resolve(
-                "flash_attention_decode", autotune.decode_attention_key(
-                    S, h, page, d, nb, jnp.float32)),
-            "block_sparse_matmul": autotune.resolve(
-                "block_sparse_matmul", autotune.block_sparse_key(
-                    S, K, N, bk, bn, cdt)),
-            "fused_layernorm": autotune.resolve(
-                "fused_layernorm", autotune.rows_key(rows, K, jnp.float32)),
-            "int8_matmul": autotune.resolve(
-                "int8_matmul", autotune.matmul_key(m_, k_, n_, jnp.int8)),
+            "flash_attention_decode": {"block_h": decode_block_h(h)},
+            "block_sparse_matmul": {"block_m": BLOCK_M},
+            "fused_layernorm": {"block_rows": BLOCK_ROWS},
+            "int8_matmul": dict(zip(("block_m", "block_n", "block_k"),
+                                    TILES)),
         }
-        self.say("kernels", f"autotune_cache={autotune.get_cache().path} "
-                            f"tiles={json.dumps(tiles)}")
+        self.say("kernels", f"tiles={json.dumps(tiles)}")
         if bad:
             raise AssertionError(f"kernels off their reference: {bad}")
 

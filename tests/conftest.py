@@ -14,18 +14,6 @@ if "xla_force_host_platform_device_count" not in flags:
         flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 
-# Hermetic kernel-autotune cache: without this, any kernel called with
-# default (None) tiles would consult the developer's real
-# .autotune_cache and parity tests would compile whatever
-# tiles that machine once tuned — test behavior must not depend on
-# machine state.  Tests that exercise the cache itself redirect this
-# again via monkeypatch.
-if "BIGDL_TPU_AUTOTUNE_CACHE" not in os.environ:
-    import tempfile
-
-    os.environ["BIGDL_TPU_AUTOTUNE_CACHE"] = tempfile.mkdtemp(
-        prefix="bigdl_tpu_autotune_test_")
-
 import jax  # noqa: E402
 
 jax.config.update("jax_threefry_partitionable", True)

@@ -474,13 +474,15 @@ class TestPagedDecodeAttention:
                                    jnp.zeros((2,), jnp.int32), block_h=3)
 
     def test_registered_in_autotuner(self):
-        from bigdl_tpu.ops import autotune
+        """The head tile a call leaving ``block_h`` as None takes: 4 where
+        it divides the heads, else the largest of (1, 2, 4, 8) that
+        does."""
+        from bigdl_tpu.ops.flash_attention import decode_block_h
 
-        spec = autotune.REGISTRY["flash_attention_decode"]
-        assert "block_h" in spec.space
-        key = autotune.decode_attention_key(8, 4, 8, 32, 4, "float32")
-        tiles = autotune.resolve("flash_attention_decode", key)
-        assert tiles["block_h"] in (1, 2, 4, 8)
+        for heads, bh in ((4, 4), (8, 4), (12, 4), (16, 4), (1, 1),
+                          (2, 2), (6, 2), (3, 1), (10, 2), (5, 1)):
+            assert decode_block_h(heads) == bh
+            assert heads % bh == 0
 
     def test_engine_flash_path_greedy_tokens_agree(self, lm):
         jnp_eng = _lm_engine(lm, use_flash_decode=False)

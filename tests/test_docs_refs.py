@@ -101,7 +101,7 @@ def _path_exists(doc, name, basenames, sources):
     if "/" not in name:
         # a bare name: a file of the tree wherever it lives
         return name in basenames or run_time
-    # an output under a dot directory (`.autotune_cache/tiles.json`)
+    # an output under a dot directory the program writes at run time
     return name.startswith(".") and run_time
 
 

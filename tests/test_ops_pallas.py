@@ -241,19 +241,10 @@ class TestFlashBackwardKernels:
         assert cq <= round_up(sq, 8) and ck <= round_up(skv, 8)
         assert sq_p % cq == 0 and skv_p % ck == 0
         assert sq <= sq_p < sq + cq and skv <= skv_p < skv + ck
-        # the registry's default IS the rule
-        from bigdl_tpu.ops import autotune
-        if sq == skv and d_v is None:
-            dtype = "bfloat16" if itemsize == 2 else "float32"
-            spec = autotune.REGISTRY[f"flash_attention_{direction}"]
-            assert spec.defaults_for((1, 1, sq, d, dtype)) == got
-            for axis, v in got.items():
-                assert v in spec.space[axis].grid()
 
     def test_block_rule_follows_the_shape(self):
         """Bigger tiles where they fit, smaller where the head size or the
-        itemsize fills the budget; explicit kwargs and a cached winner
-        still win, in that order."""
+        itemsize fills the budget; explicit kwargs still win."""
         import importlib
         fa = importlib.import_module("bigdl_tpu.ops.flash_attention")
 
@@ -305,14 +296,12 @@ class TestFlashBackwardKernels:
         assert gauge("bwd") == pytest.approx(share)
 
     def test_autotune_winner_and_explicit_blocks_beat_the_rule(
-            self, tmp_path, monkeypatch):
+            self, monkeypatch):
+        """Blocks left as None take the rule's pick, forward and backward;
+        explicit kwargs win axis by axis."""
         import importlib
         fa = importlib.import_module("bigdl_tpu.ops.flash_attention")
-        from bigdl_tpu.ops import autotune
 
-        monkeypatch.setenv("BIGDL_TPU_AUTOTUNE_CACHE", str(tmp_path))
-        monkeypatch.delenv("BIGDL_TPU_AUTOTUNE", raising=False)
-        autotune.reset_cache()
         q, k, v = self._inputs(32, 32, 8)
         seen = []
         real = fa._flash
@@ -323,30 +312,19 @@ class TestFlashBackwardKernels:
                         interpret)
 
         monkeypatch.setattr(fa, "_flash", spy)
-        key = autotune.attention_key(q.shape, 32, q.dtype)
-        try:
-            fa.flash_attention(q, k, v, interpret=True)
-            rule_f = fa.default_blocks("fwd", 32, 32, 8, 4)
-            rule_b = fa.default_blocks("bwd", 32, 32, 8, 4)
-            assert seen[-1] == (rule_f["block_q"], rule_f["block_k"],
-                                rule_b["block_q"], rule_b["block_k"])
-            for kern, tiles in (("flash_attention_fwd", (16, 8)),
-                                ("flash_attention_bwd", (8, 16))):
-                autotune.get_cache().put(autotune.full_key(kern, key), {
-                    "tiles": {"block_q": tiles[0], "block_k": tiles[1]},
-                    "best_ms": 1.0, "default_ms": 2.0, "trials": 1,
-                    "winner": "searched"})
-            fa.flash_attention(q, k, v, interpret=True)
-            assert seen[-1] == (16, 8, 8, 16)        # cached winners
-            fa.flash_attention(q, k, v, block_q=32, interpret=True)
-            assert seen[-1] == (32, 8, 32, 16)       # block_q pins both
-            fa.flash_attention(q, k, v, block_k=32, interpret=True)
-            assert seen[-1] == (16, 32, 8, 32)       # block_k pins both
-            fa.flash_attention(q, k, v, block_k=32, block_k_bwd=8,
-                               interpret=True)
-            assert seen[-1] == (16, 32, 8, 8)
-        finally:
-            autotune.reset_cache()
+        fa.flash_attention(q, k, v, interpret=True)
+        rule_f = fa.default_blocks("fwd", 32, 32, 8, 4)
+        rule_b = fa.default_blocks("bwd", 32, 32, 8, 4)
+        fq, fk, bq, bk = (rule_f["block_q"], rule_f["block_k"],
+                          rule_b["block_q"], rule_b["block_k"])
+        assert seen[-1] == (fq, fk, bq, bk)
+        fa.flash_attention(q, k, v, block_q=32, interpret=True)
+        assert seen[-1] == (32, fk, 32, bk)      # block_q pins both
+        fa.flash_attention(q, k, v, block_k=32, interpret=True)
+        assert seen[-1] == (fq, 32, bq, 32)      # block_k pins both
+        fa.flash_attention(q, k, v, block_k=32, block_k_bwd=8,
+                           interpret=True)
+        assert seen[-1] == (fq, 32, bq, 8)
 
 
 class TestInt8Matmul:
@@ -1216,134 +1194,118 @@ class TestBlockSparse:
 
 
 class TestAutotune:
-    """Kernel tile autotuner (docs/performance.md §Kernel autotuning):
-    cache determinism, explicit-kwarg precedence, never-slower-than-
-    default."""
+    """Tiles a call leaves as None (docs/performance.md §Kernel tiles)
+    change the tiling, never the math."""
 
-    @pytest.fixture()
-    def at(self, tmp_path, monkeypatch):
-        from bigdl_tpu.ops import autotune
-
-        monkeypatch.setenv("BIGDL_TPU_AUTOTUNE_CACHE", str(tmp_path))
-        monkeypatch.delenv("BIGDL_TPU_AUTOTUNE", raising=False)
-        autotune.reset_cache()
-        yield autotune
-        autotune.reset_cache()
-
-    def _fake_measure(self, monkeypatch, at, fn):
-        calls = {"n": 0}
-
-        def fake(thunk, repeats=0):
-            calls["n"] += 1
-            return fn()
-
-        monkeypatch.setattr(at, "_measure_ms", fake)
-        return calls
-
-    def test_cache_hit_determinism_second_lookup_zero_trials(
-            self, at, monkeypatch):
-        # deterministic fake timing: the 4th measured config is fastest
-        seen = []
-
-        def fake(thunk, repeats=0):
-            seen.append(1)
-            return 0.5 if len(seen) == 4 else 1.0 + 0.1 * len(seen)
-
-        monkeypatch.setattr(at, "_measure_ms", fake)
-        shape = (512, 256, "float32")
-        entry = at.tune("fused_layernorm", shape, n_trials=8)
-        assert entry["trials"] > 0
-        # tune keys through the SAME bucketed key the kernel computes at
-        # call time — an offline winner must be exactly what
-        # fused_layernorm(block_rows=None) looks up
-        key = at.canonical_key("fused_layernorm", shape)
-        assert key == at.full_key("fused_layernorm",
-                                  at.rows_key(512, 256, "float32"))
-        assert at.get_cache().get(key)["tiles"] == entry["tiles"]
-        # a "second process": fresh in-memory handle over the same dir,
-        # online mode armed — the disk hit must answer with ZERO timing
-        # trials and the identical tiles
-        at.reset_cache()
-        monkeypatch.setenv("BIGDL_TPU_AUTOTUNE", "online")
-        n_before = len(seen)
-        got = at.resolve("fused_layernorm", at.rows_key(512, 256,
-                                                        "float32"),
-                         online_shape=shape)
-        assert len(seen) == n_before
-        assert got == entry["tiles"]
-
-    def test_explicit_kwarg_beats_cache(self, at):
-        key_shape = "r512_c256_float32"
-        at.get_cache().put(
-            at.full_key("fused_layernorm", key_shape),
-            {"tiles": {"block_rows": 1024}, "best_ms": 1.0,
-             "default_ms": 2.0, "trials": 5, "winner": "searched"})
-        # cache wins over the registry default...
-        auto = at.resolve("fused_layernorm", key_shape)
-        assert auto["block_rows"] == 1024
-        # ...but an explicit kwarg beats the cache
-        expl = at.resolve("fused_layernorm", key_shape,
-                          explicit={"block_rows": 64})
-        assert expl["block_rows"] == 64
-        # and None means "not passed", not "explicit"
-        expl2 = at.resolve("fused_layernorm", key_shape,
-                           explicit={"block_rows": None})
-        assert expl2["block_rows"] == 1024
-
-    def test_mode_off_ignores_cache(self, at, monkeypatch):
-        key_shape = "r512_c256_float32"
-        at.get_cache().put(
-            at.full_key("fused_layernorm", key_shape),
-            {"tiles": {"block_rows": 1024}, "best_ms": 1.0,
-             "default_ms": 2.0, "trials": 5, "winner": "searched"})
-        monkeypatch.setenv("BIGDL_TPU_AUTOTUNE", "off")
-        assert at.resolve("fused_layernorm",
-                          key_shape)["block_rows"] == 256  # the default
-
-    def test_tuner_never_slower_than_default(self, at, monkeypatch):
-        # every candidate measures SLOWER than the default -> the tuner
-        # must hand back the hand-picked defaults
-        def fake_slow(thunk, repeats=0):
-            fake_slow.n = getattr(fake_slow, "n", 0) + 1
-            return 1.0 if fake_slow.n == 1 else 5.0  # first call = default
-
-        monkeypatch.setattr(at, "_measure_ms", fake_slow)
-        entry = at.tune("fused_layernorm", (512, 256, "float32"),
-                        n_trials=6)
-        assert entry["winner"] == "default"
-        assert entry["tiles"] == {"block_rows": 256}
-        assert entry["best_ms"] <= entry["default_ms"]
-
-    def test_garbage_cache_entry_falls_back_to_defaults(self, at):
-        key_shape = "r512_c256_float32"
-        at.get_cache().put(
-            at.full_key("fused_layernorm", key_shape),
-            {"tiles": {"block_rows": "boom"}, "best_ms": 1.0,
-             "default_ms": 2.0, "trials": 1, "winner": "searched"})
-        assert at.resolve("fused_layernorm",
-                          key_shape)["block_rows"] == 256
-
-    def test_kernels_consult_resolution_without_breaking_parity(
-            self, at):
-        """flash_attention/fused_layernorm with auto tiles (None) match
-        their explicit-tile outputs — the resolution layer changes tile
-        choice, never math."""
-        from bigdl_tpu.ops import flash_attention, fused_layernorm
+    def test_kernels_consult_resolution_without_breaking_parity(self):
+        """Tiles left as None give the output the rule's tiles give when
+        passed explicitly."""
+        import importlib
+        fa = importlib.import_module("bigdl_tpu.ops.flash_attention")
+        from bigdl_tpu.ops import fused, quantized
 
         rng = np.random.default_rng(0)
         q = _rand(rng, 1, 2, 24, 8)
+        rule = fa.default_blocks("fwd", 24, 24, 8, 4)
         a = flash_attention(q, q, q, causal=True, interpret=True)
-        b = flash_attention(q, q, q, causal=True, block_q=8, block_k=8,
-                            interpret=True)
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-4, atol=2e-4)
+        b = flash_attention(q, q, q, causal=True, interpret=True, **rule)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
         x = _rand(rng, 9, 17)
         g2 = _rand(rng, 17)
         b2 = _rand(rng, 17)
         y_auto = fused_layernorm(x, g2, b2, interpret=True)
-        y_expl = fused_layernorm(x, g2, b2, block_rows=8, interpret=True)
-        np.testing.assert_allclose(np.asarray(y_auto), np.asarray(y_expl),
-                                   rtol=1e-5, atol=1e-5)
+        y_expl = fused_layernorm(x, g2, b2, block_rows=fused.BLOCK_ROWS,
+                                 interpret=True)
+        np.testing.assert_array_equal(np.asarray(y_auto), np.asarray(y_expl))
+        x8 = jnp.asarray(rng.integers(-127, 128, (40, 70)), jnp.int8)
+        w8 = jnp.asarray(rng.integers(-127, 128, (70, 30)), jnp.int8)
+        bm, bn, bk = quantized.TILES
+        np.testing.assert_array_equal(
+            np.asarray(int8_matmul(x8, w8, interpret=True)),
+            np.asarray(int8_matmul(x8, w8, block_m=bm, block_n=bn,
+                                   block_k=bk, interpret=True)))
+
+
+class TestKernelTiles:
+    """The tiles each kernel takes where a call leaves them as None: one
+    rule or constant in the kernel's own module; explicit kwargs win,
+    axis by axis.  Read off the kernel's inner call, not timed."""
+
+    @staticmethod
+    def _pallas_calls(monkeypatch):
+        """Record each ``pallas_call``'s arguments and hand back zeros of
+        its result: the tiles are what is read, nothing is run."""
+        from jax.experimental import pallas as pl
+
+        seen = []
+
+        def spy(kernel, **kw):
+            seen.append(kw)
+            out = kw["out_shape"]
+            return lambda *args: jnp.zeros(out.shape, out.dtype)
+
+        monkeypatch.setattr(pl, "pallas_call", spy)
+        return seen
+
+    def _fused_layernorm(self, monkeypatch, **kw):
+        from bigdl_tpu.ops import fused
+
+        seen = []
+        monkeypatch.setattr(fused, "_fused_ln",
+                            lambda x, g, b, eps, rows, interp:
+                            seen.append(rows) or x)
+        x = jnp.zeros((1024, 16), jnp.float32)
+        fused.fused_layernorm(x, x[0], x[0], **kw)
+        return seen[-1]
+
+    def _int8_matmul(self, monkeypatch, **kw):
+        from bigdl_tpu.ops import quantized
+
+        seen = self._pallas_calls(monkeypatch)
+        x = jnp.zeros((1024, 1024), jnp.int8)
+        quantized.int8_matmul(x, x, **kw)
+        (bm, bk), (_, bn) = (s.block_shape for s in seen[-1]["in_specs"])
+        return bm, bn, bk
+
+    def _block_sparse_matmul(self, monkeypatch, **kw):
+        from bigdl_tpu.ops import block_sparse
+
+        seen = []
+        monkeypatch.setattr(block_sparse, "_bsmm",
+                            lambda x, w, mask, bm, bk, bn, interp:
+                            seen.append(bm) or x @ w)
+        x = jnp.zeros((512, 64), jnp.float32)
+        block_sparse.block_sparse_matmul(
+            x, jnp.zeros((64, 64), jnp.float32), np.ones((2, 2), bool),
+            block_k=32, block_n=32, **kw)
+        return seen[-1]
+
+    def _paged_decode(self, monkeypatch, heads, **kw):
+        from bigdl_tpu.ops.flash_attention import paged_decode_attention
+
+        seen = self._pallas_calls(monkeypatch)
+        slots, page, d, nb = 2, 8, 16, 2
+        pages = jnp.zeros((slots * nb, heads, page, d), jnp.float32)
+        paged_decode_attention(
+            jnp.zeros((slots, heads, d), jnp.float32), pages, pages,
+            jnp.zeros((slots, nb), jnp.int32),
+            jnp.zeros((slots,), jnp.int32), **kw)
+        return heads // seen[-1]["grid_spec"].grid[1]
+
+    @pytest.mark.parametrize("kernel,kwargs,want", [
+        ("fused_layernorm", {}, 256),
+        ("fused_layernorm", {"block_rows": 64}, 64),
+        ("int8_matmul", {}, (256, 256, 512)),
+        ("int8_matmul", {"block_n": 128}, (256, 128, 512)),
+        ("block_sparse_matmul", {}, 128),
+        ("paged_decode", {"heads": 8}, 4),
+        ("paged_decode", {"heads": 6}, 2),
+        ("paged_decode", {"heads": 1}, 1),
+        ("paged_decode", {"heads": 8, "block_h": 8}, 8),
+    ])
+    def test_tiles_left_none_take_the_kernel_rule(self, monkeypatch, kernel,
+                                                   kwargs, want):
+        assert getattr(self, f"_{kernel}")(monkeypatch, **kwargs) == want
 
 
 class TestBlockSparseServing:
@@ -1367,40 +1329,6 @@ class TestBlockSparseServing:
         out = im.predict(ids)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=1e-4, atol=1e-4)
-
-
-class TestAutotuneOnline:
-    def test_online_mode_tunes_on_first_eager_call_only(
-            self, tmp_path, monkeypatch):
-        """BIGDL_TPU_AUTOTUNE=online: the first EAGER kernel call at a
-        new shape bucket runs trials and caches; the second call (and any
-        jitted call) runs zero trials."""
-        from bigdl_tpu.ops import autotune, fused_layernorm
-
-        monkeypatch.setenv("BIGDL_TPU_AUTOTUNE_CACHE", str(tmp_path))
-        monkeypatch.setenv("BIGDL_TPU_AUTOTUNE", "online")
-        autotune.reset_cache()
-        calls = {"n": 0}
-
-        def fake(thunk, repeats=0):
-            calls["n"] += 1
-            return float(calls["n"])  # first measured (the default) wins
-
-        monkeypatch.setattr(autotune, "_measure_ms", fake)
-        rng = np.random.default_rng(0)
-        x = _rand(rng, 32, 16)
-        g = _rand(rng, 16)
-        b = _rand(rng, 16)
-        y = fused_layernorm(x, g, b, interpret=True)
-        assert calls["n"] > 0  # tuned on the miss
-        n_after = calls["n"]
-        y2 = fused_layernorm(x, g, b, interpret=True)
-        assert calls["n"] == n_after  # cache hit: zero further trials
-        np.testing.assert_allclose(np.asarray(y), np.asarray(y2))
-        # jitted call: tracers -> no tuning, cache consulted silently
-        jax.jit(lambda x: fused_layernorm(x, g, b, interpret=True))(x)
-        assert calls["n"] == n_after
-        autotune.reset_cache()
 
 
 class TestPruneBinding:
